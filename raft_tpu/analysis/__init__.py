@@ -2,7 +2,7 @@
 
 Round 5 burned a scarce TPU bench window discovering failure classes that are
 decidable from source alone — host syncs hiding in hot loops, Python control
-flow on traced values, un-instrumented hot paths (VERDICT.md r5; the ROADMAP
+flow on traced values, un-instrumented hot paths (round-5 review; the ROADMAP
 "telemetry is a prerequisite" open item). This package is the cheap CPU-side
 gate: an AST walk over the whole repo on every tier-1 run, with a pluggable
 rule registry targeting this codebase's real bug classes and a checked-in

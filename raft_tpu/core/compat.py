@@ -1,8 +1,8 @@
 """Version shims for jax API moves.
 
-One function per moved API, resolved once at import. Library code imports
-from here instead of feature-testing at every call site; when the minimum
-supported jax passes the new spelling, delete the shim and inline the call.
+The installed jax (0.9 series, pinned in pyproject.toml) has every API in
+its current home; these one-line wrappers keep the call sites' spelling in
+one place.
 """
 
 from __future__ import annotations
@@ -10,25 +10,11 @@ from __future__ import annotations
 import jax
 from jax import lax
 
-if hasattr(lax, "axis_size"):
 
-    def axis_size(axis: str) -> int:
-        return lax.axis_size(axis)
+def axis_size(axis: str) -> int:
+    return lax.axis_size(axis)
 
-else:  # jax < 0.5: psum of a literal 1 constant-folds to the static size
 
-    def axis_size(axis: str) -> int:
-        return lax.psum(1, axis)
-
-if hasattr(jax, "shard_map"):
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-
-else:  # jax < 0.5: experimental home, `check_vma` was named `check_rep`
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=check_vma)
+def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)

@@ -175,7 +175,19 @@ def search(
     report = probe_shards("brute_force", comms.size, index.n_total,
                           health=health)
     ok_dev = shard_ok_device(report.ok, comms)
-    vals, ids = fn(index.dataset, norms, queries, fwords, ok_dev)
+    # query tiles bound the (tile, shard rows) fp32 distance block to the
+    # workspace (a v5e compile of a 10k-query call over a 1M-row shard asked
+    # for 40 GB, PR 21); equal tiles share one compiled program
+    q = queries.shape[0]
+    rows = index.dataset.shape[0] // comms.size
+    q_tile = max(1, min(q, res.workspace_bytes // (4 * max(rows, 1))))
+    q_tile = -(-q // -(-q // q_tile))
+    outs = [fn(index.dataset, norms, queries[s:s + q_tile], fwords, ok_dev)
+            for s in range(0, q, q_tile)]
+    vals = outs[0][0] if len(outs) == 1 else jnp.concatenate(
+        [o[0] for o in outs])
+    ids = outs[0][1] if len(outs) == 1 else jnp.concatenate(
+        [o[1] for o in outs])
     return SearchResult(vals, ids, coverage=report.coverage,
                         degraded=report.degraded,
                         lost_shards=report.dropped)

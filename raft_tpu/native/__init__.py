@@ -2,14 +2,18 @@
 
 The compute path of this framework is JAX/XLA/Pallas; the runtime around it
 — bulk host IO like the hnswlib-format writer — is native C++ like the
-reference's, compiled on demand with the system toolchain and cached next
-to the source. Every native entry point has a pure-Python fallback so the
-package works without a compiler.
+reference's, compiled on demand with the system toolchain. The build lands
+in ``_build/`` (gitignored) under a name keyed by the source's content hash,
+so a library is reused only when it was built from exactly the committed
+source — never a stale or copied-in ``.so`` trusted for its mtime. Every
+native entry point has a pure-Python fallback so the package works without
+a compiler.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -23,14 +27,19 @@ _TRIED = False
 
 def _build_lib() -> Optional[str]:
     src = os.path.join(_DIR, "hnsw_writer.cpp")
-    out = os.path.join(_DIR, "_raft_tpu_native.so")
-    if os.path.exists(out) and os.path.getmtime(out) >= os.path.getmtime(src):
+    with open(src, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    out = os.path.join(_DIR, "_build", f"hnsw_writer-{digest}.so")
+    if os.path.exists(out):
         return out
+    tmp = f"{out}.{os.getpid()}.tmp"  # concurrent builders: rename is atomic
     try:
+        os.makedirs(os.path.dirname(out), exist_ok=True)
         subprocess.run(
-            ["g++", "-O2", "-shared", "-fPIC", "-std=c++17", src, "-o", out],
+            ["g++", "-O2", "-shared", "-fPIC", "-std=c++17", src, "-o", tmp],
             check=True, capture_output=True, timeout=120,
         )
+        os.replace(tmp, out)
         return out
     except (subprocess.SubprocessError, FileNotFoundError, OSError):
         return None

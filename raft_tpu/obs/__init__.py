@@ -10,8 +10,7 @@ fleet-wide merge of per-process snapshots (obs/aggregate.py, also
 ``python -m raft_tpu.obs.aggregate``); and a subprocess-isolated
 device-health probe (obs/health.py) that answers "is this backend alive?" in
 bounded time — the check bench.py runs before committing its TPU window (the
-round-5 wedge ate the whole window with no record; ISSUE 1 / VERDICT.md
-round 5).
+round-5 wedge ate the whole window with no record; ISSUE 1).
 
 Usage::
 

@@ -155,10 +155,13 @@ class FlightRecorder:
     Providers (``engine``/``sampler``/``queue``/``capacity``) are passed
     straight to ``obs.report.collect``; each may be a zero-arg callable.
     ``knobs`` (dict or callable) feeds :func:`fingerprint`. ``health`` is
-    a precomputed device-health verdict for the first window; with
+    a precomputed device-health verdict for the first window — a process
+    that holds the chip passes ``obs.health.in_process()``; with
     ``probe_health=True`` the recorder runs the subprocess probe itself
-    (classified on failure) — callers pay that cost once, on the first
-    sample, so take window 0 off any measured clock.
+    (classified on failure), which only a process that does NOT hold the
+    chip may do (a child cannot open a chip its parent holds). Callers pay
+    that cost once, on the first sample, so take window 0 off any
+    measured clock.
     """
 
     def __init__(self, path: Optional[str] = None, *, knobs=None,

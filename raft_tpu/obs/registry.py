@@ -19,7 +19,7 @@ asserted in tests) and never touches the registry.
 Span timings are host wall-clock around the instrumented region. JAX dispatch
 is asynchronous, so a span around a pure-dispatch region measures dispatch +
 trace/compile time, not device execution — that is the useful number for the
-wedge-hunting this layer exists for (VERDICT.md round 5: the failure modes are
+wedge-hunting this layer exists for (round 5: the failure modes are
 host-side hangs, not slow kernels).
 """
 

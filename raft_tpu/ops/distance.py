@@ -92,9 +92,12 @@ def canonical_metric(metric: str) -> str:
     return m
 
 
+@functools.partial(jax.jit, static_argnames=("axis",))
 def sqnorm(x: jax.Array, axis: int = 1) -> jax.Array:
     """Row squared-L2 norms, squaring in fp32: fp16 inputs overflow and int8
-    inputs wrap if squared in their own dtype before the fp32 accumulation."""
+    inputs wrap if squared in their own dtype before the fp32 accumulation.
+    One fused program, so the fp32 copy is never materialized (eagerly, a
+    4M-row uint8 list block's copy alone is 8 GB — PR 21's 4-chip run)."""
     xf = jnp.asarray(x).astype(jnp.float32)
     return jnp.sum(xf * xf, axis=axis)
 

@@ -126,7 +126,7 @@ def default_window_s() -> float:
 
 def default_promote_deadline() -> float:
     """Wall-clock bound on one snapshot restore (promotion); a hang on
-    the tunneled runtime lands as a classified DEADLINE verdict."""
+    the device load path lands as a classified DEADLINE verdict."""
     return _env_pos(PROMOTE_DEADLINE_ENV, 30.0) or 30.0
 
 

@@ -1,8 +1,8 @@
 #!/usr/bin/env python
 """Bench regression tracking: diff two runs, emit a markdown delta table.
 
-Closes the telemetry loop the round-8 ISSUE names: BENCH_r01–r05 /
-MULTICHIP_r01–r05 give the repo a trajectory, but until now every round's
+Closes the telemetry loop the round-8 ISSUE names: BENCH_r02–r05 /
+MULTICHIP_r02–r05 give the repo a trajectory, but until now every round's
 headline was a one-off — nothing diffed round N against N−1, so a 10% QPS
 regression would ship unremarked. This tool compares any two of:
 

@@ -4,6 +4,7 @@ LocalCUDACluster-analog fixture is the conftest virtual CPU mesh)."""
 
 import time
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -160,6 +161,73 @@ class TestDistributedKMeans:
         with pytest.raises(ValueError, match="n_clusters"):
             dkm.fit(np.zeros((4, 2), np.float32),
                     kmeans_sd.KMeansParams(n_clusters=10), comms=comms)
+
+
+@pytest.mark.parametrize("family", ["brute_force", "ivf_flat"])
+def test_query_tiles_match_one_call(rng, comms, family):
+    """A workspace too small for one call splits the queries into equal
+    tiles (distributed brute force: the (tile, shard rows) distance block;
+    the dense IVF scan: dense_q_tile) and changes no answer."""
+    from raft_tpu import obs
+    from raft_tpu.core.resources import Resources, use_resources
+    from raft_tpu.distributed import ivf_flat as divf
+
+    X, Q = _data(rng, n=800, q=20)
+    if family == "brute_force":
+        idx = dbf.build(X, comms=comms)
+        search = lambda: dbf.search(idx, Q, 10)
+        tiny = 4 * 100 * 7          # 100 rows per shard: 7-query tiles
+    else:
+        idx = divf.build(X, divf.IvfFlatParams(n_lists=4), comms=comms)
+        search = lambda: divf.search(idx, Q, 10, n_probes=4)
+        tiny = idx.max_list_size * (4 * 16 + 16 * 4) * 7
+    v0, i0 = search()
+    obs.enable()
+    try:
+        before = obs.snapshot()["counters"].get("distributed.search.tiles", 0)
+        with use_resources(Resources(workspace_bytes=tiny)):
+            v1, i1 = search()
+        tiles = (obs.snapshot()["counters"].get("distributed.search.tiles", 0)
+                 - before)
+    finally:
+        obs.disable()
+    np.testing.assert_array_equal(np.asarray(i1), np.asarray(i0))
+    np.testing.assert_allclose(np.asarray(v1), np.asarray(v0), rtol=1e-6)
+    if family == "ivf_flat":
+        assert tiles == 3, tiles    # 20 queries in tiles of 7, 7, 6
+
+
+def test_sharded_strip_tiles_match_dense_scan(rng, comms):
+    """The TPU engine of the sharded search — strip tables planned inside
+    each shard_map tile on the static layout, the Pallas kernel in
+    interpret mode here — returns the dense XLA scan's answers."""
+    from raft_tpu.distributed import ivf_flat as divf
+    from raft_tpu.distributed._sharding import tiled_search
+    from raft_tpu.neighbors.ivf_flat import _coarse_probes
+
+    X, Q = _data(rng, n=4000, dim=16, q=24)
+    idx = divf.build(X, divf.IvfFlatParams(n_lists=4, group_size=512),
+                     comms=comms)
+    assert idx.max_list_size == 512      # strip-eligible
+    probes = _coarse_probes(jnp.asarray(Q), idx.centers, 3, "sqeuclidean",
+                            "exact", jnp.float32)
+    out = {}
+    for dense in (True, False):
+        v, i, _ = tiled_search(
+            jnp.asarray(Q), probes, idx.lens_max, idx.n_lists, 10, comms,
+            -2.0, dense=dense, interpret=True, data=idx.list_data,
+            ids_arr=idx.list_ids, bias=idx.bias, algo="ivf_flat",
+            n_total=idx.n_total)
+        out[dense] = (np.asarray(v), np.asarray(i))
+    # the strip kernel scores in bf16 (its documented contract): near-ties
+    # may swap, so compare as sets, and the distances loosely
+    from raft_tpu import stats
+
+    overlap = float(stats.neighborhood_recall(out[False][1], out[True][1]))
+    assert overlap >= 0.9, overlap
+    np.testing.assert_allclose(np.sort(out[False][0], 1)[:, 0],
+                               np.sort(out[True][0], 1)[:, 0], rtol=0.05,
+                               atol=0.05)
 
 
 class TestShardedIvfFlat:

@@ -81,3 +81,36 @@ class TestBenchRunner:
         cfg["algos"] = [{"name": "bogus"}]
         with pytest.raises(ValueError):
             run_benchmark(cfg, reps=1)
+
+
+class TestCompileCache:
+    """utils.compile_cache: the environment's directory wins; otherwise one
+    fixed directory inside the checkout."""
+
+    @pytest.fixture(autouse=True)
+    def _restore(self):
+        prev = jax.config.jax_compilation_cache_dir
+        yield
+        jax.config.update("jax_compilation_cache_dir", prev)
+
+    def test_env_dir_is_left_alone(self, monkeypatch, tmp_path):
+        from raft_tpu.utils import compile_cache
+
+        d = str(tmp_path / "xla")
+        monkeypatch.setenv(compile_cache.ENV, d)
+        # what JAX does with the variable at import
+        jax.config.update("jax_compilation_cache_dir", d)
+        assert compile_cache.enable_persistent_cache() == d
+        assert jax.config.jax_compilation_cache_dir == d
+
+    def test_default_is_fixed_in_checkout(self, monkeypatch):
+        import os
+
+        from raft_tpu.utils import compile_cache
+
+        monkeypatch.delenv(compile_cache.ENV, raising=False)
+        first = compile_cache.enable_persistent_cache()
+        assert compile_cache.enable_persistent_cache() == first
+        assert jax.config.jax_compilation_cache_dir == first
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert first == os.path.join(repo, ".jax_cache")

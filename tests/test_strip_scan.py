@@ -176,3 +176,25 @@ class TestStripScan:
                 )[:k]
                 np.testing.assert_allclose(v[r][: len(wv)], wv,
                                            rtol=2e-2, atol=2e-1)
+
+
+@pytest.mark.parametrize("family", ["ivf_flat", "ivf_pq"])
+def test_ragged_search_tiles_one_dispatch_each(rng, family):
+    """A search larger than one query tile runs one fused dispatch per tile
+    (strip_scan.per_tile) and answers exactly as one tile does."""
+    from raft_tpu.core.resources import Resources
+    from raft_tpu.neighbors import ivf_flat, ivf_pq
+
+    X = rng.normal(size=(3000, 16)).astype(np.float32)
+    Q = rng.normal(size=(1100, 16)).astype(np.float32)
+    mod = ivf_flat if family == "ivf_flat" else ivf_pq
+    params = (ivf_flat.IvfFlatParams(n_lists=8, group_size=512)
+              if family == "ivf_flat" else
+              ivf_pq.IvfPqParams(n_lists=8, pq_dim=8, group_size=512))
+    idx = mod.build(X, params)
+    one = mod.search(idx, Q, 10, n_probes=2, backend="ragged")
+    tiled = mod.search(idx, Q, 10, n_probes=2, backend="ragged",
+                       res=Resources(workspace_bytes=1))   # 512-query tiles
+    np.testing.assert_array_equal(np.asarray(tiled[1]), np.asarray(one[1]))
+    np.testing.assert_allclose(np.asarray(tiled[0]), np.asarray(one[0]),
+                               rtol=1e-6)
