@@ -237,11 +237,6 @@ def run_suite():
     # barriers it enables (cagra _sync) are exactly what build_phases_s
     # needs to be comparable across rounds
     obs.enable()
-    # ...but NEVER span-level sync mode: an inherited RAFT_TPU_OBS_SYNC=1
-    # would force-drain at every scan-span exit inside _time_qps's
-    # back-to-back loop, turning amortized QPS into synced per-call latency
-    # (per-batch latency already has its own dedicated pass)
-    obs.disable_sync()
 
     def latency_percentiles(hist_name):
         """p50/p90/p99 upper bounds of one batch-latency histogram, for the

@@ -33,10 +33,12 @@ _EXPORT_CALLS = {"export_jsonl", "export_chrome_trace"}
 
 def _literal_span_names(tree):
     """Yield (node, name) for every literal span name in the module: the
-    first argument of record_span(...) calls and of traced(...) decorators."""
+    first argument of record_span(...) / record_launch(...) calls and of
+    traced(...) decorators."""
     for node in ast.walk(tree):
         if isinstance(node, ast.Call) and \
-                dotted_name(node.func).rsplit(".", 1)[-1] == "record_span" \
+                dotted_name(node.func).rsplit(".", 1)[-1] in (
+                    "record_span", "record_launch") \
                 and node.args and isinstance(node.args[0], ast.Constant) \
                 and isinstance(node.args[0].value, str):
             yield node, node.args[0].value

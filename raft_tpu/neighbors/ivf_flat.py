@@ -330,13 +330,16 @@ def _lens_np(index):
 def _coarse_probes(queries, centers, n_probes, metric, select_algo, compute_dtype):
     """Stage 1 alone: each query's top-n_probes list ids (q, p) int32
     (detail/ivf_flat_search-inl.cuh:130)."""
-    if metric in ("sqeuclidean", "euclidean"):
-        coarse = dist_mod._expanded_distance(
-            queries, centers, "sqeuclidean", compute_dtype, "highest"
-        )
-    else:
-        coarse = -dist_mod.matmul_t(queries, centers, compute_dtype, "highest")
-    _, probes = select_k(coarse, n_probes, select_min=True, algo=select_algo)
+    with jax.named_scope("coarse_probe"):
+        if metric in ("sqeuclidean", "euclidean"):
+            coarse = dist_mod._expanded_distance(
+                queries, centers, "sqeuclidean", compute_dtype, "highest"
+            )
+        else:
+            coarse = -dist_mod.matmul_t(queries, centers, compute_dtype,
+                                        "highest")
+        _, probes = select_k(coarse, n_probes, select_min=True,
+                             algo=select_algo)
     return probes
 
 
@@ -355,15 +358,16 @@ def _ragged_bias(list_ids, list_norms, filter, mode: str):
 def _finalize_ragged(vals, ids, queries, metric):
     """One fused dispatch for the score finalization (each eager op here
     used to cost a runtime dispatch of its own)."""
-    if metric in ("sqeuclidean", "euclidean"):
-        vals = jnp.maximum(vals + dist_mod.sqnorm(queries)[:, None], 0.0)
-        if metric == "euclidean":
-            vals = jnp.sqrt(vals)
-        return jnp.where(ids >= 0, vals, jnp.inf), ids
-    if metric == "cosine":
-        return jnp.where(ids >= 0, 1.0 + vals, jnp.inf), ids
-    # inner_product: flip back to "larger is better" values
-    return jnp.where(ids >= 0, -vals, -jnp.inf), ids
+    with jax.named_scope("finalize"):
+        if metric in ("sqeuclidean", "euclidean"):
+            vals = jnp.maximum(vals + dist_mod.sqnorm(queries)[:, None], 0.0)
+            if metric == "euclidean":
+                vals = jnp.sqrt(vals)
+            return jnp.where(ids >= 0, vals, jnp.inf), ids
+        if metric == "cosine":
+            return jnp.where(ids >= 0, 1.0 + vals, jnp.inf), ids
+        # inner_product: flip back to "larger is better" values
+        return jnp.where(ids >= 0, -vals, -jnp.inf), ids
 
 
 @functools.partial(
@@ -471,7 +475,8 @@ def _search_ragged(index, queries, k, n_probes, filter, select_algo, res):
         index.list_ids, cls_ord, int(k), n_probes, index.metric,
         select_algo, res.compute_dtype, classes, class_counts,
         jax.default_backend() != "tpu",
-    ), queries.shape[0], q_tile)
+    ), queries.shape[0], q_tile, "ivf_flat::launch",
+        "ivf_flat.search.launches")
 
 
 @functools.partial(
@@ -654,7 +659,8 @@ def search(
     # query-tile size: the (qt, p, m, d) gather is the big intermediate
     per_query = max(1, n_probes * index.max_list_size * (index.dim + 2) * 4)
     q_tile = int(max(1, min(queries.shape[0], res.workspace_bytes // per_query)))
-    with scan_span:
+    with scan_span, obs.record_launch("ivf_flat::launch",
+                                      "ivf_flat.search.launches"):
         vals, ids = _search_impl(
             queries,
             index.centers,

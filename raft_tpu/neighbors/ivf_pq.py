@@ -1130,7 +1130,7 @@ def _search_ragged_pq(index, queries, k, n_probes, filter, select_algo, res):
         index.list_ids, index.decoded, index.decoded_scale, filter, cls_ord,
         int(k), n_probes, index.metric, select_algo, res.compute_dtype, l2,
         classes, class_counts, jax.default_backend() != "tpu",
-    ), queries.shape[0], q_tile)
+    ), queries.shape[0], q_tile, "ivf_pq::launch", "ivf_pq.search.launches")
 
 
 def _pq_probe_prep(queries, centers, rotation, n_probes, select_algo, l2,
@@ -1141,15 +1141,17 @@ def _pq_probe_prep(queries, centers, rotation, n_probes, select_algo, l2,
     plane's acceptance contract, so this math must not fork).
     ``rotation_kind`` selects the apply (ops/linalg.rotate_rows): the
     dense gemm, or the SRHT butterfly ivf_bq's Hadamard indexes carry."""
-    ip_c = dist_mod.matmul_t(queries, centers, None, "highest")
-    if l2:
-        # expanded L2 from the single gemm (review: _expanded_distance would
-        # recompute the same q×n_lists inner products)
-        coarse = (dist_mod.sqnorm(queries)[:, None]
-                  + dist_mod.sqnorm(centers)[None, :] - 2.0 * ip_c)
-    else:
-        coarse = -ip_c
-    _, probes = select_k(coarse, n_probes, select_min=True, algo=select_algo)
+    with jax.named_scope("coarse_probe"):
+        ip_c = dist_mod.matmul_t(queries, centers, None, "highest")
+        if l2:
+            # expanded L2 from the single gemm (review: _expanded_distance
+            # would recompute the same q×n_lists inner products)
+            coarse = (dist_mod.sqnorm(queries)[:, None]
+                      + dist_mod.sqnorm(centers)[None, :] - 2.0 * ip_c)
+        else:
+            coarse = -ip_c
+        _, probes = select_k(coarse, n_probes, select_min=True,
+                             algo=select_algo)
     qr = linalg.rotate_rows(queries, rotation, rotation_kind)
     alpha = -2.0 if l2 else -1.0
     pair_const = alpha * jnp.take_along_axis(ip_c, probes, axis=1)
@@ -1517,7 +1519,7 @@ def search(
                 f"multiple of 512, got {index.max_list_size}; rebuild with "
                 "group_size=512 (or use backend='pallas'/'gather')"
             )
-        # cosine included in _finalize_pq's fused dispatch
+        # cosine included in _finalize_ragged's fused dispatch
         with scan_span:
             return _search_ragged_pq(
                 index, queries, int(k), n_probes, filter, select_algo, res
@@ -1556,13 +1558,16 @@ def search(
         # take_along_axis crashes the TPU runtime.
         with scan_span:
             while True:
-                vals, ids, dropped = _search_impl_pallas(
-                    queries, index.centers, index.rotation, index.codebooks,
-                    index.list_codes, index.list_ids, index.b_sum, filter,
-                    int(k), n_probes, index.metric, int(q_tile), int(qpl_cap),
-                    select_algo, res.compute_dtype, jax.default_backend() != "tpu",
-                    index.pq_dim, index.pq_bits,
-                )
+                with obs.record_launch("ivf_pq::launch",
+                                       "ivf_pq.search.launches"):
+                    vals, ids, dropped = _search_impl_pallas(
+                        queries, index.centers, index.rotation,
+                        index.codebooks, index.list_codes, index.list_ids,
+                        index.b_sum, filter, int(k), n_probes, index.metric,
+                        int(q_tile), int(qpl_cap), select_algo,
+                        res.compute_dtype, jax.default_backend() != "tpu",
+                        index.pq_dim, index.pq_bits,
+                    )
                 dropped = int(dropped)
                 if dropped == 0:
                     break
@@ -1587,7 +1592,8 @@ def search(
         # tile budget: the (qt, p, m, s) code gather dominates
         per_query = max(1, n_probes * index.max_list_size * (index.pq_dim * 5 + 8))
         q_tile = int(max(1, min(queries.shape[0], res.workspace_bytes // per_query)))
-        with scan_span:
+        with scan_span, obs.record_launch("ivf_pq::launch",
+                                          "ivf_pq.search.launches"):
             vals, ids = _search_impl_jnp(
                 queries, index.centers, index.rotation, index.codebooks,
                 index.list_codes, index.list_ids, index.b_sum, filter,

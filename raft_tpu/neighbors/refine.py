@@ -21,7 +21,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from raft_tpu import obs
 from raft_tpu.core.resources import Resources, current_resources
+from raft_tpu.core.trace import traced
 from raft_tpu.ops import distance as dist_mod
 from raft_tpu.ops.select_k import select_k
 
@@ -30,56 +32,58 @@ SUPPORTED_METRICS = ("sqeuclidean", "euclidean", "inner_product", "cosine")
 
 @functools.partial(jax.jit, static_argnames=("k", "metric", "q_tile"))
 def _refine_impl(queries, dataset, candidates, k, metric, q_tile):
-    q, dim = queries.shape
-    n_cand = candidates.shape[1]
-    l2 = metric in ("sqeuclidean", "euclidean")
+    with jax.named_scope("refine"):
+        q, dim = queries.shape
+        n_cand = candidates.shape[1]
+        l2 = metric in ("sqeuclidean", "euclidean")
 
-    if metric == "cosine":
-        queries = queries / jnp.maximum(jnp.linalg.norm(queries, axis=1, keepdims=True), 1e-30)
-        dataset = dataset / jnp.maximum(jnp.linalg.norm(dataset, axis=1, keepdims=True), 1e-30)
-    qn = dist_mod.sqnorm(queries) if l2 else None
+        if metric == "cosine":
+            queries = queries / jnp.maximum(jnp.linalg.norm(queries, axis=1, keepdims=True), 1e-30)
+            dataset = dataset / jnp.maximum(jnp.linalg.norm(dataset, axis=1, keepdims=True), 1e-30)
+        qn = dist_mod.sqnorm(queries) if l2 else None
 
-    def one_tile(args):
-        q_blk, qn_blk, cand_blk = args
-        safe = jnp.maximum(cand_blk, 0)
-        vecs = dataset[safe].astype(jnp.float32)  # (qt, c, dim) gather
-        ip = jnp.einsum("qd,qcd->qc", q_blk, vecs, preferred_element_type=jnp.float32)
-        if l2:
-            vn = dist_mod.sqnorm(vecs, axis=2)
-            d = jnp.maximum(qn_blk[:, None] + vn - 2.0 * ip, 0.0)
-            if metric == "euclidean":
-                d = jnp.sqrt(d)
-        elif metric == "cosine":
-            d = 1.0 - ip
-        else:
-            d = -ip  # inner product: min of negated
-        d = jnp.where(cand_blk >= 0, d, jnp.inf)
-        vals, sel = select_k(d, k, select_min=True)
-        out_ids = jnp.where(jnp.isinf(vals), -1, jnp.take_along_axis(cand_blk, sel, axis=1))
-        if metric == "inner_product":
-            vals = -vals
-        return vals, out_ids
+        def one_tile(args):
+            q_blk, qn_blk, cand_blk = args
+            safe = jnp.maximum(cand_blk, 0)
+            vecs = dataset[safe].astype(jnp.float32)  # (qt, c, dim) gather
+            ip = jnp.einsum("qd,qcd->qc", q_blk, vecs, preferred_element_type=jnp.float32)
+            if l2:
+                vn = dist_mod.sqnorm(vecs, axis=2)
+                d = jnp.maximum(qn_blk[:, None] + vn - 2.0 * ip, 0.0)
+                if metric == "euclidean":
+                    d = jnp.sqrt(d)
+            elif metric == "cosine":
+                d = 1.0 - ip
+            else:
+                d = -ip  # inner product: min of negated
+            d = jnp.where(cand_blk >= 0, d, jnp.inf)
+            vals, sel = select_k(d, k, select_min=True)
+            out_ids = jnp.where(jnp.isinf(vals), -1, jnp.take_along_axis(cand_blk, sel, axis=1))
+            if metric == "inner_product":
+                vals = -vals
+            return vals, out_ids
 
-    if qn is None:
-        qn = jnp.zeros((q,), jnp.float32)
-    if q_tile >= q:
-        return one_tile((queries, qn, candidates))
-    n_tiles = -(-q // q_tile)
-    pad = n_tiles * q_tile - q
-    qp = jnp.pad(queries, ((0, pad), (0, 0)))
-    qnp = jnp.pad(qn, (0, pad))
-    cp = jnp.pad(candidates, ((0, pad), (0, 0)), constant_values=-1)
-    vals, ids = lax.map(
-        one_tile,
-        (
-            qp.reshape(n_tiles, q_tile, dim),
-            qnp.reshape(n_tiles, q_tile),
-            cp.reshape(n_tiles, q_tile, n_cand),
-        ),
-    )
-    return vals.reshape(-1, k)[:q], ids.reshape(-1, k)[:q]
+        if qn is None:
+            qn = jnp.zeros((q,), jnp.float32)
+        if q_tile >= q:
+            return one_tile((queries, qn, candidates))
+        n_tiles = -(-q // q_tile)
+        pad = n_tiles * q_tile - q
+        qp = jnp.pad(queries, ((0, pad), (0, 0)))
+        qnp = jnp.pad(qn, (0, pad))
+        cp = jnp.pad(candidates, ((0, pad), (0, 0)), constant_values=-1)
+        vals, ids = lax.map(
+            one_tile,
+            (
+                qp.reshape(n_tiles, q_tile, dim),
+                qnp.reshape(n_tiles, q_tile),
+                cp.reshape(n_tiles, q_tile, n_cand),
+            ),
+        )
+        return vals.reshape(-1, k)[:q], ids.reshape(-1, k)[:q]
 
 
+@traced("refine::refine")
 def refine(
     dataset,
     queries,
@@ -112,7 +116,9 @@ def refine(
         raise ValueError(f"k={k} out of range for n_candidates={candidates.shape[1]}")
     per_query = max(1, candidates.shape[1] * (dataset.shape[1] + 4) * 4)
     q_tile = int(max(1, min(queries.shape[0], res.workspace_bytes // per_query)))
-    return _refine_impl(queries, dataset, candidates, int(k), metric, q_tile)
+    with obs.record_launch("refine::launch", "refine.launches"):
+        return _refine_impl(queries, dataset, candidates, int(k), metric,
+                            q_tile)
 
 
 def refine_host(dataset, queries, candidates, k: int,

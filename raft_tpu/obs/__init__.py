@@ -32,10 +32,9 @@ Instrumented code gates every emission::
         obs.add("ivf_pq.search.queries", q)
 
 so the telemetry-off cost of a hot path is one function call and one branch.
-``RAFT_TPU_OBS_SYNC=1`` (or :func:`enable_sync`) opts spans into device-time
-attribution: the dispatch queue is drained at span exit so jitted phases
-report committed time, with the raw dispatch wall-clock kept as the
-``dispatch_s`` span attribute.
+A span around a jitted call times its enqueue; device time is read from a
+profiler trace, where every span also lands as a host event
+(``jax.profiler.TraceAnnotation``) on the device's clock.
 """
 
 # NOTE: obs.aggregate and obs.report are deliberately NOT imported here —
@@ -55,6 +54,7 @@ from raft_tpu.obs.registry import (
     export_jsonl,
     inc_gauge,
     observe,
+    record_launch,
     record_span,
     record_timing,
     registry,
@@ -65,12 +65,9 @@ from raft_tpu.obs.registry import (
 from raft_tpu.obs.tracing import (
     chrome_trace,
     clear_spans,
-    disable_sync,
-    enable_sync,
     export_chrome_trace,
     process_info,
     spans,
-    sync_enabled,
 )
 from raft_tpu.obs.health import MAX_TIMEOUT, HealthReport, probe
 
@@ -83,9 +80,7 @@ __all__ = [
     "chrome_trace",
     "clear_spans",
     "disable",
-    "disable_sync",
     "enable",
-    "enable_sync",
     "enabled",
     "export_chrome_trace",
     "export_jsonl",
@@ -93,6 +88,7 @@ __all__ = [
     "observe",
     "probe",
     "process_info",
+    "record_launch",
     "record_span",
     "record_timing",
     "registry",
@@ -100,6 +96,5 @@ __all__ = [
     "set_gauge",
     "snapshot",
     "spans",
-    "sync_enabled",
     "tracing",
 ]

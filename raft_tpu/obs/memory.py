@@ -41,7 +41,7 @@ __all__ = [
 
 def _live_jax():
     """The jax module ONLY when a backend is already initialized (the
-    process_info/drain_device contract: never trigger init from telemetry)."""
+    tracing.process_info contract: never trigger init from telemetry)."""
     jax = sys.modules.get("jax")
     xb = sys.modules.get("jax._src.xla_bridge")
     if jax is None or xb is None or not getattr(xb, "_backends", None):

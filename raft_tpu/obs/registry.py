@@ -17,10 +17,11 @@ When disabled, :func:`record_span` returns one shared no-op context manager
 asserted in tests) and never touches the registry.
 
 Span timings are host wall-clock around the instrumented region. JAX dispatch
-is asynchronous, so a span around a pure-dispatch region measures dispatch +
-trace/compile time, not device execution — that is the useful number for the
-wedge-hunting this layer exists for (round 5: the failure modes are
-host-side hangs, not slow kernels).
+is asynchronous, so a span around a jitted call measures its enqueue (plus
+any trace/compile), not device execution. Device time comes from a profiler
+trace: every span is also a ``TraceAnnotation``, so it lands on the
+profiler's host plane on the same clock as the device's ops, and a trace
+reader can put each device gap down to the span the host was in.
 """
 
 from __future__ import annotations
@@ -36,7 +37,6 @@ from typing import Optional
 from raft_tpu.obs import tracing as _tracing
 
 __all__ = [
-    "DISPATCH_HIST_PREFIX",
     "EXEMPLAR_CAP",
     "MetricsRegistry",
     "NOOP_SPAN",
@@ -47,9 +47,9 @@ __all__ = [
     "export_jsonl",
     "inc_gauge",
     "observe",
+    "record_launch",
     "record_span",
     "record_timing",
-    "register_dispatch_span",
     "registry",
     "reset",
     "set_gauge",
@@ -60,25 +60,6 @@ __all__ = [
 #: percentile bucket of a live latency histogram to a recent trace id
 #: without growing the snapshot unboundedly
 EXEMPLAR_CAP = 8
-
-#: histogram namespace for sync-mode committed span durations (round 15):
-#: ``dispatch.<span name>`` — the per-entry device-time fold obs/roofline
-#: pairs with its static FLOP/byte model (the ONE definition; roofline
-#: reads histograms back through it)
-DISPATCH_HIST_PREFIX = "dispatch."
-
-#: spans whose sync-mode committed durations are worth a dispatch
-#: histogram — ONLY registered device-dispatch spans fold (obs/roofline
-#: registers its entry spans at import). Folding every span would double
-#: histogram cardinality and label host-only telemetry spans as device
-#: dispatches.
-_DISPATCH_SPANS: set = set()
-
-
-def register_dispatch_span(name: str) -> None:
-    """Opt a span name into the sync-mode ``dispatch.*`` histogram fold
-    (obs/roofline does this for every entry it models)."""
-    _DISPATCH_SPANS.add(name)
 
 _enabled = os.environ.get("RAFT_TPU_OBS", "").strip().lower() in (
     "1", "true", "on", "yes",
@@ -356,10 +337,7 @@ class _Span:
 
     Exception-safe by contract: a body that raises still records its
     duration, and the span (plus a ``span.errors.{kind}`` counter) is tagged
-    with the ``resilience.classify()`` kind of the failure. Under sync mode
-    (``RAFT_TPU_OBS_SYNC=1``) the dispatch queue is force-drained at exit so
-    ``dur_s`` is committed device-inclusive time, with the raw dispatch
-    wall-clock preserved as the ``dispatch_s`` attribute."""
+    with the ``resilience.classify()`` kind of the failure."""
 
     __slots__ = ("_name", "_reg", "_t0", "_t0_epoch", "_ann", "_attrs",
                  "_ids", "_token")
@@ -390,15 +368,6 @@ class _Span:
 
     def __exit__(self, exc_type, exc, tb):
         dt = time.perf_counter() - self._t0
-        dispatch_s = None
-        if exc_type is None and _tracing.sync_enabled() and \
-                _tracing.drain_device():
-            # device-time attribution: the body's wall-clock measured only
-            # dispatch; the queue drained, so re-read — dur_s is committed.
-            # A failed/no-op drain (no live backend) records NO dispatch_s:
-            # the span must not claim attribution it didn't get
-            dispatch_s = dt
-            dt = time.perf_counter() - self._t0
         if self._ann is not None:
             self._ann.__exit__(exc_type, exc, tb)
         error = None
@@ -406,19 +375,9 @@ class _Span:
             error = _classify_error(exc)
             self._reg.add(f"span.errors.{error}")
         self._reg.record_timing(self._name, dt)
-        if dispatch_s is not None and self._name in _DISPATCH_SPANS:
-            # sync-mode device-time attribution (round 15): fold the
-            # COMMITTED duration into a per-entry histogram — until now
-            # it lived only as a span attr, so nothing could aggregate
-            # measured device time per dispatch entry. Exemplar-linked to
-            # this span's trace (the request-latency convention), so a
-            # percentile bucket dereferences to a concrete dispatch.
-            # Registered dispatch spans only (see _DISPATCH_SPANS).
-            self._reg.observe(f"{DISPATCH_HIST_PREFIX}{self._name}", dt,
-                              trace_id=self._ids[0])
         _tracing.exit_span(self._ids, self._token, name=self._name,
                            t0=self._t0_epoch, dur_s=dt, attrs=self._attrs,
-                           error=error, dispatch_s=dispatch_s)
+                           error=error)
         return False
 
 
@@ -459,6 +418,18 @@ def record_span(name: str, reg: Optional[MetricsRegistry] = None,
     if not _enabled:
         return NOOP_SPAN
     return _Span(name, reg if reg is not None else _default, attrs)
+
+
+def record_launch(span: str, counter: str):
+    """``with obs.record_launch("ivf_flat::launch",
+    "ivf_flat.search.launches"): program(...)`` — a :func:`record_span`
+    around ONE jitted program call, plus 1 on ``counter``. The span ends
+    when the call returns, so it times the enqueue, not the device; the
+    counter counts programs launched. Off: :data:`NOOP_SPAN`, no count."""
+    if not _enabled:
+        return NOOP_SPAN
+    _default.add(counter, 1)
+    return _Span(span, _default, None)
 
 
 def add(name: str, value: float = 1) -> None:

@@ -77,13 +77,13 @@ def _admission_counts(counters: dict) -> dict:
     return costmodel.admission_counts(counters)
 
 
-def _roofline_summary(snapshot: dict) -> dict:
+def _roofline_summary() -> dict:
     """The roofline plane's report section (round 15; lazy import like the
-    rest of the SLO plane): per-entry static FLOP/byte model + measured
-    fold + occupancy, against the platform peak table."""
+    rest of the SLO plane): per-entry static FLOP/byte model + occupancy,
+    against the platform peak table."""
     from raft_tpu.obs import roofline
 
-    return roofline.summary(snapshot=snapshot)
+    return roofline.summary()
 
 
 def _classified(fn, label: str, out_errors: dict):
@@ -153,10 +153,9 @@ def collect(engine=None, sampler=None, queue=None, capacity=None,
             "admission": _classified(
                 lambda: _admission_counts(counters), "admission", errors),
             # roofline plane (round 15): per-dispatch FLOP/byte model vs
-            # platform peaks + sync-mode measured durations — "is the
-            # hardware actually being used" straight from the snapshot
+            # platform peaks
             "roofline": _classified(
-                lambda: _roofline_summary(snap), "roofline", errors),
+                _roofline_summary, "roofline", errors),
             "shard_health": _classified(
                 lambda: resilience.shard_health().snapshot(),
                 "shard_health", errors),

@@ -24,21 +24,18 @@ serving scatter):
 * :func:`platform_peaks` — per-generation peak table selected by
   ``jax.devices()[0].device_kind`` (TPU v2→v6e, bf16 dense MXU peak +
   HBM bandwidth), overridable for unlisted platforms via
-  ``RAFT_TPU_OBS_PEAK_FLOPS`` / ``RAFT_TPU_OBS_PEAK_BW``; an honest CPU
-  fallback answers ``source="unknown"`` and every derived utilization is
-  marked ``peaks_unknown`` instead of being invented.
+  ``RAFT_TPU_OBS_PEAK_FLOPS`` / ``RAFT_TPU_OBS_PEAK_BW``; a CPU (or no
+  backend) answers ``source="unknown"`` and every derived utilization is
+  marked ``peaks_unknown`` instead of being invented; a TPU whose
+  ``device_kind`` the table does not list is an error.
 * :func:`utilization` — the roofline fold: time bound
   ``max(flops/peak_flops, bytes/peak_bw)``, ``bound ∈ {compute, memory,
   unknown}``, and — given a measured duration — ``achieved_gflops``,
   ``mxu_utilization``, ``hbm_bw_utilization`` and
   ``model_to_measured`` (bound/measured, ≤1 by construction; how much of
-  the gap is overhead vs the model being optimistic).
-* The measured leg rides the existing ``RAFT_TPU_OBS_SYNC`` device-time
-  attribution: sync-mode spans now fold their committed durations into
-  ``dispatch.<span>`` histograms (obs/registry), and :func:`summary`
-  pairs each noted entry with its histogram mean, so every hot entry
-  carries ``(predicted_bound_s, measured_s, mxu_utilization,
-  hbm_bw_utilization, bound)`` as gauges.
+  the gap is overhead vs the model being optimistic). Nothing here
+  measures time: a device time comes from a profiler trace (the
+  benchmark's trace reduction) and is passed in.
 * Occupancy: the three Pallas kernels expose static diagnostics from
   their OWN planning code (``strip_scan.occupancy_stats`` /
   ``bq_scan.occupancy_stats`` / ``cagra_hop.occupancy_stats``) —
@@ -77,7 +74,6 @@ __all__ = [
     "BOUND_UNKNOWN",
     "PEAK_BW_ENV",
     "PEAK_FLOPS_ENV",
-    "dispatch_histogram",
     "entries",
     "estimate_flops",
     "estimate_search_flops",
@@ -136,21 +132,24 @@ def _env_float(env: str) -> Optional[float]:
     return v if v > 0 else None
 
 
-def _device_kind() -> str:
-    """``jax.devices()[0].device_kind`` ONLY from an already-initialized
-    backend (the obs/memory ``_live_jax`` contract: a telemetry read must
-    never pay first-touch backend init — the round-5 wedge class)."""
+def _device() -> tuple:
+    """``(platform, device_kind)`` of ``jax.local_devices()[0]`` ONLY from
+    an already-initialized backend (the obs/memory ``_live_jax`` contract:
+    a telemetry read must never pay first-touch backend init — the round-5
+    wedge class); ``("", "")`` without one."""
     jax = sys.modules.get("jax")
     xb = sys.modules.get("jax._src.xla_bridge")
     if jax is None or xb is None or not getattr(xb, "_backends", None):
-        return ""
+        return "", ""
     try:
         devs = jax.local_devices()
-        return str(devs[0].device_kind) if devs else ""
+        if not devs:
+            return "", ""
+        return str(devs[0].platform), str(devs[0].device_kind)
     # a backend without device_kind is a supported state — the peaks just
     # degrade to unknown, which every consumer handles
     except Exception:  # graftlint: ignore[unclassified-except]
-        return ""
+        return "", ""
 
 
 def platform_peaks() -> dict:
@@ -158,10 +157,13 @@ def platform_peaks() -> dict:
     roofline denominators. Resolution order: the env overrides
     (``RAFT_TPU_OBS_PEAK_FLOPS`` / ``RAFT_TPU_OBS_PEAK_BW``, for unlisted
     platforms and CPU preview runs), then the per-generation table keyed
-    by ``device_kind``, else zeros with ``source="unknown"`` — utilization
-    against an invented peak would be worse than none."""
+    by ``device_kind``. A CPU (or no live backend) has no peak to hold
+    work against: zeros with ``source="unknown"``. A TPU whose
+    ``device_kind`` the table does not list raises ``ValueError`` —
+    utilization against an invented peak would be worse than none, and a
+    silent zero would hide that the table needs a row."""
     env_f, env_b = _env_float(PEAK_FLOPS_ENV), _env_float(PEAK_BW_ENV)
-    kind = _device_kind()
+    platform, kind = _device()
     if env_f and env_b:
         return {"peak_flops": env_f, "peak_bw": env_b, "source": "env",
                 "device_kind": kind}
@@ -174,6 +176,11 @@ def platform_peaks() -> dict:
         if pat in low:
             return {"peak_flops": pf, "peak_bw": pb,
                     "source": "table", "device_kind": kind}
+    if platform == "tpu":
+        raise ValueError(
+            f"no roofline peaks for TPU device_kind {kind!r}; add a row to "
+            f"obs/roofline._PEAK_TABLE or set {PEAK_FLOPS_ENV} and "
+            f"{PEAK_BW_ENV}")
     return {"peak_flops": 0.0, "peak_bw": 0.0,
             "source": "unknown", "device_kind": kind}
 
@@ -495,33 +502,6 @@ _MODELS = {
     "ivf_bq.build": _fb_ivf_bq_build,
 }
 
-#: dispatch entry → the span whose sync-mode committed durations measure
-#: it (``dispatch.<span>`` histograms, obs/registry round-15 satellite)
-_SPAN_OF = {
-    "brute_force.search": "brute_force::search",
-    "ivf_flat.search": "ivf_flat::scan",
-    "ivf_flat.paged_scan": "ivf_flat::paged_scan",
-    "ivf_flat.paged_pallas": "ivf_flat::paged_pallas",
-    "ivf_pq.search": "ivf_pq::scan",
-    "ivf_pq.paged_scan": "ivf_pq::paged_scan",
-    "ivf_pq.paged_pallas": "ivf_pq::paged_pallas",
-    "ivf_bq.search": "ivf_bq::scan",
-    "ivf_bq.paged_pallas": "ivf_bq::paged_pallas",
-    "cagra.fused_hop": "cagra::hop",
-    "serving.scatter": "serving::upsert",
-    "serving.maintenance.reencode": "serving::maintenance_recluster",
-}
-
-# opt the modeled spans into the registry's sync-mode dispatch fold —
-# only these earn `dispatch.*` histograms (folding every span would
-# double histogram cardinality and label host spans as device dispatches)
-from raft_tpu.obs.registry import register_dispatch_span as _reg_span
-
-for _span_name in set(_SPAN_OF.values()):
-    _reg_span(_span_name)
-del _reg_span
-
-
 def estimate_flops(entry: str, **shapes) -> dict:
     """Static FLOPs and bytes-moved of ONE dispatch of ``entry`` from its
     capacity-padded layout parameters — the roofline numerators. FLOPs
@@ -725,8 +705,7 @@ def note_dispatch(entry: str, shapes: dict,
                   occupancy: Optional[dict] = None) -> None:
     """Record one dispatch of ``entry`` (shape kwargs for the model, plus
     optional static occupancy stats from the kernel's planning code), so
-    :func:`summary` can pair the static model with the measured
-    ``dispatch.*`` histograms. FLOPs/bytes accumulate across dispatches
+    :func:`summary` can report the static model per entry. FLOPs/bytes accumulate across dispatches
     (mixed shapes fold to honest per-dispatch means, not last-shape
     snapshots). NOOP when telemetry is off — callers gate, and the gate
     is re-checked here so a stray call costs one branch."""
@@ -783,43 +762,21 @@ def reset() -> None:
         _DISPATCHES.clear()
 
 
-def dispatch_histogram(entry: str,
-                       snapshot: Optional[dict] = None) -> Optional[dict]:
-    """The ``dispatch.<span>`` histogram measuring ``entry`` (committed
-    sync-mode durations; obs/registry), or None when sync attribution
-    never ran for it."""
-    from raft_tpu.obs.registry import DISPATCH_HIST_PREFIX
-
-    span = _SPAN_OF.get(entry)
-    if span is None:
-        return None
-    snap = snapshot if snapshot is not None else obs.snapshot()
-    return (snap.get("histograms") or {}).get(
-        f"{DISPATCH_HIST_PREFIX}{span}")
-
-
-def summary(snapshot: Optional[dict] = None) -> dict:
+def summary() -> dict:
     """One report-ready roofline section: the platform peaks and, per
-    noted entry, the static model + measured fold + occupancy. Both legs
-    are PER-DISPATCH MEANS over the window — mean FLOPs/bytes over every
-    noted dispatch against the histogram-mean committed duration (the
-    sync-mode ``dispatch.*`` fold; ``measured_s=None`` honestly when
-    ``RAFT_TPU_OBS_SYNC`` never ran) — so mixed-shape windows (a serving
-    bucket ramp) report window-average utilization, never one shape's
-    model against another shape's time. Numeric utilizations also land
-    as ``roofline.<entry>.*`` gauges so the fleet merge carries them."""
+    noted entry, the static model + occupancy, as PER-DISPATCH MEANS over
+    every noted dispatch — so mixed-shape windows (a serving bucket ramp)
+    report a window-average model, never one shape's. No entry carries a
+    measured time (``measured_s=None``): device time is read from a
+    profiler trace, not from host spans. Numeric fields also land as
+    ``roofline.<entry>.*`` gauges so the fleet merge carries them."""
     with obs.record_span("obs.roofline::summary"):
         peaks = platform_peaks()
-        snap = snapshot if snapshot is not None else obs.snapshot()
         out_entries = {}
         for entry, rec in entries().items():
             n = rec.get("count", 0)
             if not n:
                 continue
-            h = dispatch_histogram(entry, snap)
-            measured = None
-            if h and h.get("count"):
-                measured = h["sum"] / h["count"]
             br = rec["total_bytes_read"] / n
             bw = rec["total_bytes_written"] / n
             est = {
@@ -832,7 +789,7 @@ def summary(snapshot: Optional[dict] = None) -> dict:
                     round(rec["total_flops"] / n / (br + bw), 4)
                     if br + bw else None),
             }
-            row = _fold(est, peaks, measured, rec.get("occupancy"))
+            row = _fold(est, peaks, None, rec.get("occupancy"))
             row["dispatches"] = n
             row["last_shapes"] = dict(rec.get("shapes") or {})
             out_entries[entry] = row

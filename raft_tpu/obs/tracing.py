@@ -20,15 +20,6 @@ module upgrades every enabled span into a node of a trace tree:
   resilience recovery ring — loadable directly in Perfetto
   (https://ui.perfetto.dev) or ``chrome://tracing``.
 
-**Sync mode** (``RAFT_TPU_OBS_SYNC=1`` / :func:`enable_sync`): JAX dispatch
-is asynchronous, so a span around a jitted region measures dispatch +
-trace/compile time, not device execution — systematically under-reporting
-jitted search phases. Sync mode drains every local device at span exit
-(:func:`drain_device`) and records BOTH numbers: ``dur_s`` becomes committed time, and the pre-drain
-wall-clock rides the span as the ``dispatch_s`` attribute. It costs one host
-round-trip per span, so it is OFF by default and meant for attribution runs,
-not amortized QPS measurement.
-
 Everything here is stdlib-only at import time (jax and resilience are
 reached lazily), so the module stays importable in jax-free parents.
 """
@@ -51,9 +42,6 @@ __all__ = [
     "clear_spans",
     "clock_handshake",
     "current_span",
-    "disable_sync",
-    "drain_device",
-    "enable_sync",
     "enter_span",
     "exit_span",
     "export_chrome_trace",
@@ -64,7 +52,6 @@ __all__ = [
     "reset_fleet_ids",
     "set_ring_cap",
     "spans",
-    "sync_enabled",
 ]
 
 # ---------------------------------------------------------------------------
@@ -111,56 +98,6 @@ def process_info() -> tuple:
 
 
 # ---------------------------------------------------------------------------
-# sync mode (device-time attribution)
-# ---------------------------------------------------------------------------
-
-_sync = os.environ.get("RAFT_TPU_OBS_SYNC", "").strip().lower() in (
-    "1", "true", "on", "yes",
-)
-
-
-def sync_enabled() -> bool:
-    return _sync
-
-
-def enable_sync() -> None:
-    global _sync
-    _sync = True
-
-
-def disable_sync() -> None:
-    global _sync
-    _sync = False
-
-
-def drain_device() -> bool:
-    """Force completion of everything dispatched so far on EVERY local
-    device: enqueue a trivial computation per device and wait for it. Each
-    device's stream executes in order, so its completion implies every
-    earlier dispatch on that device committed; draining only the default
-    device would let a multi-chip span's shards run on while dur_s claims
-    they committed. Returns False (and stays silent) when jax
-    has no live backend — like :func:`_jax_process_info`, this must never
-    TRIGGER backend init (a span around pure host work would otherwise pay
-    first-touch init inside telemetry teardown, the round-5 wedge class)."""
-    try:
-        jax = sys.modules.get("jax")
-        xb = sys.modules.get("jax._src.xla_bridge")
-        if jax is None or xb is None or not getattr(xb, "_backends", None):
-            return False
-        import jax.numpy as jnp
-
-        for dev in jax.local_devices():
-            x = jax.device_put(jnp.float32(0), dev) + jnp.float32(0)
-            x.block_until_ready()
-        return True
-    # a failed drain only means "no device-time attribution for this
-    # span" (the caller records no dispatch_s) — not a failure class
-    except Exception:  # graftlint: ignore[unclassified-except]
-        return False
-
-
-# ---------------------------------------------------------------------------
 # span ring + contextvar lineage
 # ---------------------------------------------------------------------------
 
@@ -179,7 +116,7 @@ def set_ring_cap(cap: int) -> None:
     """Resize the span ring at runtime (newest spans kept). The
     ``RAFT_TPU_OBS_TRACE_CAP`` env var is read once at import — a process
     that decides on a long attribution run AFTER importing raft_tpu uses
-    this instead (the runtime twin, like enable_sync for the env gate)."""
+    this instead (the runtime twin of the env var)."""
     global _SPANS
     with _LOCK:
         _SPANS = deque(_SPANS, maxlen=max(1, int(cap)))
@@ -218,8 +155,8 @@ def enter_span():
 
 
 def exit_span(ids, token, *, name: str, t0: float, dur_s: float,
-              attrs: Optional[dict] = None, error: Optional[str] = None,
-              dispatch_s: Optional[float] = None) -> dict:
+              attrs: Optional[dict] = None,
+              error: Optional[str] = None) -> dict:
     """Close a span opened by :func:`enter_span`: restore the parent context
     and append the completed record to the ring. Returns the record."""
     _current.reset(token)
@@ -237,8 +174,6 @@ def exit_span(ids, token, *, name: str, t0: float, dur_s: float,
         rec["attrs"] = dict(attrs)
     if error is not None:
         rec["error"] = error
-    if dispatch_s is not None:
-        rec["dispatch_s"] = dispatch_s
     push_span(rec)
     return rec
 
@@ -383,8 +318,6 @@ def chrome_trace(span_records: Optional[list] = None,
         args.update(rec.get("attrs") or {})
         if "error" in rec:
             args["error"] = rec["error"]
-        if "dispatch_s" in rec:
-            args["dispatch_s"] = rec["dispatch_s"]
         out.append({
             "name": rec.get("name", "?"),
             "cat": "span",

@@ -77,6 +77,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from raft_tpu import obs
+
 C = 192          # queries per strip (MXU M dim; fewer, fatter strips
                  # amortize the measured ~25 µs fixed per-strip cost;
                  # 256 measured a VMEM stack OOM at kf=40)
@@ -513,25 +515,29 @@ def _strip_tile_body(queries_mat, qids, strip_list, pair_strip, pair_slot,
     ranking, so this is exact. IVF-PQ uses it for the −2⟨q, R·c_l⟩ term so
     the int8 cache only has to carry the (much smaller) residuals."""
     n_lists, m = list_data.shape[0], list_data.shape[1]
-    a_grouped = jnp.where(
-        (qids >= 0)[:, :, None],
-        queries_mat[jnp.clip(qids, 0), :],
-        0,
-    ).astype(jnp.bfloat16)                           # (S_pad, C, dim)
-    bias3 = bias.reshape(n_lists, 1, m)
+    with jax.named_scope("strip_plan"):
+        a_grouped = jnp.where(
+            (qids >= 0)[:, :, None],
+            queries_mat[jnp.clip(qids, 0), :],
+            0,
+        ).astype(jnp.bfloat16)                       # (S_pad, C, dim)
+        bias3 = bias.reshape(n_lists, 1, m)
 
     outs_v, outs_e = [], []
-    for (w_blocks, n_sub, start, count) in class_layout:
-        ov, oe = _strip_class_call(
-            lax.slice_in_dim(strip_list, start, start + count, axis=0),
-            lax.slice_in_dim(a_grouped, start, start + count, axis=0),
-            list_data, bias3, w_blocks, n_sub, alpha, kf, interpret,
-            approx_ok,
-        )
-        outs_v.append(ov)
-        outs_e.append(oe)
-    out_v = jnp.concatenate(outs_v, axis=0) if len(outs_v) > 1 else outs_v[0]
-    out_e = jnp.concatenate(outs_e, axis=0) if len(outs_e) > 1 else outs_e[0]
+    with jax.named_scope("strip_scan"):
+        for (w_blocks, n_sub, start, count) in class_layout:
+            ov, oe = _strip_class_call(
+                lax.slice_in_dim(strip_list, start, start + count, axis=0),
+                lax.slice_in_dim(a_grouped, start, start + count, axis=0),
+                list_data, bias3, w_blocks, n_sub, alpha, kf, interpret,
+                approx_ok,
+            )
+            outs_v.append(ov)
+            outs_e.append(oe)
+        out_v = (jnp.concatenate(outs_v, axis=0) if len(outs_v) > 1
+                 else outs_v[0])
+        out_e = (jnp.concatenate(outs_e, axis=0) if len(outs_e) > 1
+                 else outs_e[0])
 
     return merge_strip_candidates(out_v, out_e, strip_list, pair_strip,
                                   pair_slot, list_ids, class_layout, k, kf,
@@ -553,46 +559,47 @@ def merge_strip_candidates(out_v, out_e, strip_list, pair_strip, pair_slot,
     class's padded count is below its region size (round-3 on-chip bug:
     recall collapsed to 0.16 while every small CPU test's buckets happened
     to equal the region size)."""
-    q, p = pair_strip.shape
-    if len(class_layout) > 1:
-        concat_starts = np.cumsum([0] + [cnt for (_, _, _, cnt)
-                                         in class_layout[:-1]])
-        deltas = np.asarray(
-            [int(cs - start) for cs, (_, _, start, _)
-             in zip(concat_starts, class_layout)], np.int32)
-        cls_idx = sum((pair_strip >= start).astype(jnp.int32)
-                      for (_, _, start, _) in class_layout[1:])
-        pair_strip_c = pair_strip + jnp.asarray(deltas)[cls_idx]
-    else:
-        pair_strip_c = pair_strip - class_layout[0][2]
-    cand_v = out_v[pair_strip_c, pair_slot]
-    if pair_const is not None:
-        cand_v = cand_v + pair_const[:, :, None]
-    cand_v = cand_v.reshape(q, p * kf)
-    cand_e = out_e[pair_strip_c, pair_slot].reshape(q, p * kf)
-    from raft_tpu.ops.select_k import iter_topk_min, iter_topk_min_packed
+    with jax.named_scope("merge"):
+        q, p = pair_strip.shape
+        if len(class_layout) > 1:
+            concat_starts = np.cumsum([0] + [cnt for (_, _, _, cnt)
+                                             in class_layout[:-1]])
+            deltas = np.asarray(
+                [int(cs - start) for cs, (_, _, start, _)
+                 in zip(concat_starts, class_layout)], np.int32)
+            cls_idx = sum((pair_strip >= start).astype(jnp.int32)
+                          for (_, _, start, _) in class_layout[1:])
+            pair_strip_c = pair_strip + jnp.asarray(deltas)[cls_idx]
+        else:
+            pair_strip_c = pair_strip - class_layout[0][2]
+        cand_v = out_v[pair_strip_c, pair_slot]
+        if pair_const is not None:
+            cand_v = cand_v + pair_const[:, :, None]
+        cand_v = cand_v.reshape(q, p * kf)
+        cand_e = out_e[pair_strip_c, pair_slot].reshape(q, p * kf)
+        from raft_tpu.ops.select_k import iter_topk_min, iter_topk_min_packed
 
-    kk = min(k, p * kf)
-    if kk <= 64 and not interpret and p * kf <= 2048:
-        # packed passes: half the VPU cost of iter_topk_min; ≤ 11 index
-        # bits keeps the perturbation ≤ 2^-12 ≈ 2.4e-4 — inside this
-        # path's bf16 score contract. Wider merges (big n_probes · kf)
-        # would dilute the value mantissa (code-review r4), so they take
-        # the exact iter passes instead.
-        vals, sel = iter_topk_min_packed(cand_v, kk)
-    elif kk <= 64 and not interpret:
-        vals, sel = iter_topk_min(cand_v, kk)
-    else:
-        nv, sel = lax.top_k(-cand_v, kk)
-        vals = -nv
-    win_list = jnp.take_along_axis(strip_list[pair_strip], sel // kf, axis=1)
-    win_off = jnp.take_along_axis(cand_e, sel, axis=1)
-    out_ids = list_ids[win_list, win_off]            # (q, kk)
-    if kk < k:
-        vals = jnp.pad(vals, ((0, 0), (0, k - kk)), constant_values=jnp.inf)
-        out_ids = jnp.pad(out_ids, ((0, 0), (0, k - kk)), constant_values=-1)
-    out_ids = jnp.where(jnp.isfinite(vals), out_ids, -1)
-    return vals, out_ids
+        kk = min(k, p * kf)
+        if kk <= 64 and not interpret and p * kf <= 2048:
+            # packed passes: half the VPU cost of iter_topk_min; ≤ 11 index
+            # bits keeps the perturbation ≤ 2^-12 ≈ 2.4e-4 — inside this
+            # path's bf16 score contract. Wider merges (big n_probes · kf)
+            # would dilute the value mantissa (code-review r4), so they take
+            # the exact iter passes instead.
+            vals, sel = iter_topk_min_packed(cand_v, kk)
+        elif kk <= 64 and not interpret:
+            vals, sel = iter_topk_min(cand_v, kk)
+        else:
+            nv, sel = lax.top_k(-cand_v, kk)
+            vals = -nv
+        win_list = jnp.take_along_axis(strip_list[pair_strip], sel // kf, axis=1)
+        win_off = jnp.take_along_axis(cand_e, sel, axis=1)
+        out_ids = list_ids[win_list, win_off]            # (q, kk)
+        if kk < k:
+            vals = jnp.pad(vals, ((0, 0), (0, k - kk)), constant_values=jnp.inf)
+            out_ids = jnp.pad(out_ids, ((0, 0), (0, k - kk)), constant_values=-1)
+        out_ids = jnp.where(jnp.isfinite(vals), out_ids, -1)
+        return vals, out_ids
 
 
 def max_class_for(dim: int) -> int:
@@ -643,50 +650,51 @@ def _plan_device(probes, cls_ord, n_lists: int,
     few-query shapes); unused slots carry qids=-1 / strip_list=-1 and are
     never read by the merge. Returns (qids, strip_list, pair_strip,
     pair_slot, counts_per_class)."""
-    q, p = probes.shape
-    qp = q * p
-    n_classes = len(region_starts)
-    flat = probes.reshape(-1)
-    order = jnp.argsort(flat, stable=True)
-    sorted_lists = flat[order]
-    # per-list pair counts from the sorted array (binary search): bincount's
-    # scatter-add measured 8 ms at 320K pairs on TPU, searchsorted ~none
-    bounds = jnp.searchsorted(sorted_lists,
-                              jnp.arange(n_lists + 1, dtype=jnp.int32))
-    r = (bounds[1:] - bounds[:-1]).astype(jnp.int32)
-    n_qc = -(-r // C)                                  # strips per list
+    with jax.named_scope("strip_plan"):
+        q, p = probes.shape
+        qp = q * p
+        n_classes = len(region_starts)
+        flat = probes.reshape(-1)
+        order = jnp.argsort(flat, stable=True)
+        sorted_lists = flat[order]
+        # per-list pair counts from the sorted array (binary search): bincount's
+        # scatter-add measured 8 ms at 320K pairs on TPU, searchsorted ~none
+        bounds = jnp.searchsorted(sorted_lists,
+                                  jnp.arange(n_lists + 1, dtype=jnp.int32))
+        r = (bounds[1:] - bounds[:-1]).astype(jnp.int32)
+        n_qc = -(-r // C)                                  # strips per list
 
-    # class-major list layout: lists sorted by (class, id); each list's
-    # strip base = its class region start + strips of earlier lists in class
-    list_order = jnp.argsort(cls_ord * n_lists
-                             + jnp.arange(n_lists, dtype=jnp.int32))
-    n_qc_sorted = n_qc[list_order]
-    csum = jnp.cumsum(n_qc_sorted) - n_qc_sorted       # exclusive, global
-    cls_sorted = cls_ord[list_order]
-    counts = jax.ops.segment_sum(n_qc_sorted, cls_sorted,
-                                 num_segments=n_classes)
-    class_first = jnp.cumsum(counts) - counts          # exclusive
-    starts = jnp.asarray(region_starts, jnp.int32)
-    base_sorted = starts[cls_sorted] + (csum - class_first[cls_sorted])
-    strip_base = jnp.zeros(n_lists, jnp.int32).at[list_order].set(
-        base_sorted.astype(jnp.int32))
+        # class-major list layout: lists sorted by (class, id); each list's
+        # strip base = its class region start + strips of earlier lists in class
+        list_order = jnp.argsort(cls_ord * n_lists
+                                 + jnp.arange(n_lists, dtype=jnp.int32))
+        n_qc_sorted = n_qc[list_order]
+        csum = jnp.cumsum(n_qc_sorted) - n_qc_sorted       # exclusive, global
+        cls_sorted = cls_ord[list_order]
+        counts = jax.ops.segment_sum(n_qc_sorted, cls_sorted,
+                                     num_segments=n_classes)
+        class_first = jnp.cumsum(counts) - counts          # exclusive
+        starts = jnp.asarray(region_starts, jnp.int32)
+        base_sorted = starts[cls_sorted] + (csum - class_first[cls_sorted])
+        strip_base = jnp.zeros(n_lists, jnp.int32).at[list_order].set(
+            base_sorted.astype(jnp.int32))
 
-    pair_off = jnp.cumsum(r) - r
-    rank = (jnp.arange(qp, dtype=jnp.int32)
-            - pair_off[sorted_lists].astype(jnp.int32))
-    ps_sorted = strip_base[sorted_lists] + rank // C
-    slot_sorted = rank % C
-    pair_strip = jnp.zeros(qp, jnp.int32).at[order].set(ps_sorted)
-    pair_slot = jnp.zeros(qp, jnp.int32).at[order].set(slot_sorted)
+        pair_off = jnp.cumsum(r) - r
+        rank = (jnp.arange(qp, dtype=jnp.int32)
+                - pair_off[sorted_lists].astype(jnp.int32))
+        ps_sorted = strip_base[sorted_lists] + rank // C
+        slot_sorted = rank % C
+        pair_strip = jnp.zeros(qp, jnp.int32).at[order].set(ps_sorted)
+        pair_slot = jnp.zeros(qp, jnp.int32).at[order].set(slot_sorted)
 
-    # padding slots = -1: the kernel skips them entirely (round-4; with the
-    # static worst-case layout the padded grid would otherwise do real work)
-    strip_list = jnp.full(s_tot, -1, jnp.int32).at[ps_sorted].set(
-        sorted_lists.astype(jnp.int32))
-    qids = jnp.full((s_tot, C), -1, jnp.int32).at[ps_sorted, slot_sorted].set(
-        (order // p).astype(jnp.int32))
-    return (qids, strip_list, pair_strip.reshape(q, p),
-            pair_slot.reshape(q, p), counts)
+        # padding slots = -1: the kernel skips them entirely (round-4; with the
+        # static worst-case layout the padded grid would otherwise do real work)
+        strip_list = jnp.full(s_tot, -1, jnp.int32).at[ps_sorted].set(
+            sorted_lists.astype(jnp.int32))
+        qids = jnp.full((s_tot, C), -1, jnp.int32).at[ps_sorted, slot_sorted].set(
+            (order // p).astype(jnp.int32))
+        return (qids, strip_list, pair_strip.reshape(q, p),
+                pair_slot.reshape(q, p), counts)
 
 
 def fit_q_tile(q: int, p: int, n_lists: int, n_classes: int, kf: int,
@@ -776,14 +784,22 @@ def strip_search_traced(queries_mat, probes, list_data, bias, list_ids,
         pair_const, approx_ok)
 
 
-def per_tile(search, q: int, q_tile: int):
+def per_tile(search, q: int, q_tile: int, span: str = "",
+             counter: str = ""):
     """``search(lo, hi)`` once per query tile ``[lo, hi)`` — one dispatch
     each — and the (vals, ids) concatenated. Tiles are equal but the
     last. (PR 21: a TPU v5e core halted in a program that scanned two
     tiles, 8,192 + 1,808 queries, for IVF-PQ and IVF-Flat alike, while
-    one 10k-query tile ran; so no program scans more than one.)"""
+    one 10k-query tile ran; so no program scans more than one.)
+
+    With ``span`` (the caller's launch span, e.g. ``"ivf_flat::launch"``)
+    each tile's call is wrapped in :func:`obs.record_launch`, which also
+    adds 1 to ``counter`` per program launched."""
     qt = -(-q // -(-q // max(int(q_tile), 1)))
-    outs = [search(s, min(q, s + qt)) for s in range(0, q, qt)]
+    outs = []
+    for s in range(0, q, qt):
+        with obs.record_launch(span, counter) if span else obs.NOOP_SPAN:
+            outs.append(search(s, min(q, s + qt)))
     if len(outs) == 1:
         return outs[0]
     return (jnp.concatenate([o[0] for o in outs], 0),

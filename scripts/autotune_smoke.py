@@ -31,7 +31,6 @@ from raft_tpu import obs  # noqa: E402
 
 def main():
     obs.enable()
-    obs.disable_sync()
     import bench
 
     out = bench._autotune_rung(tiny=True)

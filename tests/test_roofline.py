@@ -1,5 +1,5 @@
-"""Roofline plane (ISSUE 12): per-dispatch FLOP/byte model, device-time
-fold, utilization gauges, kernel occupancy.
+"""Roofline plane (ISSUE 12): per-dispatch FLOP/byte model, utilization
+gauges, kernel occupancy.
 
 Tier-1 contracts:
 
@@ -13,9 +13,9 @@ Tier-1 contracts:
   analysis lowering fabricates no unexplained retrace;
 * occupancy — exact values for a hand-built ragged layout through the
   kernels' own planning code (strip_scan / bq_scan / cagra_hop);
-* sync-mode fold (round-15 satellite) — ``RAFT_TPU_OBS_SYNC`` span exits
-  land committed durations in exemplar-linked ``dispatch.<span>``
-  histograms, which ``summary()`` pairs with the static model;
+* peaks — a TPU whose ``device_kind`` the table does not list is an
+  error; ``summary()`` reports the static model and never a measured time
+  (device time comes from a profiler trace);
 * NOOP gate — telemetry off ⇒ zero roofline work on the hot path;
 * report — ``obs.report.collect()`` carries a validating ``roofline``
   section; malformed records are flagged.
@@ -46,7 +46,6 @@ def telemetry():
         yield obs
     finally:
         obs.disable()
-        obs.disable_sync()
         obs.reset()
         obs.tracing.clear_spans()
         roofline.reset()
@@ -403,6 +402,17 @@ class TestPeaksAndBound:
         # achieved throughput needs no denominator — still reported
         assert util["achieved_gflops"] > 0
 
+    def test_unlisted_tpu_kind_is_an_error(self, monkeypatch):
+        monkeypatch.delenv(roofline.PEAK_FLOPS_ENV, raising=False)
+        monkeypatch.delenv(roofline.PEAK_BW_ENV, raising=False)
+        monkeypatch.setattr(roofline, "_device",
+                            lambda: ("tpu", "TPU v99 experimental"))
+        with pytest.raises(ValueError, match="TPU v99 experimental"):
+            roofline.platform_peaks()
+        # a listed kind still reads the table
+        monkeypatch.setattr(roofline, "_device", lambda: ("tpu", "TPU v5 lite"))
+        assert roofline.platform_peaks()["source"] == "table"
+
     def test_bound_verdicts(self, peaks_env):
         # compute-heavy: huge dim → intensity far above the 10 flop/byte
         # ridge of the synthetic peaks
@@ -554,7 +564,7 @@ class TestXlaCrossCheck:
 
 
 # ---------------------------------------------------------------------------
-# sync-mode dispatch fold (round-15 satellite) + summary + report
+# summary + report
 # ---------------------------------------------------------------------------
 
 
@@ -564,52 +574,17 @@ def _tiny_flat(rng, n=600, dim=16, n_lists=4):
         n_lists=n_lists, list_size_cap=0))
 
 
-class TestDispatchFold:
-    def test_sync_spans_land_in_dispatch_histograms(self, telemetry, rng):
-        obs.enable_sync()
-        X, idx = _tiny_flat(rng)
-        ivf_flat.search(idx, X[:4], 3, n_probes=2)
-        h = obs.snapshot()["histograms"].get("dispatch.ivf_flat::scan")
-        assert h is not None and h["count"] >= 1
-        # exemplar-linked (the request-latency convention): the bucket
-        # dereferences to the span's own trace
-        assert h.get("exemplars"), h
-        assert all(ex["trace_id"] for ex in h["exemplars"])
-        assert roofline.dispatch_histogram("ivf_flat.search") == h
-        # only REGISTERED dispatch spans fold (review r15): host-only
-        # telemetry spans (coarse_train, obs.roofline::*, build phases)
-        # must not double the histogram cardinality under sync mode
-        modeled = set(roofline._SPAN_OF.values())
-        hists = obs.snapshot()["histograms"]
-        extra = {k for k in hists if k.startswith("dispatch.")
-                 and k[len("dispatch."):] not in modeled}
-        assert not extra, extra
-
-    def test_no_sync_no_dispatch_histograms(self, telemetry, rng):
-        X, idx = _tiny_flat(rng)
-        ivf_flat.search(idx, X[:4], 3, n_probes=2)
-        hists = obs.snapshot()["histograms"]
-        assert not any(k.startswith("dispatch.") for k in hists)
-
-    def test_summary_folds_measured_leg(self, telemetry, rng, peaks_env):
-        obs.enable_sync()
-        X, idx = _tiny_flat(rng)
-        ivf_flat.search(idx, X[:4], 3, n_probes=2)
-        s = roofline.summary()
-        row = s["entries"]["ivf_flat.search"]
-        assert row["measured_s"] and row["measured_s"] > 0
-        assert row["mxu_utilization"] is not None
-        assert row["bound"] in (roofline.BOUND_COMPUTE,
-                                roofline.BOUND_MEMORY)
-        assert row["dispatches"] >= 1
-        gauges = obs.snapshot()["gauges"]
-        assert "roofline.ivf_flat.search.mxu_utilization" in gauges
-
-    def test_summary_without_sync_is_honest(self, telemetry, rng):
+class TestSummary:
+    def test_summary_has_no_measured_leg(self, telemetry, rng, peaks_env):
         X, idx = _tiny_flat(rng)
         ivf_flat.search(idx, X[:4], 3, n_probes=2)
         row = roofline.summary()["entries"]["ivf_flat.search"]
         assert row["measured_s"] is None
+        assert row["bound"] in (roofline.BOUND_COMPUTE,
+                                roofline.BOUND_MEMORY)
+        assert row["dispatches"] >= 1
+        assert not any(k.startswith("dispatch.")
+                       for k in obs.snapshot()["histograms"])
 
 
 class TestNoopGate:

@@ -3,12 +3,15 @@
 Covers: contextvar parenting + attribute round-trip, exception-safe
 classified spans (enabled AND disabled — the satellite regression test),
 the bounded ring, Chrome trace-event export from an instrumented ivf_pq
-build+search (≥3-level tree: entry → phase → tile), sync-mode device-time
-attribution, and the histogram percentile upper bounds."""
+build+search (≥3-level tree: entry → phase → tile), the launch spans and
+counters inside the search entries (entry → scan → one launch per program),
+spans on the profiler's own host plane, the device-stage ``named_scope``s
+in the lowered search program, and the histogram percentile upper
+bounds."""
 
 import json
+import re
 import threading
-import time
 
 import numpy as np
 import pytest
@@ -233,33 +236,184 @@ def test_chrome_trace_includes_resilience_instants(telemetry):
 
 
 # ---------------------------------------------------------------------------
-# sync mode (device-time attribution)
+# launch spans + counters inside the search entries
 # ---------------------------------------------------------------------------
 
 
-def test_sync_mode_records_dispatch_and_committed(telemetry):
+def _children(records, parent, name):
+    return [r for r in records
+            if r["name"] == name and r["parent_id"] == parent["span_id"]]
+
+
+def _ivf(family, rng, n=3000, dim=16):
+    from raft_tpu.neighbors import ivf_flat, ivf_pq
+
+    X = rng.normal(size=(n, dim)).astype(np.float32)
+    if family == "ivf_flat":
+        idx = ivf_flat.build(X, ivf_flat.IvfFlatParams(n_lists=8,
+                                                       group_size=512))
+        return ivf_flat, X, idx
+    idx = ivf_pq.build(X, ivf_pq.IvfPqParams(n_lists=8, pq_dim=8,
+                                             group_size=512))
+    return ivf_pq, X, idx
+
+
+@pytest.mark.parametrize("family,backend,launches", [
+    ("ivf_flat", "ragged", 2), ("ivf_flat", "gather", 1),
+    ("ivf_pq", "ragged", 2), ("ivf_pq", "gather", 1)])
+def test_search_entry_records_one_launch_span_per_program(
+        telemetry, rng, family, backend, launches):
+    """entry > scan > one ``<family>::launch`` per jitted program call, and
+    ``<family>.search.launches`` counts them: 600 queries under a 1-byte
+    workspace make two ragged tiles (512-query floor), the gather path one
+    program."""
+    from raft_tpu.core.resources import Resources
+
+    mod, X, idx = _ivf(family, rng)
+    Q = rng.normal(size=(600, 16)).astype(np.float32)
+    obs.reset()
+    obs.clear_spans()
+    mod.search(idx, Q, 5, n_probes=2, backend=backend,
+               res=Resources(workspace_bytes=1))
+    recs = obs.spans()
+    (entry,) = [r for r in recs if r["name"] == f"{family}::search"]
+    (scan,) = _children(recs, entry, f"{family}::scan")
+    assert len(_children(recs, scan, f"{family}::launch")) == launches
+    assert len([r for r in recs if r["name"] == f"{family}::launch"]) \
+        == launches
+    assert obs.snapshot()["counters"][f"{family}.search.launches"] \
+        == launches
+
+
+def test_refine_records_entry_and_launch_spans(telemetry, rng):
+    """``ivf_pq.search`` (two ragged tiles) then ``refine.refine``:
+    ``refine::refine`` > ``refine::launch``, one refine program."""
+    from raft_tpu.core.resources import Resources
+    from raft_tpu.neighbors import refine
+
+    mod, X, idx = _ivf("ivf_pq", rng)
+    Q = rng.normal(size=(600, 16)).astype(np.float32)
+    obs.reset()
+    obs.clear_spans()
+    _, cand = mod.search(idx, Q, 20, n_probes=2, backend="ragged",
+                         res=Resources(workspace_bytes=1))
+    d, ids = refine.refine(X, Q, cand, 5)
+    assert ids.shape == (600, 5)
+    recs = obs.spans()
+    (entry,) = [r for r in recs if r["name"] == "refine::refine"]
+    assert entry["parent_id"] is None
+    assert len(_children(recs, entry, "refine::launch")) == 1
+    counters = obs.snapshot()["counters"]
+    assert counters["refine.launches"] == 1
+    assert counters["ivf_pq.search.launches"] == 2
+
+
+def test_launch_spans_and_counters_off_are_noops(rng):
+    """Telemetry off: every span is the shared NOOP_SPAN and no counter
+    moves, through whole searches and refine."""
+    from raft_tpu.core.resources import Resources
+    from raft_tpu.neighbors import refine
+
+    assert not obs.enabled()
+    assert obs.record_span("ivf_flat::launch") is obs.NOOP_SPAN
+    assert obs.record_launch("ivf_flat::launch",
+                             "ivf_flat.search.launches") is obs.NOOP_SPAN
+    flat, X, fidx = _ivf("ivf_flat", rng)
+    pq, _, pidx = _ivf("ivf_pq", rng)
+    Q = rng.normal(size=(600, 16)).astype(np.float32)
+    obs.reset()
+    obs.clear_spans()
+    flat.search(fidx, Q, 5, n_probes=2, backend="ragged",
+                res=Resources(workspace_bytes=1))
+    _, cand = pq.search(pidx, Q, 20, n_probes=2, backend="ragged")
+    refine.refine(X, Q, cand, 5)
+    assert obs.snapshot()["counters"] == {}
+    assert obs.spans() == []
+
+
+def test_span_lands_on_the_profiler_host_plane(telemetry, tmp_path):
+    """A span recorded under an active ``jax.profiler`` trace is a host
+    event of the same name in the ``.xplane.pb`` — on the device trace's
+    clock, which is how the benchmark reads it."""
+    import glob
+
+    import jax
+    import jax.numpy as jnp
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with obs.record_span("unit::profiled"):
+            with obs.record_launch("unit::launch", "unit.launches"):
+                jnp.ones(8).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    names = {e.name for plane in ProfileData.from_file(path).planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for e in line.events}
+    assert {"unit::profiled", "unit::launch"} <= names
+    assert obs.snapshot()["counters"]["unit.launches"] == 1
+
+
+# ---------------------------------------------------------------------------
+# device-stage scopes in the lowered programs (HLO op_name metadata)
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def ragged_fused_hlo():
+    """HLO text, with metadata, of ``ivf_flat._ragged_fused`` lowered for
+    one small tile (interpret mode, as on the CPU)."""
+    import jax
     import jax.numpy as jnp
 
-    assert not obs.sync_enabled()
-    obs.enable_sync()
-    try:
-        with obs.record_span("unit::jitted"):
-            jnp.sum(jnp.ones((64, 64)))  # dispatched, not fetched
-    finally:
-        obs.disable_sync()
-    rec = _by_name(obs.spans())["unit::jitted"]
-    # dispatch wall-clock preserved; committed duration includes the drain
-    assert "dispatch_s" in rec
-    assert rec["dispatch_s"] <= rec["dur_s"]
-    # the registry timer carries the committed (drained) duration
-    assert obs.snapshot()["timers"]["unit::jitted"]["total_s"] == \
-        pytest.approx(rec["dur_s"])
+    from raft_tpu.neighbors import ivf_flat
+    from raft_tpu.ops import strip_scan as ss
+
+    n_lists, m, dim = 8, 512, 16
+    classes, cls_ord = ss.class_info(np.full(n_lists, 300), dim=dim)
+    f32 = jnp.float32
+    return ivf_flat._ragged_fused.lower(
+        jax.ShapeDtypeStruct((64, dim), f32),
+        jax.ShapeDtypeStruct((n_lists, dim), f32),
+        jax.ShapeDtypeStruct((n_lists, m, dim), f32),
+        jax.ShapeDtypeStruct((n_lists, m), f32),
+        jax.ShapeDtypeStruct((n_lists, m), jnp.int32),
+        jax.ShapeDtypeStruct((n_lists,), jnp.int32),
+        k=5, n_probes=2, metric="sqeuclidean", select_algo="exact",
+        compute_dtype=None, classes=tuple(classes),
+        class_counts=ss.class_counts_of(cls_ord, len(classes)),
+        interpret=True).as_text(dialect="hlo", debug_info=True)
 
 
-def test_sync_mode_off_has_no_dispatch_attr(telemetry):
-    with obs.record_span("unit::plain"):
-        time.sleep(0.001)
-    assert "dispatch_s" not in _by_name(obs.spans())["unit::plain"]
+def _has_scope(hlo: str, scope: str) -> bool:
+    """An op whose ``op_name`` names ``scope`` (a nested jit's ops carry
+    names relative to their own computation: ``coarse_probe/dot_general``;
+    inlined ones the caller's path: ``jit(_ragged_fused)/merge/add``)."""
+    return re.search(rf'op_name="(?:[^"]*/)?{scope}/', hlo) is not None
+
+
+@pytest.mark.parametrize("scope", ["coarse_probe", "strip_plan",
+                                   "strip_scan", "merge", "finalize"])
+def test_ragged_fused_carries_stage_scope(ragged_fused_hlo, scope):
+    assert _has_scope(ragged_fused_hlo, scope)
+    assert not _has_scope(ragged_fused_hlo, scope + "_absent")
+
+
+def test_refine_program_carries_refine_scope():
+    import jax
+    import jax.numpy as jnp
+
+    from raft_tpu.neighbors import refine
+
+    text = refine._refine_impl.lower(
+        jax.ShapeDtypeStruct((8, 16), jnp.float32),
+        jax.ShapeDtypeStruct((100, 16), jnp.float32),
+        jax.ShapeDtypeStruct((8, 20), jnp.int32),
+        k=5, metric="sqeuclidean", q_tile=8,
+    ).as_text(dialect="hlo", debug_info=True)
+    assert _has_scope(text, "refine")
 
 
 # ---------------------------------------------------------------------------
