@@ -468,8 +468,10 @@ def _search_ragged(index, queries, k, n_probes, filter, select_algo, res):
                             "l2" if l2 else "ip")
     classes, class_counts, cls_ord, q_tile = _ragged_plan_static(
         index, n_probes, k, res, index.dim)
-    from raft_tpu.ops.strip_scan import per_tile
+    from raft_tpu.ops.strip_scan import note_topk_routes, per_tile
 
+    if obs.enabled():
+        note_topk_routes(classes, int(k), False, queries.shape[0], q_tile)
     return per_tile(lambda lo, hi: _ragged_fused(
         queries[lo:hi], index.centers, index.list_data, bias,
         index.list_ids, cls_ord, int(k), n_probes, index.metric,

@@ -1123,8 +1123,10 @@ def _search_ragged_pq(index, queries, k, n_probes, filter, select_algo, res):
     # cache (store="cache", cache_dim < rot_dim) narrows the fetch classes
     classes, class_counts, cls_ord, q_tile = _ragged_plan_static(
         index, n_probes, k, res, int(index.decoded.shape[-1]))
-    from raft_tpu.ops.strip_scan import per_tile
+    from raft_tpu.ops.strip_scan import note_topk_routes, per_tile
 
+    if obs.enabled():
+        note_topk_routes(classes, int(k), True, queries.shape[0], q_tile)
     return per_tile(lambda lo, hi: _ragged_fused_pq(
         queries[lo:hi], index.centers, index.rotation, index.b_sum,
         index.list_ids, index.decoded, index.decoded_scale, filter, cls_ord,

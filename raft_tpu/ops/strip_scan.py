@@ -24,8 +24,8 @@ shrinks to one gather + one small select over (q, n_probes·kf).
     second grid dimension, merging running top-kf across revisits — VMEM
     stays bounded no matter the list length.
   * Per strip: one MXU matmul (C, dim) × (W, dim)ᵀ → (C, W) fp32 scores
-    (+ per-entry bias, +inf at padding), then a strided-bin tournament
-    top-k on the VPU extracts per-(query, list) top-kf values + offsets.
+    (+ per-entry bias, +inf at padding), then a top-k on the VPU
+    (_topk_block) extracts per-(query, list) top-kf values + offsets.
     A (query, probe) pair maps to exactly one strip slot, so these ARE the
     per-pair candidates — no cross-chunk reduction exists anymore.
   * The merge is one XLA gather of (q, p, kf) candidate rows followed by an
@@ -61,6 +61,14 @@ Round-4 changes (measured on the 1M bench shape):
     full-width VPU ops vs 5), which was the kernel's dominant cost.
   * Together: IVF-Flat 43K → 92K QPS, IVF-PQ 33K → 54K at unchanged
     recall (0.985), single chip, 1M × 128.
+
+Sorted-bin top-kf (``topk_route``): each direct pass is a min, a compare
+and a select over the whole (C, w) block. Where w is whole 128-lane slabs,
+a compare-exchange network sorts the 128 strided lane bins once and each
+pass reads only the head slab; exact and bit-identical to the direct
+passes. A static rule on (kf, w) picks the route, so every strip-shaped
+kernel (packed, paged, 1-bit) takes it at the same shapes; the tournament
+(``approx_ok``) is unchanged.
 """
 
 from __future__ import annotations
@@ -219,6 +227,9 @@ _PACK_BITS = 12          # low-mantissa bits carrying the column index
                          # (covers w = MAX_CLASS·MC = 4096; ≤ 2⁻¹¹ relative
                          # value perturbation — inside the bf16 contract)
 _PACK_MASK = (1 << _PACK_BITS) - 1
+_NB = 128                # lane bins of the sorted route and the tournament
+                         # (strided: bin j = cols ≡ j mod _NB — a full VPU
+                         # lane row, so the per-bin operations stay wide)
 
 
 def _pack_scores(s, w: int):
@@ -239,30 +250,30 @@ def _pack_scores(s, w: int):
     return pack_values(s, _PACK_BITS)
 
 
-def _extract_topk_packed(pv, kf: int):
-    """kf min passes over packed scores (C, n) → ((C, kf) values, (C, kf)
-    columns). Two full-width VPU ops per pass (min + mask) vs the generic
-    _extract_topk's five — the packed trick halves-to-thirds the kernel's
-    dominant cost. Values at the packing clamp are restored to +inf: a
+def _extract_passes(state, head, drop, c: int, kf: int):
+    """kf extraction passes → ((C, kf) values, (C, kf) columns).
+    ``head(state)`` is a packed block whose row minimum is the row's next
+    winner; ``drop(state, hit)`` removes it (``hit`` marks it in
+    ``head(state)``). Values at the packing clamp are restored to +inf: a
     clamped +inf sentinel (filtered/padding entry) must come back as inf,
     not as a finite ~3.4e38 hit (code-review r4)."""
-    c, n = pv.shape
     kcols = lax.broadcasted_iota(jnp.int32, (c, kf), 1)
 
     def body(i, carry):
-        pv, vals, es = carry
-        mn = jnp.min(pv, axis=1)                      # packed winner
+        state, vals, es = carry
+        h = head(state)
+        mn = jnp.min(h, axis=1)                       # packed winner
         mb = lax.bitcast_convert_type(mn, jnp.int32)
         e = mb & jnp.int32(_PACK_MASK)
         v = lax.bitcast_convert_type(mb & jnp.int32(~_PACK_MASK), jnp.float32)
         sel = kcols == i
         vals = jnp.where(sel, v[:, None], vals)
         es = jnp.where(sel, e[:, None], es)
-        return jnp.where(pv == mn[:, None], jnp.inf, pv), vals, es
+        return drop(state, h == mn[:, None]), vals, es
 
     _, vals, es = lax.fori_loop(
         0, kf, body,
-        (pv, jnp.full((c, kf), jnp.inf, jnp.float32),
+        (state, jnp.full((c, kf), jnp.inf, jnp.float32),
          jnp.zeros((c, kf), jnp.int32)),
     )
     from raft_tpu.ops.select_k import pack_clamp_for
@@ -271,6 +282,72 @@ def _extract_topk_packed(pv, kf: int):
     # unpacked winner of a clamped entry equals it exactly (static python
     # float: Mosaic rejects scalar bitcast ops in-kernel)
     return jnp.where(vals >= pack_clamp_for(_PACK_BITS), jnp.inf, vals), es
+
+
+def _extract_topk_packed(pv, kf: int):
+    """kf min passes over packed scores (C, n) → ((C, kf) values, (C, kf)
+    columns). Two full-width VPU ops per pass (min + mask) vs the generic
+    _extract_topk's five — the packed trick halves-to-thirds the kernel's
+    dominant cost."""
+    return _extract_passes(
+        pv, lambda pv: pv, lambda pv, hit: jnp.where(hit, jnp.inf, pv),
+        pv.shape[0], kf)
+
+
+@functools.lru_cache(maxsize=None)
+def _sort_pairs(n: int) -> Tuple[Tuple[int, int], ...]:
+    """Compare-exchange pairs ``(i, j)``, i < j, of Batcher's odd-even
+    merge sort of ``n`` keys, in order. Built for the next power of two
+    with the surplus keys taken as +inf at the end: a pair that touches one
+    never moves anything, so it is left out."""
+    p2 = _next_pow2(n)
+    pairs = []
+    p = 1
+    while p < p2:
+        k = p
+        while k >= 1:
+            for j in range(k % p, p2 - k, 2 * k):
+                for i in range(min(k, p2 - j - k)):
+                    a, b = i + j, i + j + k
+                    if a // (2 * p) == b // (2 * p) and b < n:
+                        pairs.append((a, b))
+            k //= 2
+        p *= 2
+    return tuple(pairs)
+
+
+def _extract_topk_sorted(pv, kf: int):
+    """Exact top-kf of packed scores (C, w), w = bs·_NB, from sorted lane
+    bins: the same (values, columns) as :func:`_extract_topk_packed`.
+
+    Bin j holds the columns ≡ j mod _NB, one per (C, _NB) slab. A
+    compare-exchange network sorts every bin along the slab axis (plain
+    elementwise min/max of slab pairs, no lane reduce) and the first
+    D = min(kf, bs) levels are kept: no bin holds more than kf winners.
+    Each pass then takes the lane-min of the head slab alone, counts the
+    hit in the winning bin's depth and puts that bin's next level into the
+    head (+inf once its D levels are spent). The levels stay put: selecting
+    the next level by depth measured 1.4-1.8x faster on a v5e than
+    shifting all D levels up every pass. Packed values are unique within a
+    row, so the winners come out in the same ascending order as the direct
+    passes, bit for bit."""
+    c, w = pv.shape
+    bs = w // _NB
+    lv = [pv[:, i * _NB:(i + 1) * _NB] for i in range(bs)]
+    for a, b in _sort_pairs(bs):
+        lv[a], lv[b] = jnp.minimum(lv[a], lv[b]), jnp.maximum(lv[a], lv[b])
+    lv = lv[:min(kf, bs)]
+
+    def drop(state, hit):
+        head, depth = state
+        depth = depth + hit.astype(jnp.int32)
+        nxt = jnp.full(head.shape, jnp.inf, jnp.float32)
+        for l in range(1, len(lv)):
+            nxt = jnp.where(depth == l, lv[l], nxt)
+        return jnp.where(hit, nxt, head), depth
+
+    return _extract_passes((lv[0], jnp.zeros(lv[0].shape, jnp.int32)),
+                           lambda state: state[0], drop, c, kf)
 
 
 def _extract_topk(v, offs, kf: int):
@@ -302,30 +379,16 @@ def _extract_topk(v, offs, kf: int):
     return vals, es
 
 
-_NB = 128   # tournament bin count (strided: bin j = cols ≡ j mod _NB —
-            # a full VPU lane row, so the per-bin reductions stay wide)
 _KEEP = 4   # per-bin survivors in the tournament pool
 
 
-def _topk_block(s, kf: int, w: int, approx_ok: bool):
-    """Top-kf of a (C, w) score block.
-
-    Direct kf masked-min passes cost kf·C·w VPU work — the kernel's
-    dominant cost at round-3 profiling. For kf ≥ 16 the block first plays a
-    tournament: keep the _KEEP smallest of each of _NB strided bins (built
-    with _KEEP passes reduced along the small axis of a (C, w/_NB, _NB)
-    view — the minor dim stays a full 128 lanes), then extract kf from the
-    _KEEP·_NB pool: (_KEEP·w + kf·_KEEP·_NB) vs kf·w work, ~1.7× at kf=40,
-    w=1024. Exact unless > _KEEP of a row's true top-kf collide in one bin
-    (entries land in bins by storage position, arbitrary w.r.t. distance —
-    a small tail event). The tournament only engages when the caller
-    declares the loss acceptable via ``approx_ok`` (ADVICE r3: IVF-PQ
-    over-fetches + exact-re-ranks, so it opts in; IVF-Flat's contract is
-    exact-within-probes, so it never takes the lossy route at any k).
-    """
-    c = s.shape[0]
+def topk_route(kf: int, w: int, approx_ok: bool = False) -> str:
+    """Which extraction :func:`_topk_block` runs on a (C, w) block:
+    ``"tournament"`` (approximate, only with ``approx_ok``), ``"sorted"``
+    or ``"direct"`` (both exact, bit-identical). A pure function of the
+    static shape, so telemetry counts the routes without the device."""
     bs = w // _NB
-    # engage when the tournament's total work (build + pool extraction)
+    # tournament: engage when its total work (build + pool extraction)
     # beats direct extraction (kf·w > _KEEP·w + kf·_KEEP·_NB) AND the
     # collision loss stays a tail event. The loss is governed by the
     # expected per-bin top-kf mass kf/_NB (width-independent!), so cap at
@@ -333,9 +396,54 @@ def _topk_block(s, kf: int, w: int, approx_ok: bool):
     # per strip row); kf ≤ bs·_KEEP additionally guarantees the pool can
     # hold kf at small widths.
     wins = kf * w > _KEEP * w + kf * _KEEP * _NB
+    if (approx_ok and 16 <= kf <= min(bs * _KEEP, _NB // 4) and bs >= 2
+            and wins):
+        return "tournament"
+    # sorted bins vs direct passes, in (C, _NB) slab operations: a direct
+    # pass is a min, a compare and a select over all bs slabs; the sorted
+    # route pays its network (a min and a max per pair) once, then per
+    # pass a compare, a depth count, a compare and a select per level
+    # below the head (D = min(kf, bs) levels) and the head's select. Each
+    # pass's one lane reduce is common to both and left out.
+    if w % _NB or bs < 2:
+        return "direct"
+    sort_ops = 2 * len(_sort_pairs(bs)) + kf * (2 * min(kf, bs) + 1)
+    return "sorted" if sort_ops < 3 * kf * bs else "direct"
+
+
+def _topk_block(s, kf: int, w: int, approx_ok: bool):
+    """Top-kf of a (C, w) score block, on the route :func:`topk_route`
+    picks from the static (kf, w).
+
+    Every route first packs each column id into its score's low mantissa
+    bits (_pack_scores), so a row minimum names its winner.
+
+    * ``direct`` (:func:`_extract_topk_packed`): kf min passes over the
+      whole block, each a min, a compare and a select over all w lanes.
+    * ``sorted`` (:func:`_extract_topk_sorted`): exact and bit-identical
+      to ``direct``. The _NB strided lane bins are sorted once by a
+      compare-exchange network, then each pass reads only the head slab.
+      It takes every shape whose w is a multiple of _NB with two or more
+      slabs, where it does less work: the cells' kf = 10 and 40 at every
+      list width, not kf = 1.
+    * ``tournament``: keep the _KEEP smallest of each of _NB strided bins
+      (built with _KEEP passes reduced along the small axis of a (C,
+      w/_NB, _NB) view), then extract kf from the _KEEP·_NB pool. Exact
+      unless > _KEEP of a row's true top-kf collide in one bin (entries
+      land in bins by storage position, arbitrary w.r.t. distance — a
+      small tail event), so it engages only for 16 ≤ kf ≤ 32 when the
+      caller declares the loss acceptable via ``approx_ok`` (ADVICE r3:
+      IVF-PQ over-fetches + exact-re-ranks, so it opts in; IVF-Flat's
+      contract is exact-within-probes, so it never takes the lossy route
+      at any k).
+    """
+    c = s.shape[0]
+    bs = w // _NB
+    route = topk_route(kf, w, approx_ok)
     pv = _pack_scores(s, w)
-    if (not approx_ok or kf < 16 or kf > min(bs * _KEEP, _NB // 4)
-            or bs < 2 or not wins):
+    if route == "sorted":
+        return _extract_topk_sorted(pv, kf)
+    if route == "direct":
         return _extract_topk_packed(pv, kf)
     # tournament on packed values: the bin survivors carry their own column
     # ids in the mantissa, so the pool extraction needs no offset tables
@@ -352,10 +460,10 @@ def _strip_kernel(sl_ref, lv_ref, a_ref, b_ref, bias_ref, outv_ref,
                   oute_ref, *, alpha, kf, w, n_sub, approx_ok):
     """One strip (× one sub-block when n_sub > 1): matmul + fused top-kf.
 
-    Scores = alpha·(A @ Bᵀ) + bias, smaller is better; the (packed)
-    tournament top-k (_topk_block) extracts per-row top-kf values and
-    within-list entry offsets. Sub-block revisits merge the running top-kf
-    via a concat + kf passes over the 2·kf-wide block.
+    Scores = alpha·(A @ Bᵀ) + bias, smaller is better; the packed top-k
+    (_topk_block, on its route for (kf, w)) extracts per-row top-kf
+    values and within-list entry offsets. Sub-block revisits merge the
+    running top-kf via a concat + kf passes over the 2·kf-wide block.
 
     Strips with ``strip_list == -1`` are static-layout padding (round-4
     sync-free planning, static_layout): the whole body is skipped via
@@ -804,6 +912,20 @@ def per_tile(search, q: int, q_tile: int, span: str = "",
         return outs[0]
     return (jnp.concatenate([o[0] for o in outs], 0),
             jnp.concatenate([o[1] for o in outs], 0))
+
+
+def note_topk_routes(classes, kf: int, approx_ok: bool, q: int,
+                     q_tile: int) -> None:
+    """Count the length-class kernel calls of the programs
+    :func:`per_tile` launches for ``q`` queries (``strip_scan.topk.calls``)
+    and those of them whose in-kernel top-kf takes the sorted route
+    (``strip_scan.topk.sorted``), from the static (kf, w) alone: no device
+    fetch. Callers gate it on ``obs.enabled()``."""
+    launches = _ceil_div(q, q_tile)
+    routes = [topk_route(kf, w_blocks * MC, approx_ok)
+              for w_blocks, _ in classes]
+    obs.add("strip_scan.topk.calls", launches * len(routes))
+    obs.add("strip_scan.topk.sorted", launches * routes.count("sorted"))
 
 
 def occupancy_stats(lens, m: int, q: int, p: int, dim: int = 0,

@@ -64,8 +64,16 @@ N_LISTS, DIM, S = 1024, 128, 96   # strips per call: a bucketed class count
     (jnp.uint8, 8, 1, 10),    # IVF-Flat over uint8 rows, 4096-row lists
     (jnp.uint8, 8, 2, 10),    # lists past the widest fetch: sub-blocks
     (jnp.int8, 1, 1, 40),     # IVF-PQ int8 decoded cache, 512-row lists
+    # the sorted top-kf route at every list width the SIFT-1M cells have
+    (jnp.uint8, 1, 1, 10),
+    (jnp.uint8, 2, 1, 10),
+    (jnp.uint8, 4, 1, 10),
+    (jnp.int8, 2, 1, 40),
+    (jnp.int8, 4, 1, 40),
+    (jnp.int8, 8, 1, 40),     # 32 slabs, 32 levels: the largest network
 ])
 def test_strip_kernel(one_chip, dtype, w_blocks, n_sub, kf):
+    assert ss.topk_route(kf, w_blocks * ss.MC) == "sorted"
     m = w_blocks * ss.MC * n_sub
     fn = functools.partial(ss._strip_class_call, w_blocks=w_blocks,
                            n_sub=n_sub, alpha=-2.0, kf=kf, interpret=False)

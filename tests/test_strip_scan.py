@@ -198,3 +198,92 @@ def test_ragged_search_tiles_one_dispatch_each(rng, family):
     np.testing.assert_array_equal(np.asarray(tiled[1]), np.asarray(one[1]))
     np.testing.assert_allclose(np.asarray(tiled[0]), np.asarray(one[0]),
                                rtol=1e-6)
+
+
+def _score_block(rng, c, w):
+    """Scores with the cases an extraction must survive: +inf padding
+    lanes, an all-+inf row, runs of equal scores, a NaN and a -inf."""
+    s = rng.standard_normal((c, w)).astype(np.float32) * 100.0
+    s[1, w - w // 3:] = np.inf           # a list shorter than the block
+    s[2] = np.inf                        # nothing rankable
+    s[3, :50] = 1.5                      # a run of equal scores
+    s[4, ::3] = -2.25                    # equal scores in every bin
+    s[5, 7] = np.nan
+    s[5, 9] = -np.inf
+    s[6, w // 2:] = 0.75                 # half the row ties
+    return s
+
+
+@pytest.mark.parametrize("w,kf", [
+    (w, kf) for w in (256, 512, 1024, 2048, 4096)
+    for kf in (1, 2, 10, 40, 129, w) if kf <= w])
+def test_sorted_topk_bit_identical_to_direct(w, kf):
+    """The sorted-bin extraction returns exactly the direct passes'
+    (values, columns) at every width and kf, including kf = w."""
+    import jax
+
+    from raft_tpu.ops import strip_scan as ss
+
+    s = _score_block(np.random.default_rng(w + kf), 16, w)
+    pv = ss._pack_scores(jnp.asarray(s), w)
+    want = jax.jit(ss._extract_topk_packed, static_argnums=1)(pv, kf)
+    got = jax.jit(ss._extract_topk_sorted, static_argnums=1)(pv, kf)
+    assert np.array_equal(np.asarray(got[0]), np.asarray(want[0]))
+    assert np.array_equal(np.asarray(got[1]), np.asarray(want[1]))
+    # the all-+inf row comes back as +inf at columns 0..kf-1 on both
+    assert np.isinf(np.asarray(got[0])[2]).all()
+
+
+@pytest.mark.parametrize("w", [512, 1024, 2048])
+def test_topk_route_rule(w):
+    """The cells' shapes (kf 10 for IVF-Flat, 40 for IVF-PQ, which allows
+    the tournament) take the sorted route at every list width; kf = 1
+    keeps the direct passes; the tournament window is unchanged."""
+    from raft_tpu.ops.strip_scan import topk_route
+
+    for kf in (10, 40):
+        for approx_ok in (False, True):
+            assert topk_route(kf, w, approx_ok) == "sorted"
+    assert topk_route(1, w) == "direct"
+    assert topk_route(32, w) == "sorted"
+    assert topk_route(32, w, approx_ok=True) == (
+        "tournament" if w >= 1024 else "sorted")   # it must beat the passes
+    assert topk_route(10, w + 64) == "direct"    # not whole lane slabs
+
+
+def test_strip_class_call_routes_agree_with_sub_blocks(rng, monkeypatch):
+    """Interpret-mode kernel at kf = 40 over lists longer than one fetch
+    (n_sub = 2), so the sub-block merge runs on top of the sorted route:
+    the same outputs, bit for bit, as the kernel forced onto the direct
+    passes."""
+    import jax
+
+    from raft_tpu.ops import strip_scan as ss
+
+    n_lists, dim, kf, w_blocks, n_sub = 3, 8, 40, 1, 2
+    m = w_blocks * MC * n_sub
+    assert ss.topk_route(kf, w_blocks * MC) == "sorted"
+    data = rng.standard_normal((n_lists, m, dim)).astype(np.float32)
+    bias = (data ** 2).sum(-1)
+    bias[0, 700:] = np.inf                    # one list ends mid-block
+    bias[1, 200:] = np.inf                    # one ends in the first block
+    bias[2, 100:300] = bias[2, 0]             # ties across columns
+    strip_list = np.array([0, 2, 1, -1], np.int32)
+    a = rng.standard_normal((4, C, dim)).astype(np.float32)
+    args = (jnp.asarray(strip_list), jnp.asarray(a, jnp.bfloat16),
+            jnp.asarray(data), jnp.asarray(bias.reshape(n_lists, 1, m)))
+    static = dict(w_blocks=w_blocks, n_sub=n_sub, alpha=-2.0, kf=kf,
+                  interpret=True)
+
+    def fresh():    # a new trace, so the route is decided again
+        return jax.jit(ss._strip_class_call.__wrapped__,
+                       static_argnames=tuple(static))
+
+    sv, se = fresh()(*args, **static)
+    monkeypatch.setattr(ss, "topk_route", lambda *a, **k: "direct")
+    dv, de = fresh()(*args, **static)
+    real = strip_list >= 0
+    assert np.array_equal(np.asarray(sv)[real], np.asarray(dv)[real])
+    assert np.array_equal(np.asarray(se)[real], np.asarray(de)[real])
+    # list 1 holds 200 rows: its strip's 40 columns lie in the first block
+    assert (np.asarray(se)[2] < 200).all()

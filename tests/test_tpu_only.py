@@ -107,3 +107,45 @@ class TestCompiledStrip:
         vg, ig = ivf_pq.search(idx, qs, 10, n_probes=16, backend="gather")
         vp, ip = ivf_pq.search(idx, qs, 10, n_probes=16, backend="pallas")
         assert _overlap(ig, ip, 10) >= 0.95
+
+
+@pytest.mark.parametrize("dtype,kf", [("uint8", 10), ("int8", 40)])
+def test_strip_kernel_topk_routes_bit_identical(monkeypatch, dtype, kf):
+    """The compiled strip kernel at a width both SIFT-1M cells scan
+    (1024-row lists, dim 128): IVF-Flat's kf = 10 over uint8 rows and
+    IVF-PQ's kf = 40 over int8 rows give the same values and columns, bit
+    for bit, on the sorted top-kf route and forced onto the direct
+    passes."""
+    import jax.numpy as jnp
+
+    from raft_tpu.ops import strip_scan as ss
+
+    rng = np.random.default_rng(kf)
+    n_lists, m, dim, s = 64, 1024, 128, 96
+    assert ss.topk_route(kf, m) == "sorted"
+    if dtype == "uint8":
+        data = rng.integers(0, 256, (n_lists, m, dim)).astype(np.uint8)
+    else:
+        data = rng.integers(-127, 128, (n_lists, m, dim)).astype(np.int8)
+    bias = (data.astype(np.float32) ** 2).sum(-1)
+    lens = rng.integers(300, m + 1, n_lists)
+    bias[np.arange(m)[None, :] >= lens[:, None]] = np.inf
+    strip_list = rng.integers(0, n_lists, s).astype(np.int32)
+    strip_list[-5:] = -1                     # static-layout padding strips
+    a = rng.normal(scale=8.0, size=(s, ss.C, dim))
+    args = (jnp.asarray(strip_list), jnp.asarray(a, jnp.bfloat16),
+            jnp.asarray(data), jnp.asarray(bias.reshape(n_lists, 1, m)))
+    static = dict(w_blocks=m // ss.MC, n_sub=1, alpha=-2.0, kf=kf,
+                  interpret=False)
+
+    def fresh():    # a new trace, so the route is decided again
+        return jax.jit(ss._strip_class_call.__wrapped__,
+                       static_argnames=tuple(static))
+
+    sv, se = fresh()(*args, **static)
+    monkeypatch.setattr(ss, "topk_route", lambda *a, **k: "direct")
+    dv, de = fresh()(*args, **static)
+    real = strip_list >= 0
+    assert np.array_equal(np.asarray(sv)[real], np.asarray(dv)[real])
+    assert np.array_equal(np.asarray(se)[real], np.asarray(de)[real])
+    assert np.isfinite(np.asarray(sv)[real]).all()

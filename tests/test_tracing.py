@@ -285,6 +285,28 @@ def test_search_entry_records_one_launch_span_per_program(
         == launches
 
 
+@pytest.mark.parametrize("family,k,sorted_route", [
+    ("ivf_flat", 10, True), ("ivf_pq", 40, True), ("ivf_flat", 1, False)])
+def test_ragged_search_counts_topk_routes(telemetry, rng, family, k,
+                                          sorted_route):
+    """Per launch, ``strip_scan.topk.calls`` counts the length-class kernel
+    calls of the class layout and ``strip_scan.topk.sorted`` those on the
+    sorted top-kf route: all of them at the cells' kf, none at kf = 1."""
+    from raft_tpu.core.resources import Resources
+
+    mod, X, idx = _ivf(family, rng)
+    Q = rng.normal(size=(600, 16)).astype(np.float32)
+    obs.reset()
+    mod.search(idx, Q, k, n_probes=2, backend="ragged",
+               res=Resources(workspace_bytes=1))
+    counters = obs.snapshot()["counters"]
+    classes = idx._ragged_static_cache[0]
+    assert counters[f"{family}.search.launches"] == 2
+    assert counters["strip_scan.topk.calls"] == 2 * len(classes) > 0
+    assert counters.get("strip_scan.topk.sorted", 0) == (
+        counters["strip_scan.topk.calls"] if sorted_route else 0)
+
+
 def test_refine_records_entry_and_launch_spans(telemetry, rng):
     """``ivf_pq.search`` (two ragged tiles) then ``refine.refine``:
     ``refine::refine`` > ``refine::launch``, one refine program."""
