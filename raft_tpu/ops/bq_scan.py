@@ -172,16 +172,19 @@ def _score_topk(a, b_packed, scale_row, bias_row, alpha: float, kf: int,
 
 
 def _bq_strip_kernel(sl_ref, lv_ref, a_ref, b_ref, scale_ref, bias_ref,
-                     outv_ref, oute_ref, *, alpha, kf, w, n_sub, approx_ok):
+                     *refs, alpha, kf, w, n_sub, start, approx_ok):
     """One strip (× one sub-block when n_sub > 1): in-VMEM unpack + MXU
     matmul + fused top-kf. Mirrors strip_scan._strip_kernel with the packed
-    B operand and the per-entry scale; padding strips (strip_list == -1)
-    skip the body via ``pl.when`` exactly like the fp kernel, and dead
-    sub-blocks (``lv_ref`` word 0: every bias lane +inf — filtered out /
-    tombstoned / padding) skip the unpack+matmul and write the all-dead
-    extraction constant on first visit (bit parity argument in
-    strip_scan._strip_kernel)."""
-    slv = sl_ref[pl.program_id(0)]
+    B operand and the per-entry scale: grid step ``i`` scans plan strip
+    ``start + i``, candidates are stored lane-dense into the tile's shared
+    buffers (``refs`` ends with the two output refs), padding strips
+    (strip_list == -1) skip the body via ``pl.when`` exactly like the fp
+    kernel, and dead sub-blocks (``lv_ref`` word 0: every bias lane +inf —
+    filtered out / tombstoned / padding) skip the unpack+matmul and write
+    the all-dead extraction constant on first visit (bit parity argument
+    in strip_scan._strip_kernel)."""
+    outv_ref, oute_ref = refs[-2:]
+    slv = sl_ref[start + pl.program_id(0)]
     j = pl.program_id(1) if n_sub > 1 else 0
     lvv = lv_ref[jnp.maximum(slv, 0) * n_sub + (j if n_sub > 1 else 0)]
 
@@ -189,48 +192,31 @@ def _bq_strip_kernel(sl_ref, lv_ref, a_ref, b_ref, scale_ref, bias_ref,
     def _compute():
         nv, ne = _score_topk(a_ref[0], b_ref[0], scale_ref[0], bias_ref[0],
                              alpha, kf, w, approx_ok)
+        ss._store_candidates(outv_ref, oute_ref, nv, ne + j * w, j, n_sub,
+                             kf)
 
-        if n_sub == 1:
-            outv_ref[0] = nv
-            oute_ref[0] = ne
-            return
-
-        ne = ne + j * w
-
-        @pl.when(j == 0)
-        def _():
-            outv_ref[0] = nv
-            oute_ref[0] = ne
-
-        @pl.when(j > 0)
-        def _():
-            cv = jnp.concatenate([outv_ref[0], nv], axis=1)   # (C, 2kf)
-            ce = jnp.concatenate([oute_ref[0], ne], axis=1)
-            mv, me = ss._extract_topk(cv, ce, kf)
-            outv_ref[0] = mv
-            oute_ref[0] = me
-
-    c = outv_ref.shape[1]
     first = (j == 0) if n_sub > 1 else True
 
     @pl.when((slv >= 0) & (lvv == 0) & first)
     def _dead_first():
-        outv_ref[0] = jnp.full((c, kf), jnp.inf, jnp.float32)
-        oute_ref[0] = lax.broadcasted_iota(jnp.int32, (c, kf), 1)
+        ss._store_dead(outv_ref, oute_ref, kf)
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("w_blocks", "n_sub", "alpha", "kf", "interpret",
-                     "approx_ok"),
+    static_argnames=("w_blocks", "n_sub", "start", "count", "alpha", "kf",
+                     "interpret", "approx_ok"),
 )
-def _bq_class_call(strip_list, a_grouped, list_codes, scale3, bias3,
-                   w_blocks: int, n_sub: int, alpha: float, kf: int,
-                   interpret: bool, approx_ok: bool = False):
-    """Run one length-class through the Pallas kernel: grid (S,) or
-    (S, n_sub) over (C, W) strips (strip_scan._strip_class_call shape, with
-    the packed B block and the scale operand)."""
-    s_pad, c, rot_dim = a_grouped.shape
+def _bq_class_call(strip_list, a_grouped, list_codes, scale3, bias3, cand,
+                   w_blocks: int, n_sub: int, start: int, count: int,
+                   alpha: float, kf: int, interpret: bool,
+                   approx_ok: bool = False):
+    """Run one length-class through the Pallas kernel: grid (count,) or
+    (count, n_sub) over (C, W) strips from plan strip ``start``, writing
+    the tile's shared candidate buffers ``cand`` (None: allocate them) —
+    strip_scan._strip_class_call's protocol, with the packed B block and
+    the scale operand."""
+    s_tot, c, rot_dim = a_grouped.shape
     w = w_blocks * MC
     nb = list_codes.shape[-1]
     n_lists = bias3.shape[0]
@@ -240,35 +226,10 @@ def _bq_class_call(strip_list, a_grouped, list_codes, scale3, bias3,
     fin = jnp.isfinite(bias3[:, 0, : n_sub * w]).reshape(n_lists, n_sub, w)
     sub_live = jnp.any(fin, axis=2).astype(jnp.int32).reshape(-1)
 
-    # padding strips: block maps collapse to constants (no refetch), outputs
-    # route to the trash row — the fp kernel's exact convention; dead
-    # sub-blocks collapse their code/scale/bias maps the same way but keep
-    # their output row (the kernel writes the all-dead constant)
-    if n_sub > 1:
-        grid = (s_pad, n_sub)
-        pad_ = lambda i, sl: sl[i] < 0
-        dead_ = lambda i, j, sl, lv: pad_(i, sl) | (
-            lv[jnp.maximum(sl[i], 0) * n_sub + j] == 0)
-        a_map = lambda i, j, sl, lv: (jnp.where(pad_(i, sl), 0, i), 0, 0)
-        b_map = lambda i, j, sl, lv: (
-            jnp.where(dead_(i, j, sl, lv), 0, jnp.maximum(sl[i], 0)),
-            jnp.where(dead_(i, j, sl, lv), 0, j), 0)
-        sb_map = lambda i, j, sl, lv: (
-            jnp.where(dead_(i, j, sl, lv), 0, jnp.maximum(sl[i], 0)), 0,
-            jnp.where(dead_(i, j, sl, lv), 0, j))
-        o_map = lambda i, j, sl, lv: (jnp.where(pad_(i, sl), s_pad, i), 0, 0)
-    else:
-        grid = (s_pad,)
-        pad_ = lambda i, sl: sl[i] < 0
-        dead_ = lambda i, sl, lv: pad_(i, sl) | (
-            lv[jnp.maximum(sl[i], 0)] == 0)
-        a_map = lambda i, sl, lv: (jnp.where(pad_(i, sl), 0, i), 0, 0)
-        b_map = lambda i, sl, lv: (
-            jnp.where(dead_(i, sl, lv), 0, jnp.maximum(sl[i], 0)), 0, 0)
-        sb_map = lambda i, sl, lv: (
-            jnp.where(dead_(i, sl, lv), 0, jnp.maximum(sl[i], 0)), 0, 0)
-        o_map = lambda i, sl, lv: (jnp.where(pad_(i, sl), s_pad, i), 0, 0)
-
+    grid, a_map, b_map, sb_map, o_map = ss._class_maps(start, count, s_tot,
+                                                       n_sub)
+    out_shape, out_specs, cand_specs, cand_ops, aliases = ss._candidate_io(
+        cand, 6, s_tot, kf, o_map)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=grid,
@@ -277,36 +238,34 @@ def _bq_class_call(strip_list, a_grouped, list_codes, scale3, bias3,
             pl.BlockSpec((1, w, nb), b_map),
             pl.BlockSpec((1, 1, w), sb_map),
             pl.BlockSpec((1, 1, w), sb_map),
-        ],
-        out_specs=[pl.BlockSpec((1, c, kf), o_map)] * 2,
+        ] + cand_specs,
+        out_specs=out_specs,
     )
-    ov, oe = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(_bq_strip_kernel, alpha=alpha, kf=kf, w=w,
-                          n_sub=n_sub, approx_ok=approx_ok),
+                          n_sub=n_sub, start=start, approx_ok=approx_ok),
         grid_spec=grid_spec,
-        out_shape=(
-            jax.ShapeDtypeStruct((s_pad + 1, c, kf), jnp.float32),
-            jax.ShapeDtypeStruct((s_pad + 1, c, kf), jnp.int32),
-        ),
+        out_shape=out_shape,
+        input_output_aliases=aliases,
         interpret=interpret,
-    )(strip_list, sub_live, a_grouped, list_codes, scale3, bias3)
-    return (lax.slice_in_dim(ov, 0, s_pad, axis=0),
-            lax.slice_in_dim(oe, 0, s_pad, axis=0))
+    )(strip_list, sub_live, a_grouped, list_codes, scale3, bias3, *cand_ops)
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("w_blocks", "n_sub", "alpha", "kf", "approx_ok"),
+    static_argnames=("w_blocks", "n_sub", "start", "count", "alpha", "kf",
+                     "approx_ok"),
 )
-def _bq_class_jnp(strip_list, a_grouped, list_codes, scale3, bias3,
-                  w_blocks: int, n_sub: int, alpha: float, kf: int,
-                  approx_ok: bool = False):
+def _bq_class_jnp(strip_list, a_grouped, list_codes, scale3, bias3, cand,
+                  w_blocks: int, n_sub: int, start: int, count: int,
+                  alpha: float, kf: int, approx_ok: bool = False):
     """Pure-jnp reference for one length-class: the SAME per-(strip,
     sub-block) op sequence as the kernel (shared :func:`_score_topk`, same
     ``_extract_topk`` sub-block merge), driven by a sequential ``lax.map``
-    over strips so memory stays bounded. Padding strips (sl < 0) compute
-    against list 0 — their outputs, like the kernel's unwritten garbage,
-    are never read by the merge."""
+    over the class's strips so memory stays bounded, written into the
+    shared candidate buffers like :func:`_bq_class_call`'s. Padding strips
+    (sl < 0) compute against list 0 — their outputs, like the kernel's
+    unwritten garbage, are never read by the merge."""
     w = w_blocks * MC
 
     def one_strip(args):
@@ -331,9 +290,16 @@ def _bq_class_jnp(strip_list, a_grouped, list_codes, scale3, bias3,
 
         init = (jnp.full((C, kf), jnp.inf, jnp.float32),
                 jnp.zeros((C, kf), jnp.int32))
-        return lax.fori_loop(0, n_sub, sub, init)
+        return tuple(x.T for x in lax.fori_loop(0, n_sub, sub, init))
 
-    return lax.map(one_strip, (strip_list, a_grouped))
+    out = lax.map(one_strip, (
+        lax.slice_in_dim(strip_list, start, start + count, axis=0),
+        lax.slice_in_dim(a_grouped, start, start + count, axis=0)))
+    if cand is None:
+        shape = (strip_list.shape[0] + 1, kf, C)
+        cand = (jnp.zeros(shape, jnp.float32), jnp.zeros(shape, jnp.int32))
+    return tuple(lax.dynamic_update_slice_in_dim(b, o, start, axis=0)
+                 for b, o in zip(cand, out))
 
 
 def _bq_tile_body(queries_rot, qids, strip_list, pair_strip, pair_slot,
@@ -355,24 +321,19 @@ def _bq_tile_body(queries_rot, qids, strip_list, pair_strip, pair_slot,
     bias3 = bias.reshape(n_lists, 1, m)
     scale3 = scale.reshape(n_lists, 1, m)
 
-    outs_v, outs_e = [], []
+    cand = None
     for (w_blocks, n_sub, start, count) in class_layout:
-        sl = lax.slice_in_dim(strip_list, start, start + count, axis=0)
-        ag = lax.slice_in_dim(a_grouped, start, start + count, axis=0)
         if impl == "pallas":
-            ov, oe = _bq_class_call(sl, ag, list_codes, scale3, bias3,
-                                    w_blocks, n_sub, alpha, kf, interpret,
-                                    approx_ok)
+            cand = _bq_class_call(strip_list, a_grouped, list_codes, scale3,
+                                  bias3, cand, w_blocks, n_sub, start, count,
+                                  alpha, kf, interpret, approx_ok)
         else:
-            ov, oe = _bq_class_jnp(sl, ag, list_codes, scale3, bias3,
-                                   w_blocks, n_sub, alpha, kf, approx_ok)
-        outs_v.append(ov)
-        outs_e.append(oe)
-    out_v = jnp.concatenate(outs_v, axis=0) if len(outs_v) > 1 else outs_v[0]
-    out_e = jnp.concatenate(outs_e, axis=0) if len(outs_e) > 1 else outs_e[0]
-    return ss.merge_strip_candidates(out_v, out_e, strip_list, pair_strip,
-                                     pair_slot, list_ids, class_layout, k,
-                                     kf, interpret, pair_const)
+            cand = _bq_class_jnp(strip_list, a_grouped, list_codes, scale3,
+                                 bias3, cand, w_blocks, n_sub, start, count,
+                                 alpha, kf, approx_ok)
+    return ss.merge_strip_candidates(*cand, strip_list, pair_strip,
+                                     pair_slot, list_ids, k, kf, interpret,
+                                     pair_const)
 
 
 def bq_strip_search_traced(queries_rot, probes, list_codes, scale, bias,
@@ -484,25 +445,8 @@ def _paged_bq_kernel(sl_ref, tbl_ref, chain_ref, lv_ref, a_ref, codes_hbm,
         bv, be = _paged_bq_score_topk(a_ref[0], code_s[...], scale_s[...],
                                       bias_s[...], nv * R, alpha, kf, w,
                                       approx_ok)
-        be = be + j * w
-
-        if n_sub == 1:
-            outv_ref[0] = bv
-            oute_ref[0] = be
-            return
-
-        @pl.when(j == 0)
-        def _():
-            outv_ref[0] = bv
-            oute_ref[0] = be
-
-        @pl.when(j > 0)
-        def _():
-            cv = jnp.concatenate([outv_ref[0], bv], axis=1)
-            ce = jnp.concatenate([oute_ref[0], be], axis=1)
-            mv, me = ss._extract_topk(cv, ce, kf)
-            outv_ref[0] = mv
-            oute_ref[0] = me
+        ss._store_candidates(outv_ref, oute_ref, bv, be + j * w, j, n_sub,
+                             kf)
 
 
 @functools.partial(
@@ -530,6 +474,8 @@ def _paged_bq_class_call(strip_list, table_flat, chain_pages, sub_live,
         o_map = lambda i, sl, tb, ch, lv: (jnp.where(sl[i] < 0, s_pad, i),
                                            0, 0)
 
+    out_shape, out_specs, _, _, _ = ss._candidate_io(None, 0, s_pad, kf,
+                                                     o_map)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=grid,
@@ -539,7 +485,7 @@ def _paged_bq_class_call(strip_list, table_flat, chain_pages, sub_live,
             pl.BlockSpec(memory_space=pltpu.ANY),
             pl.BlockSpec(memory_space=pltpu.ANY),
         ],
-        out_specs=[pl.BlockSpec((1, c, kf), o_map)] * 2,
+        out_specs=out_specs,
         scratch_shapes=[
             pltpu.VMEM((w, codes.shape[-1]), codes.dtype),
             pltpu.VMEM((1, w), jnp.float32),
@@ -549,20 +495,15 @@ def _paged_bq_class_call(strip_list, table_flat, chain_pages, sub_live,
             pltpu.SemaphoreType.DMA,
         ],
     )
-    ov, oe = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(_paged_bq_kernel, alpha=alpha, kf=kf, w=w,
                           n_sub=n_sub, ppf=ppf, page_rows=page_rows,
                           table_width=table_width, approx_ok=approx_ok),
         grid_spec=grid_spec,
-        out_shape=(
-            jax.ShapeDtypeStruct((s_pad + 1, c, kf), jnp.float32),
-            jax.ShapeDtypeStruct((s_pad + 1, c, kf), jnp.int32),
-        ),
+        out_shape=out_shape,
         interpret=interpret,
     )(strip_list, table_flat, chain_pages, sub_live, a_grouped, codes,
       scale_pool, bias_pool)
-    return (lax.slice_in_dim(ov, 0, s_pad, axis=0),
-            lax.slice_in_dim(oe, 0, s_pad, axis=0))
 
 
 @functools.partial(
@@ -615,7 +556,7 @@ def _paged_bq_class_jnp(strip_list, table_flat, chain_pages, sub_live,
 
         init = (jnp.full((C, kf), jnp.inf, jnp.float32),
                 jnp.zeros((C, kf), jnp.int32))
-        return lax.fori_loop(0, n_sub, sub, init)
+        return tuple(x.T for x in lax.fori_loop(0, n_sub, sub, init))
 
     return lax.map(one_strip, (strip_list, a_grouped))
 
@@ -665,7 +606,7 @@ def paged_bq_search_traced(queries_rot, probes, codes, scale_pool,
     out_v, out_i = [], []
     for start in range(0, q, q_tile):
         qt = min(q_tile, q - start)
-        region_starts, s_tot, layout = static_layout(
+        region_starts, s_tot, _ = static_layout(
             classes, class_counts, qt, p)
         qids, strip_list, pair_strip, pair_slot, _ = _plan_device(
             lax.slice_in_dim(probes, start, start + qt, axis=0),
@@ -685,8 +626,8 @@ def paged_bq_search_traced(queries_rot, probes, codes, scale_pool,
                     page_rows, table_width, alpha, kf, approx_ok=approx_ok,
                     **kwargs)
         v, i = ss.merge_strip_candidates(
-            ov, oe, strip_list, pair_strip, pair_slot, translator, layout,
-            k, kf, interpret,
+            ov, oe, strip_list, pair_strip, pair_slot, translator, k, kf,
+            interpret,
             None if pair_const is None
             else lax.slice_in_dim(pair_const, start, start + qt, axis=0))
         out_v.append(v)

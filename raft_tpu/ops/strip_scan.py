@@ -456,14 +456,126 @@ def _topk_block(s, kf: int, w: int, approx_ok: bool):
     return _extract_topk_packed(jnp.concatenate(pool, axis=1), kf)
 
 
-def _strip_kernel(sl_ref, lv_ref, a_ref, b_ref, bias_ref, outv_ref,
-                  oute_ref, *, alpha, kf, w, n_sub, approx_ok):
+def _store_candidates(outv_ref, oute_ref, nv, ne, j, n_sub: int, kf: int):
+    """Store one strip's (C, kf) top-kf lane-dense, as a (kf, C) block of
+    the tile's candidate buffers (the layout :func:`merge_strip_candidates`
+    reads). On a sub-block revisit (``j > 0``) the running top-kf, read
+    back in (C, kf) orientation, is merged with the new one first. Shared
+    by every strip-shaped kernel."""
+    if n_sub == 1:
+        outv_ref[0] = nv.T
+        oute_ref[0] = ne.T
+        return
+
+    @pl.when(j == 0)
+    def _():
+        outv_ref[0] = nv.T
+        oute_ref[0] = ne.T
+
+    @pl.when(j > 0)
+    def _():
+        cv = jnp.concatenate([outv_ref[0].T, nv], axis=1)      # (C, 2kf)
+        ce = jnp.concatenate([oute_ref[0].T, ne], axis=1)
+        mv, me = _extract_topk(cv, ce, kf)
+        outv_ref[0] = mv.T
+        oute_ref[0] = me.T
+
+
+def _store_dead(outv_ref, oute_ref, kf: int):
+    """The all-dead extraction result, stored lane-dense: value ``+inf`` at
+    offsets ``0..kf-1`` in column order, exactly what ``_topk_block`` /
+    ``_extract_topk`` produce for an all-+inf score block."""
+    c = outv_ref.shape[2]
+    outv_ref[0] = jnp.full((kf, c), jnp.inf, jnp.float32)
+    oute_ref[0] = lax.broadcasted_iota(jnp.int32, (kf, c), 0)
+
+
+def _candidate_io(cand, n_in: int, s_tot: int, kf: int, o_map):
+    """Outputs of a length-class call into the tile's shared candidate
+    buffers: (s_tot + 1, kf, C) f32 values and int32 offsets, a strip's
+    candidates lane-dense at its plan row, row ``s_tot`` the one trash row
+    of every padding strip. With ``cand`` None the call allocates the
+    buffers; otherwise it writes into ``cand`` in place
+    (``input_output_aliases``), so no class's candidates are sliced,
+    concatenated or copied on their way to the merge. Rows no class
+    writes stay garbage the merge never reads.
+
+    Returns (out_shape, out_specs, extra in_specs, extra operands,
+    input_output_aliases); ``n_in`` counts the call's other operands,
+    scalar prefetch included."""
+    shape = (s_tot + 1, kf, C)
+    out_shape = (jax.ShapeDtypeStruct(shape, jnp.float32),
+                 jax.ShapeDtypeStruct(shape, jnp.int32))
+    out_specs = [pl.BlockSpec((1, kf, C), o_map)] * 2
+    if cand is None:
+        return out_shape, out_specs, [], (), {}
+    return (out_shape, out_specs, [pl.BlockSpec(memory_space=pl.ANY)] * 2,
+            tuple(cand), {n_in: 0, n_in + 1: 1})
+
+
+def _class_maps(start: int, count: int, s_tot: int, n_sub: int):
+    """Grid and block index maps of one length class of the packed strip
+    kernels (fp rows here, 1-bit codes in ops/bq_scan): grid (count,) or
+    (count, n_sub), step ``i`` scanning plan strip ``start + i``, with the
+    strip table and the liveness words as scalar prefetch.
+
+    Padding strips (sl = -1, kernel-skipped) get ALL their block maps
+    collapsed to constants — consecutive identical block indices make
+    Pallas skip the refetch, so a padding step costs only grid
+    bookkeeping (~1-2 µs), not the 512 KB list DMA + output writeback.
+    Outputs for padding route to the trash row (``s_tot``) so real rows
+    are never clobbered by stale-buffer writebacks. Dead sub-blocks
+    (liveness word 0) collapse their row maps the same way — a fully
+    filtered-out list costs grid bookkeeping, not its list DMA — but keep
+    their output row: the kernel writes the all-dead extraction constant
+    on first visit (bit-parity with computing, see _strip_kernel).
+
+    Returns (grid, a_map, b_map, row_map, o_map): ``b_map`` indexes
+    (1, w, ·) blocks of list rows, ``row_map`` (1, 1, w) blocks of a
+    per-row term (bias, scale)."""
+    def parse(args):
+        if n_sub > 1:
+            i, j, sl, lv = args
+        else:
+            (i, sl, lv), j = args, 0
+        pad = sl[start + i] < 0
+        l = jnp.maximum(sl[start + i], 0)
+        return i, j, l, pad, pad | (lv[l * n_sub + j] == 0)
+
+    def a_map(*args):
+        i, _, _, pad, _ = parse(args)
+        return jnp.where(pad, 0, start + i), 0, 0
+
+    def b_map(*args):
+        _, j, l, _, dead = parse(args)
+        return jnp.where(dead, 0, l), jnp.where(dead, 0, j), 0
+
+    def row_map(*args):
+        _, j, l, _, dead = parse(args)
+        return jnp.where(dead, 0, l), 0, jnp.where(dead, 0, j)
+
+    def o_map(*args):
+        i, _, _, pad, _ = parse(args)
+        return jnp.where(pad, s_tot, start + i), 0, 0
+
+    grid = (count, n_sub) if n_sub > 1 else (count,)
+    return grid, a_map, b_map, row_map, o_map
+
+
+def _strip_kernel(sl_ref, lv_ref, a_ref, b_ref, bias_ref, *refs, alpha, kf,
+                  w, n_sub, start, approx_ok):
     """One strip (× one sub-block when n_sub > 1): matmul + fused top-kf.
 
     Scores = alpha·(A @ Bᵀ) + bias, smaller is better; the packed top-k
     (_topk_block, on its route for (kf, w)) extracts per-row top-kf
-    values and within-list entry offsets. Sub-block revisits merge the
-    running top-kf via a concat + kf passes over the 2·kf-wide block.
+    values and within-list entry offsets, stored lane-dense
+    (_store_candidates). Sub-block revisits merge the running top-kf via
+    a concat + kf passes over the 2·kf-wide block.
+
+    Grid step ``i`` scans strip ``start + i`` of the tile's plan (the
+    class's region of the whole strip table). ``refs`` ends with the two
+    output refs; before them, when the call writes into buffers an earlier
+    class allocated, the two aliased inputs, which the kernel never reads.
 
     Strips with ``strip_list == -1`` are static-layout padding (round-4
     sync-free planning, static_layout): the whole body is skipped via
@@ -477,14 +589,13 @@ def _strip_kernel(sl_ref, lv_ref, a_ref, b_ref, bias_ref, outv_ref,
     ``+inf`` (every row filtered out / tombstoned / padding). A dead
     sub-block's B/bias block maps are collapsed to block 0 (DMA skipped
     after the first fetch) and the matmul+top-k is skipped: the first
-    visit writes the all-dead extraction result directly — value ``+inf``
-    at offsets ``0..kf-1`` in column order, exactly what
-    ``_topk_block``/``_extract_topk`` produce for an all-inf score block —
+    visit writes the all-dead extraction result directly (_store_dead)
     and revisits leave the carry untouched, which is bitwise what merging
     with an all-inf block returns (ascending carry + earliest-column inf
     ties). Filtered scans therefore stay bit-identical to the
     compute-everything path while skipping dead work entirely."""
-    slv = sl_ref[pl.program_id(0)]
+    outv_ref, oute_ref = refs[-2:]
+    slv = sl_ref[start + pl.program_id(0)]
     j = pl.program_id(1) if n_sub > 1 else 0
     lvv = lv_ref[jnp.maximum(slv, 0) * n_sub + (j if n_sub > 1 else 0)]
 
@@ -496,48 +607,33 @@ def _strip_kernel(sl_ref, lv_ref, a_ref, b_ref, bias_ref, outv_ref,
                             preferred_element_type=jnp.float32)
         s = alpha * s + bias_ref[0]                    # (C, w)
         nv, ne = _topk_block(s, kf, w, approx_ok)      # (C, kf) each
-
-        if n_sub == 1:
-            outv_ref[0] = nv
-            oute_ref[0] = ne
-            return
-
-        ne = ne + j * w
-
-        @pl.when(j == 0)
-        def _():
-            outv_ref[0] = nv
-            oute_ref[0] = ne
-
-        @pl.when(j > 0)
-        def _():
-            cv = jnp.concatenate([outv_ref[0], nv], axis=1)    # (C, 2kf)
-            ce = jnp.concatenate([oute_ref[0], ne], axis=1)
-            mv, me = _extract_topk(cv, ce, kf)
-            outv_ref[0] = mv
-            oute_ref[0] = me
+        _store_candidates(outv_ref, oute_ref, nv, ne + j * w, j, n_sub, kf)
 
     # dead sub-block, first visit: write the all-inf extraction constant
     # (revisits skip — the carry IS the merge result, see docstring)
-    c = outv_ref.shape[1]
     first = (j == 0) if n_sub > 1 else True
 
     @pl.when((slv >= 0) & (lvv == 0) & first)
     def _dead_first():
-        outv_ref[0] = jnp.full((c, kf), jnp.inf, jnp.float32)
-        oute_ref[0] = lax.broadcasted_iota(jnp.int32, (c, kf), 1)
+        _store_dead(outv_ref, oute_ref, kf)
 
 
 @functools.partial(
     jax.jit,
-    static_argnames=("w_blocks", "n_sub", "alpha", "kf", "interpret",
-                     "approx_ok"),
+    static_argnames=("w_blocks", "n_sub", "start", "count", "alpha", "kf",
+                     "interpret", "approx_ok"),
 )
-def _strip_class_call(strip_list, a_grouped, list_data, bias3,
-                      w_blocks: int, n_sub: int, alpha: float, kf: int,
-                      interpret: bool, approx_ok: bool = False):
-    """Run one length-class: grid (S,) or (S, n_sub) over (C, W) strips."""
-    s_pad, c, dim = a_grouped.shape
+def _strip_class_call(strip_list, a_grouped, list_data, bias3, cand,
+                      w_blocks: int, n_sub: int, start: int, count: int,
+                      alpha: float, kf: int, interpret: bool,
+                      approx_ok: bool = False):
+    """Run one length-class, the ``count`` strips of the plan from
+    ``start``: grid (count,) or (count, n_sub) over (C, W) strips.
+    ``strip_list`` and ``a_grouped`` are the whole tile's; ``cand`` is
+    the tile's candidate buffers pair, or None for the first class
+    (:func:`_candidate_io`). Returns the buffers with this class's strips
+    written."""
+    s_tot, c, dim = a_grouped.shape
     w = w_blocks * MC
     n_lists = bias3.shape[0]
 
@@ -549,42 +645,10 @@ def _strip_class_call(strip_list, a_grouped, list_data, bias3,
     fin = jnp.isfinite(bias3[:, 0, : n_sub * w]).reshape(n_lists, n_sub, w)
     sub_live = jnp.any(fin, axis=2).astype(jnp.int32).reshape(-1)
 
-    # Padding strips (sl = -1, kernel-skipped) get ALL their block maps
-    # collapsed to constants — consecutive identical block indices make
-    # Pallas skip the refetch, so a padding step costs only grid
-    # bookkeeping (~1-2 µs), not the 512 KB list DMA + output writeback.
-    # Outputs for padding route to a dedicated trash row (s_pad) so real
-    # rows are never clobbered by stale-buffer writebacks. Dead sub-blocks
-    # (sub_live == 0) collapse their B/bias maps the same way — a fully
-    # filtered-out list costs grid bookkeeping, not its list DMA — but
-    # keep their output row: the kernel writes the all-dead extraction
-    # constant on first visit (bit-parity with computing, see
-    # _strip_kernel).
-    if n_sub > 1:
-        grid = (s_pad, n_sub)
-        pad_ = lambda i, sl: sl[i] < 0
-        dead_ = lambda i, j, sl, lv: pad_(i, sl) | (
-            lv[jnp.maximum(sl[i], 0) * n_sub + j] == 0)
-        a_map = lambda i, j, sl, lv: (jnp.where(pad_(i, sl), 0, i), 0, 0)
-        b_map = lambda i, j, sl, lv: (
-            jnp.where(dead_(i, j, sl, lv), 0, jnp.maximum(sl[i], 0)),
-            jnp.where(dead_(i, j, sl, lv), 0, j), 0)
-        bias_map = lambda i, j, sl, lv: (
-            jnp.where(dead_(i, j, sl, lv), 0, jnp.maximum(sl[i], 0)), 0,
-            jnp.where(dead_(i, j, sl, lv), 0, j))
-        o_map = lambda i, j, sl, lv: (jnp.where(pad_(i, sl), s_pad, i), 0, 0)
-    else:
-        grid = (s_pad,)
-        pad_ = lambda i, sl: sl[i] < 0
-        dead_ = lambda i, sl, lv: pad_(i, sl) | (
-            lv[jnp.maximum(sl[i], 0)] == 0)
-        a_map = lambda i, sl, lv: (jnp.where(pad_(i, sl), 0, i), 0, 0)
-        b_map = lambda i, sl, lv: (
-            jnp.where(dead_(i, sl, lv), 0, jnp.maximum(sl[i], 0)), 0, 0)
-        bias_map = lambda i, sl, lv: (
-            jnp.where(dead_(i, sl, lv), 0, jnp.maximum(sl[i], 0)), 0, 0)
-        o_map = lambda i, sl, lv: (jnp.where(pad_(i, sl), s_pad, i), 0, 0)
-
+    grid, a_map, b_map, bias_map, o_map = _class_maps(start, count, s_tot,
+                                                     n_sub)
+    out_shape, out_specs, cand_specs, cand_ops, aliases = _candidate_io(
+        cand, 5, s_tot, kf, o_map)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=grid,
@@ -592,21 +656,17 @@ def _strip_class_call(strip_list, a_grouped, list_data, bias3,
             pl.BlockSpec((1, c, dim), a_map),
             pl.BlockSpec((1, w, dim), b_map),
             pl.BlockSpec((1, 1, w), bias_map),
-        ],
-        out_specs=[pl.BlockSpec((1, c, kf), o_map)] * 2,
+        ] + cand_specs,
+        out_specs=out_specs,
     )
-    ov, oe = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(_strip_kernel, alpha=alpha, kf=kf, w=w, n_sub=n_sub,
-                          approx_ok=approx_ok),
+                          start=start, approx_ok=approx_ok),
         grid_spec=grid_spec,
-        out_shape=(
-            jax.ShapeDtypeStruct((s_pad + 1, c, kf), jnp.float32),
-            jax.ShapeDtypeStruct((s_pad + 1, c, kf), jnp.int32),
-        ),
+        out_shape=out_shape,
+        input_output_aliases=aliases,
         interpret=interpret,
-    )(strip_list, sub_live, a_grouped, list_data, bias3)
-    return (lax.slice_in_dim(ov, 0, s_pad, axis=0),
-            lax.slice_in_dim(oe, 0, s_pad, axis=0))
+    )(strip_list, sub_live, a_grouped, list_data, bias3, *cand_ops)
 
 
 def _strip_tile_body(queries_mat, qids, strip_list, pair_strip, pair_slot,
@@ -631,60 +691,38 @@ def _strip_tile_body(queries_mat, qids, strip_list, pair_strip, pair_slot,
         ).astype(jnp.bfloat16)                       # (S_pad, C, dim)
         bias3 = bias.reshape(n_lists, 1, m)
 
-    outs_v, outs_e = [], []
+    cand = None
     with jax.named_scope("strip_scan"):
         for (w_blocks, n_sub, start, count) in class_layout:
-            ov, oe = _strip_class_call(
-                lax.slice_in_dim(strip_list, start, start + count, axis=0),
-                lax.slice_in_dim(a_grouped, start, start + count, axis=0),
-                list_data, bias3, w_blocks, n_sub, alpha, kf, interpret,
-                approx_ok,
+            cand = _strip_class_call(
+                strip_list, a_grouped, list_data, bias3, cand, w_blocks,
+                n_sub, start, count, alpha, kf, interpret, approx_ok,
             )
-            outs_v.append(ov)
-            outs_e.append(oe)
-        out_v = (jnp.concatenate(outs_v, axis=0) if len(outs_v) > 1
-                 else outs_v[0])
-        out_e = (jnp.concatenate(outs_e, axis=0) if len(outs_e) > 1
-                 else outs_e[0])
 
-    return merge_strip_candidates(out_v, out_e, strip_list, pair_strip,
-                                  pair_slot, list_ids, class_layout, k, kf,
-                                  interpret, pair_const)
+    return merge_strip_candidates(*cand, strip_list, pair_strip, pair_slot,
+                                  list_ids, k, kf, interpret, pair_const)
 
 
-def merge_strip_candidates(out_v, out_e, strip_list, pair_strip, pair_slot,
-                           list_ids, class_layout, k: int, kf: int,
-                           interpret: bool, pair_const=None):
+def merge_strip_candidates(cand_v, cand_e, strip_list, pair_strip, pair_slot,
+                           list_ids, k: int, kf: int, interpret: bool,
+                           pair_const=None):
     """The two-gather candidate merge shared by every strip-shaped engine
-    (the fp B-operand kernel here and the packed 1-bit kernel in
-    ops/bq_scan.py — one copy, so the remap/select/translate protocol
+    (the fp B-operand kernel here, the paged kernels and the packed 1-bit
+    kernels in ops/bq_scan.py — one copy, so the select/translate protocol
     cannot drift between them).
 
-    pair_strip uses the PLAN's strip numbering (device plans leave gaps
-    between class regions); the class outputs are concatenated densely —
-    remap by the static per-class delta (identity for gap-free host
-    plans). Without this the merge reads the wrong rows whenever a
-    class's padded count is below its region size (round-3 on-chip bug:
-    recall collapsed to 0.16 while every small CPU test's buckets happened
-    to equal the region size)."""
+    ``cand_v`` / ``cand_e`` (S, kf, C) hold each strip's per-slot top-kf
+    values and in-list offsets lane-dense, at the strip's row of the
+    plan (trailing rows past the plan, such as the trash row, are never
+    read): pair (query, probe) reads column ``pair_slot`` of row
+    ``pair_strip``. Candidates are ordered probe-major, then by rank."""
     with jax.named_scope("merge"):
         q, p = pair_strip.shape
-        if len(class_layout) > 1:
-            concat_starts = np.cumsum([0] + [cnt for (_, _, _, cnt)
-                                             in class_layout[:-1]])
-            deltas = np.asarray(
-                [int(cs - start) for cs, (_, _, start, _)
-                 in zip(concat_starts, class_layout)], np.int32)
-            cls_idx = sum((pair_strip >= start).astype(jnp.int32)
-                          for (_, _, start, _) in class_layout[1:])
-            pair_strip_c = pair_strip + jnp.asarray(deltas)[cls_idx]
-        else:
-            pair_strip_c = pair_strip - class_layout[0][2]
-        cand_v = out_v[pair_strip_c, pair_slot]
+        cand = cand_v[pair_strip, :, pair_slot]            # (q, p, kf)
         if pair_const is not None:
-            cand_v = cand_v + pair_const[:, :, None]
-        cand_v = cand_v.reshape(q, p * kf)
-        cand_e = out_e[pair_strip_c, pair_slot].reshape(q, p * kf)
+            cand = cand + pair_const[:, :, None]
+        cand_v = cand.reshape(q, p * kf)
+        cand_e = cand_e[pair_strip, :, pair_slot].reshape(q, p * kf)
         from raft_tpu.ops.select_k import iter_topk_min, iter_topk_min_packed
 
         kk = min(k, p * kf)
@@ -1127,25 +1165,7 @@ def _paged_strip_kernel(sl_ref, tbl_ref, chain_ref, lv_ref, a_ref, pages_hbm,
     def _compute():
         bv, be = _paged_score_topk(a_ref[0], pay_s[...], bias_s[...],
                                    nv * R, alpha, kf, w, approx_ok)
-        be = be + j * w
-
-        if n_sub == 1:
-            outv_ref[0] = bv
-            oute_ref[0] = be
-            return
-
-        @pl.when(j == 0)
-        def _():
-            outv_ref[0] = bv
-            oute_ref[0] = be
-
-        @pl.when(j > 0)
-        def _():
-            cv = jnp.concatenate([outv_ref[0], bv], axis=1)   # (C, 2kf)
-            ce = jnp.concatenate([oute_ref[0], be], axis=1)
-            mv, me = _extract_topk(cv, ce, kf)
-            outv_ref[0] = mv
-            oute_ref[0] = me
+        _store_candidates(outv_ref, oute_ref, bv, be + j * w, j, n_sub, kf)
 
 
 @functools.partial(
@@ -1162,7 +1182,8 @@ def _paged_class_call(strip_list, table_flat, chain_pages, sub_live,
     ANY) and are fetched per grid step by the kernel's own DMAs.
     ``sub_live`` (n_lists·n_sub,) int32 carries the per-sub-block
     filter-liveness words (0 ⇒ the kernel issues no page DMAs and skips
-    ranking for that block)."""
+    ranking for that block). Returns the (S + 1, kf, C) candidate buffers
+    of :func:`merge_strip_candidates`."""
     s_pad, c, dim = a_grouped.shape
     w = ppf * page_rows
 
@@ -1178,6 +1199,7 @@ def _paged_class_call(strip_list, table_flat, chain_pages, sub_live,
         o_map = lambda i, sl, tb, ch, lv: (jnp.where(sl[i] < 0, s_pad, i),
                                            0, 0)
 
+    out_shape, out_specs, _, _, _ = _candidate_io(None, 0, s_pad, kf, o_map)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=grid,
@@ -1186,7 +1208,7 @@ def _paged_class_call(strip_list, table_flat, chain_pages, sub_live,
             pl.BlockSpec(memory_space=pltpu.ANY),
             pl.BlockSpec(memory_space=pltpu.ANY),
         ],
-        out_specs=[pl.BlockSpec((1, c, kf), o_map)] * 2,
+        out_specs=out_specs,
         scratch_shapes=[
             pltpu.VMEM((w, pages.shape[-1]), pages.dtype),
             pltpu.VMEM((1, w), jnp.float32),
@@ -1194,20 +1216,15 @@ def _paged_class_call(strip_list, table_flat, chain_pages, sub_live,
             pltpu.SemaphoreType.DMA,
         ],
     )
-    ov, oe = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(_paged_strip_kernel, alpha=alpha, kf=kf, w=w,
                           n_sub=n_sub, ppf=ppf, page_rows=page_rows,
                           table_width=table_width, approx_ok=approx_ok),
         grid_spec=grid_spec,
-        out_shape=(
-            jax.ShapeDtypeStruct((s_pad + 1, c, kf), jnp.float32),
-            jax.ShapeDtypeStruct((s_pad + 1, c, kf), jnp.int32),
-        ),
+        out_shape=out_shape,
         interpret=interpret,
     )(strip_list, table_flat, chain_pages, sub_live, a_grouped, pages,
       bias_pool)
-    return (lax.slice_in_dim(ov, 0, s_pad, axis=0),
-            lax.slice_in_dim(oe, 0, s_pad, axis=0))
 
 
 @functools.partial(
@@ -1223,9 +1240,10 @@ def _paged_class_jnp(strip_list, table_flat, chain_pages, sub_live,
     sub-block) op sequence as the kernel — shared :func:`_paged_score_topk`,
     same ``_extract_topk`` sub-block merge, same skip predicate for dead
     sub-blocks (chain-exhausted OR filter-dead ``sub_live`` word) — driven
-    by a sequential ``lax.map`` over strips. This IS the jnp gather path
-    of the paged engine: pages are fetched with jnp advanced indexing and
-    scored identically, so tier-1 pins bitwise (ids + values) parity
+    by a sequential ``lax.map`` over strips, each strip's candidates
+    stored lane-dense (S, kf, C) like the kernel's. This IS the jnp gather
+    path of the paged engine: pages are fetched with jnp advanced indexing
+    and scored identically, so tier-1 pins bitwise (ids + values) parity
     against the kernel."""
     w = ppf * page_rows
     table2 = table_flat.reshape(-1, table_width)
@@ -1265,7 +1283,7 @@ def _paged_class_jnp(strip_list, table_flat, chain_pages, sub_live,
 
         init = (jnp.full((C, kf), jnp.inf, jnp.float32),
                 jnp.zeros((C, kf), jnp.int32))
-        return lax.fori_loop(0, n_sub, sub, init)
+        return tuple(x.T for x in lax.fori_loop(0, n_sub, sub, init))
 
     return lax.map(one_strip, (strip_list, a_grouped))
 
@@ -1350,7 +1368,7 @@ def paged_strip_search_traced(queries_mat, probes, pages, bias_pool,
     out_v, out_i = [], []
     for start in range(0, q, q_tile):
         qt = min(q_tile, q - start)
-        region_starts, s_tot, layout = static_layout(
+        region_starts, s_tot, _ = static_layout(
             classes, class_counts, qt, p)
         qids, strip_list, pair_strip, pair_slot, _ = _plan_device(
             lax.slice_in_dim(probes, start, start + qt, axis=0),
@@ -1368,8 +1386,8 @@ def paged_strip_search_traced(queries_mat, probes, pages, bias_pool,
                     a_grouped, pages, bias_pool, ppf, n_sub, page_rows,
                     table_width, alpha, kf, approx_ok=approx_ok, **kwargs)
         v, i = merge_strip_candidates(
-            ov, oe, strip_list, pair_strip, pair_slot, translator, layout,
-            k, kf, interpret,
+            ov, oe, strip_list, pair_strip, pair_slot, translator, k, kf,
+            interpret,
             None if pair_const is None
             else lax.slice_in_dim(pair_const, start, start + qt, axis=0))
         out_v.append(v)

@@ -52,19 +52,29 @@ def run_both(queries, probes, codes, scale, bias, ids, lens, k,
              alpha=-2.0, pair_const=None):
     """The packed kernel (interpret) and the jnp reference on identical
     plan inputs — the planning is shared, only the per-strip engine
-    differs."""
+    differs. Beside each (vals, ids), ``outs["cand"][impl]`` keeps the
+    (strip_list, values, offsets) candidate buffers the merge read."""
+    from unittest import mock
+
     lens_np = np.asarray(lens)
     classes, cls_ord_np = ss.class_info(lens_np, dim=queries.shape[1])
     class_counts = ss.class_counts_of(cls_ord_np, len(classes))
-    outs = {}
+    outs = {"cand": {}}
+    merge = ss.merge_strip_candidates
     for impl in ("pallas", "jnp"):
-        outs[impl] = bq_strip_search_traced(
-            jnp.asarray(queries), jnp.asarray(probes), jnp.asarray(codes),
-            jnp.asarray(scale), jnp.asarray(bias), jnp.asarray(ids),
-            jnp.asarray(cls_ord_np), tuple(classes), class_counts,
-            int(k), int(k), float(alpha), queries.shape[0], True,
-            None if pair_const is None else jnp.asarray(pair_const),
-            False, impl)
+        def spy(cand_v, cand_e, strip_list, *rest, impl=impl):
+            outs["cand"][impl] = (strip_list, cand_v, cand_e)
+            return merge(cand_v, cand_e, strip_list, *rest)
+
+        with mock.patch.object(ss, "merge_strip_candidates", spy):
+            outs[impl] = bq_strip_search_traced(
+                jnp.asarray(queries), jnp.asarray(probes),
+                jnp.asarray(codes), jnp.asarray(scale), jnp.asarray(bias),
+                jnp.asarray(ids), jnp.asarray(cls_ord_np), tuple(classes),
+                class_counts, int(k), int(k), float(alpha),
+                queries.shape[0], True,
+                None if pair_const is None else jnp.asarray(pair_const),
+                False, impl)
     return outs
 
 
@@ -73,6 +83,14 @@ def assert_bit_parity(outs):
     np.testing.assert_array_equal(np.asarray(i1), np.asarray(i2))
     # BIT-identical distances: same dtypes, same op sequence, same order
     np.testing.assert_array_equal(np.asarray(v1), np.asarray(v2))
+    # and the lane-dense (S + 1, kf, C) candidates of every real strip
+    (sl, kv, ke), (_, rv, re) = outs["cand"]["pallas"], outs["cand"]["jnp"]
+    real = np.asarray(sl) >= 0
+    assert kv.shape == rv.shape == (len(real) + 1, kv.shape[1], ss.C)
+    np.testing.assert_array_equal(np.asarray(kv)[:-1][real],
+                                  np.asarray(rv)[:-1][real])
+    np.testing.assert_array_equal(np.asarray(ke)[:-1][real],
+                                  np.asarray(re)[:-1][real])
 
 
 class TestPackLayout:

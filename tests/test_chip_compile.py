@@ -73,13 +73,24 @@ N_LISTS, DIM, S = 1024, 128, 96   # strips per call: a bucketed class count
     (jnp.int8, 8, 1, 40),     # 32 slabs, 32 levels: the largest network
 ])
 def test_strip_kernel(one_chip, dtype, w_blocks, n_sub, kf):
+    """Two classes of one tile: the first call allocates the lane-dense
+    candidate buffers, the second writes its strips into them in place."""
     assert ss.topk_route(kf, w_blocks * ss.MC) == "sorted"
     m = w_blocks * ss.MC * n_sub
-    fn = functools.partial(ss._strip_class_call, w_blocks=w_blocks,
-                           n_sub=n_sub, alpha=-2.0, kf=kf, interpret=False)
-    _compile(fn, one_chip,
-             ((S,), jnp.int32), ((S, ss.C, DIM), jnp.bfloat16),
-             ((N_LISTS, m, DIM), dtype), ((N_LISTS, 1, m), jnp.float32))
+    call = functools.partial(ss._strip_class_call, w_blocks=w_blocks,
+                             n_sub=n_sub, alpha=-2.0, kf=kf, interpret=False)
+
+    def two_classes(strip_list, a_grouped, list_data, bias3):
+        cand = call(strip_list, a_grouped, list_data, bias3, None, start=0,
+                    count=S // 2)
+        return call(strip_list, a_grouped, list_data, bias3, cand,
+                    start=S // 2, count=S // 2)
+
+    compiled = _compile(two_classes, one_chip,
+                        ((S,), jnp.int32), ((S, ss.C, DIM), jnp.bfloat16),
+                        ((N_LISTS, m, DIM), dtype),
+                        ((N_LISTS, 1, m), jnp.float32))
+    assert compiled.as_text().count("tpu_custom_call") >= 2
 
 
 def test_paged_kernel(one_chip):
@@ -98,8 +109,9 @@ def test_paged_kernel(one_chip):
 
 def test_bq_kernel(one_chip):
     m, kf, nb = 4096, 40, DIM // 8
-    fn = functools.partial(bq_scan._bq_class_call, w_blocks=8, n_sub=1,
-                           alpha=-2.0, kf=kf, interpret=False)
+    fn = functools.partial(bq_scan._bq_class_call, cand=None, w_blocks=8,
+                           n_sub=1, start=0, count=S, alpha=-2.0, kf=kf,
+                           interpret=False)
     _compile(fn, one_chip,
              ((S,), jnp.int32), ((S, ss.C, DIM), jnp.bfloat16),
              ((N_LISTS, m, nb), jnp.uint8), ((N_LISTS, 1, m), jnp.float32),

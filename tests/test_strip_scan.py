@@ -152,10 +152,9 @@ class TestStripScan:
         assert overlap >= 0.9
 
     def test_multi_class_region_remap(self, rng):
-        """Regression: device plans leave gaps between class regions; the
-        merge must remap into the densely concatenated kernel outputs.
-        Needs per-class padded counts BELOW the region size to trigger
-        (n_lists large relative to per-class strip counts)."""
+        """Regression (round 3): the merge must read each class's
+        candidates at the strips' plan rows, whatever the per-class padded
+        counts. Needs many lists relative to the per-class strip counts."""
         n_lists, dim, q, k = 300, 8, 200, 5
         lens = np.where(np.arange(n_lists) % 2 == 0, 100, 900)  # 2 classes
         data, bias, ids = make_lists(rng, n_lists, dim, lens)
@@ -253,9 +252,9 @@ def test_topk_route_rule(w):
 
 def test_strip_class_call_routes_agree_with_sub_blocks(rng, monkeypatch):
     """Interpret-mode kernel at kf = 40 over lists longer than one fetch
-    (n_sub = 2), so the sub-block merge runs on top of the sorted route:
-    the same outputs, bit for bit, as the kernel forced onto the direct
-    passes."""
+    (n_sub = 2), so the sub-block merge runs on top of the sorted route,
+    as two class calls sharing one candidate buffer: the same outputs, bit
+    for bit, as the kernel forced onto the direct passes."""
     import jax
 
     from raft_tpu.ops import strip_scan as ss
@@ -275,15 +274,245 @@ def test_strip_class_call_routes_agree_with_sub_blocks(rng, monkeypatch):
     static = dict(w_blocks=w_blocks, n_sub=n_sub, alpha=-2.0, kf=kf,
                   interpret=True)
 
-    def fresh():    # a new trace, so the route is decided again
-        return jax.jit(ss._strip_class_call.__wrapped__,
-                       static_argnames=tuple(static))
+    def run():      # a new trace, so the route is decided again
+        call = jax.jit(ss._strip_class_call.__wrapped__,
+                       static_argnames=tuple(static) + ("start", "count"))
+        cand = call(*args, None, start=0, count=2, **static)
+        return call(*args, cand, start=2, count=2, **static)
 
-    sv, se = fresh()(*args, **static)
+    sv, se = run()
     monkeypatch.setattr(ss, "topk_route", lambda *a, **k: "direct")
-    dv, de = fresh()(*args, **static)
+    dv, de = run()
+    assert sv.shape == (len(strip_list) + 1, kf, C)
     real = strip_list >= 0
-    assert np.array_equal(np.asarray(sv)[real], np.asarray(dv)[real])
-    assert np.array_equal(np.asarray(se)[real], np.asarray(de)[real])
+    assert np.array_equal(np.asarray(sv)[:-1][real], np.asarray(dv)[:-1][real])
+    assert np.array_equal(np.asarray(se)[:-1][real], np.asarray(de)[:-1][real])
     # list 1 holds 200 rows: its strip's 40 columns lie in the first block
     assert (np.asarray(se)[2] < 200).all()
+
+
+def _multi_class_index(rng, dtype):
+    """Lists in five length classes (w = 512 … 4,096 rows, one list past
+    the widest fetch, so n_sub = 2), rows and bias as the IVF cells store
+    them; queries and probes that leave padding strips in every class."""
+    import raft_tpu.ops.strip_scan as ss
+
+    lens = np.array([300, 900, 1800, 3000, 5000, 100, 700, 40])
+    n_lists, dim, m = len(lens), 16, 16 * MC
+    if dtype == "uint8":
+        data = rng.integers(0, 256, (n_lists, m, dim)).astype(np.uint8)
+    else:
+        data = rng.integers(-127, 128, (n_lists, m, dim)).astype(np.int8)
+    bias = (data.astype(np.float32) ** 2).sum(-1)
+    live = np.arange(m)[None, :] < lens[:, None]
+    bias[~live] = np.inf
+    ids = np.where(live, np.arange(n_lists * m).reshape(n_lists, m), -1)
+    classes, cls_ord = ss.class_info(lens, dim=dim)
+    assert len(classes) == 5 and (8, 2) in classes
+    q, p = 37, 3
+    queries = rng.normal(scale=40.0, size=(q, dim)).astype(np.float32)
+    probes = np.stack([rng.choice(n_lists, p, replace=False)
+                       for _ in range(q)]).astype(np.int32)
+    return (queries, probes, data, bias, ids.astype(np.int32), lens,
+            tuple(classes), cls_ord)
+
+
+def _oracle_class_scan(strip_list, a_grouped, data, bias, w, n_sub, kf,
+                       alpha):
+    """One class's (S_c, C, kf) candidates by a plain jnp scan: a lax.map
+    over its strips of the kernel's per-block compute."""
+    from jax import lax
+
+    import raft_tpu.ops.strip_scan as ss
+
+    def one_strip(args):
+        sl, a = args
+        l = jnp.maximum(sl, 0)
+
+        def sub(j, carry):
+            blk = lax.dynamic_slice_in_dim(data[l], j * w, w, axis=0)
+            bi = lax.dynamic_slice_in_dim(bias[l], j * w, w)[None, :]
+            s = lax.dot_general(a, ss._to_bf16(blk), (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+            nv, ne = ss._topk_block(alpha * s + bi, kf, w, False)
+            ne = ne + j * w
+            if n_sub == 1:
+                return nv, ne
+            mv, me = ss._extract_topk(
+                jnp.concatenate([carry[0], nv], axis=1),
+                jnp.concatenate([carry[1], ne], axis=1), kf)
+            return jnp.where(j == 0, nv, mv), jnp.where(j == 0, ne, me)
+
+        init = (jnp.full((C, kf), jnp.inf, jnp.float32),
+                jnp.zeros((C, kf), jnp.int32))
+        return lax.fori_loop(0, n_sub, sub, init)
+
+    return lax.map(one_strip, (strip_list, a_grouped))
+
+
+def _strip_search_oracle(queries, probes, data, bias, ids, classes, cls_ord,
+                         k, kf, alpha):
+    """The strip search with each class's (S, C, kf) candidates computed
+    by a plain jnp scan (_oracle_class_scan), then sliced, concatenated
+    and gathered by pair: the hand-off as it stood before the shared
+    lane-dense buffer."""
+    import functools
+
+    import jax
+    from jax import lax
+
+    import raft_tpu.ops.strip_scan as ss
+
+    q, p = probes.shape
+    n_lists = data.shape[0]
+    counts = ss.class_counts_of(cls_ord, len(classes))
+    starts, s_tot, layout = ss.static_layout(classes, counts, q, p)
+    qids, strip_list, pair_strip, pair_slot, _ = ss._plan_device(
+        jnp.asarray(probes), jnp.asarray(cls_ord), n_lists, starts, s_tot)
+    a_grouped = jnp.where((qids >= 0)[:, :, None],
+                          jnp.asarray(queries)[jnp.clip(qids, 0)],
+                          0).astype(jnp.bfloat16)
+    scan = jax.jit(functools.partial(_oracle_class_scan, kf=kf, alpha=alpha),
+                   static_argnames=("w", "n_sub"))
+    outs = [scan(strip_list[start:start + count],
+                 a_grouped[start:start + count], jnp.asarray(data),
+                 jnp.asarray(bias), w=w_blocks * MC, n_sub=n_sub)
+            for (w_blocks, n_sub, start, count) in layout]
+    out_v = jnp.concatenate([o[0] for o in outs])     # (S, C, kf)
+    out_e = jnp.concatenate([o[1] for o in outs])
+    cand_v = out_v[pair_strip, pair_slot].reshape(q, p * kf)
+    cand_e = out_e[pair_strip, pair_slot].reshape(q, p * kf)
+    nv, sel = lax.top_k(-cand_v, k)
+    vals = -nv
+    win_list = jnp.take_along_axis(strip_list[pair_strip], sel // kf, axis=1)
+    win_off = jnp.take_along_axis(cand_e, sel, axis=1)
+    out_ids = jnp.asarray(ids)[win_list, win_off]
+    return vals, jnp.where(jnp.isfinite(vals), out_ids, -1)
+
+
+@pytest.mark.parametrize("dtype,kf", [("uint8", 10), ("int8", 40)])
+def test_strip_search_traced_matches_oracle_bitwise(dtype, kf):
+    """Five length classes (one with n_sub = 2) with padding strips, all
+    writing one candidate buffer in place: (vals, ids) bit-identical to
+    the plain jnp scan through the per-class concat hand-off, at
+    IVF-Flat's kf and IVF-PQ's."""
+    import raft_tpu.ops.strip_scan as ss
+
+    rng = np.random.default_rng(kf)
+    queries, probes, data, bias, ids, lens, classes, cls_ord = \
+        _multi_class_index(rng, dtype)
+    counts = ss.class_counts_of(cls_ord, len(classes))
+    vals, got = ss._strip_search_jit(
+        jnp.asarray(queries), jnp.asarray(probes), jnp.asarray(data),
+        jnp.asarray(bias), jnp.asarray(ids), jnp.asarray(cls_ord), classes,
+        counts, kf, kf, -2.0, True)
+    want_v, want_i = _strip_search_oracle(queries, probes, data, bias, ids,
+                                          classes, cls_ord, kf, kf, -2.0)
+    assert np.array_equal(np.asarray(got), np.asarray(want_i))
+    assert np.array_equal(np.asarray(vals), np.asarray(want_v))
+    assert (np.asarray(got) >= 0).all()
+
+
+def test_tile_body_hands_candidates_over_in_place(rng):
+    """Structural guard: between the class kernels and the merge the tile
+    program holds no concatenate and no slice of the grouped queries or
+    of a kernel's output; the first class call allocates the lane-dense
+    (S + 1, kf, C) buffers and every later one writes into them in
+    place."""
+    import functools
+
+    import jax
+
+    import raft_tpu.ops.strip_scan as ss
+
+    queries, probes, data, bias, ids, lens, classes, cls_ord = \
+        _multi_class_index(rng, "uint8")
+    q, p = probes.shape
+    kf = 10
+    counts = ss.class_counts_of(cls_ord, len(classes))
+    starts, s_tot, layout = ss.static_layout(classes, counts, q, p)
+    plan = ss._plan_device(jnp.asarray(probes), jnp.asarray(cls_ord),
+                           data.shape[0], starts, s_tot)[:4]
+    body = functools.partial(ss._strip_tile_body, class_layout=layout, k=kf,
+                             kf=kf, alpha=-2.0, interpret=True)
+    jaxpr = jax.make_jaxpr(body)(jnp.asarray(queries), *plan,
+                                 jnp.asarray(data), jnp.asarray(bias),
+                                 jnp.asarray(ids))
+    cand_shape = (s_tot + 1, kf, C)
+
+    def eqns(jx):
+        for e in jx.eqns:
+            yield e
+            if e.primitive.name == "pallas_call":
+                continue               # the kernel body is not the hand-off
+            for v in e.params.values():
+                for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                    inner = getattr(sub, "jaxpr", None)
+                    if inner is not None:
+                        yield from eqns(getattr(inner, "jaxpr", inner))
+
+    calls = []
+    for e in eqns(jaxpr.jaxpr):
+        name = e.primitive.name
+        if name in ("concatenate", "slice", "dynamic_slice"):
+            # strip-shaped arrays (grouped queries, candidates) hold C;
+            # the gathers' index arrays, which jnp concatenates, do not
+            assert not any(C in v.aval.shape for v in e.invars
+                           if hasattr(v, "aval")), e
+        if name == "pallas_call":
+            calls.append(e)
+    assert len(calls) == len(layout)
+    for n, e in enumerate(calls):
+        assert [v.aval.shape for v in e.outvars] == [cand_shape] * 2
+        assert bool(e.params["input_output_aliases"]) == (n > 0)
+
+
+@pytest.mark.parametrize("kind,kf,n_sub", [
+    (kind, kf, n_sub) for kind in ("fp", "bq") for kf in (10, 40)
+    for n_sub in (1, 2)])
+def test_paged_class_kernel_matches_jnp_candidates(kind, kf, n_sub):
+    """The paged kernels (interpret) and their jnp references store the
+    same lane-dense (S, kf, C) candidates, bit for bit, on every real
+    strip: chains of differing length, absent pages, tombstones, a
+    filter-dead sub-block and padding strips."""
+    from raft_tpu.ops import bq_scan
+    from raft_tpu.ops import strip_scan as ss
+
+    rng = np.random.default_rng(kf + n_sub)
+    n_lists, page_rows, ppf, dim = 4, 32, 8, 16
+    width = ppf * n_sub
+    cap = n_lists * width
+    chain = np.array([width, 3, 0, ppf + 2])[:n_lists].clip(0, width)
+    table = np.full((n_lists, width), -1, np.int32)
+    perm = rng.permutation(cap).astype(np.int32)
+    for l in range(n_lists):
+        table[l, :chain[l]] = perm[l * width:l * width + chain[l]]
+    bias_pool = rng.normal(size=(cap, page_rows)).astype(np.float32)
+    bias_pool[rng.random((cap, page_rows)) < 0.1] = np.inf   # tombstones
+    sub_live = np.ones(n_lists * n_sub, np.int32)
+    sub_live[-1] = 0                                         # filter-dead
+    strip_list = np.array([0, 1, 3, 2, 0, -1, -1], np.int32)
+    a = rng.normal(size=(len(strip_list), C, dim))
+    common = (jnp.asarray(strip_list), jnp.asarray(table.reshape(-1)),
+              jnp.asarray(chain.astype(np.int32)), jnp.asarray(sub_live),
+              jnp.asarray(a, jnp.bfloat16))
+    if kind == "fp":
+        pages = rng.integers(0, 256, (cap, page_rows, dim)).astype(np.uint8)
+        ops = (jnp.asarray(pages), jnp.asarray(bias_pool))
+        kernel, ref = ss._paged_class_call, ss._paged_class_jnp
+    else:
+        codes = rng.integers(0, 256, (cap, page_rows, dim // 8))
+        scale = rng.uniform(0.5, 2.0, (cap, page_rows)).astype(np.float32)
+        ops = (jnp.asarray(codes.astype(np.uint8)), jnp.asarray(scale),
+               jnp.asarray(bias_pool))
+        kernel, ref = bq_scan._paged_bq_class_call, bq_scan._paged_bq_class_jnp
+    static = dict(ppf=ppf, n_sub=n_sub, page_rows=page_rows,
+                  table_width=width, alpha=-2.0, kf=kf)
+    kv, ke = kernel(*common, *ops, interpret=True, **static)
+    rv, re = ref(*common, *ops, **static)
+    assert kv.shape == (len(strip_list) + 1, kf, C)
+    assert rv.shape == (len(strip_list), kf, C)
+    real = strip_list >= 0
+    assert np.array_equal(np.asarray(kv)[:-1][real], np.asarray(rv)[real])
+    assert np.array_equal(np.asarray(ke)[:-1][real], np.asarray(re)[real])
+    assert np.isfinite(np.asarray(kv)[0]).all()

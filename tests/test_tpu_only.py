@@ -112,9 +112,10 @@ class TestCompiledStrip:
 @pytest.mark.parametrize("dtype,kf", [("uint8", 10), ("int8", 40)])
 def test_strip_kernel_topk_routes_bit_identical(monkeypatch, dtype, kf):
     """The compiled strip kernel at a width both SIFT-1M cells scan
-    (1024-row lists, dim 128): IVF-Flat's kf = 10 over uint8 rows and
-    IVF-PQ's kf = 40 over int8 rows give the same values and columns, bit
-    for bit, on the sorted top-kf route and forced onto the direct
+    (1024-row lists, dim 128), as two length-class calls writing one
+    shared lane-dense candidate buffer: IVF-Flat's kf = 10 over uint8 rows
+    and IVF-PQ's kf = 40 over int8 rows give the same values and columns,
+    bit for bit, on the sorted top-kf route and forced onto the direct
     passes."""
     import jax.numpy as jnp
 
@@ -131,21 +132,27 @@ def test_strip_kernel_topk_routes_bit_identical(monkeypatch, dtype, kf):
     lens = rng.integers(300, m + 1, n_lists)
     bias[np.arange(m)[None, :] >= lens[:, None]] = np.inf
     strip_list = rng.integers(0, n_lists, s).astype(np.int32)
-    strip_list[-5:] = -1                     # static-layout padding strips
+    strip_list[s // 2 - 5:s // 2] = -1       # static-layout padding strips
+    strip_list[-5:] = -1
     a = rng.normal(scale=8.0, size=(s, ss.C, dim))
     args = (jnp.asarray(strip_list), jnp.asarray(a, jnp.bfloat16),
             jnp.asarray(data), jnp.asarray(bias.reshape(n_lists, 1, m)))
     static = dict(w_blocks=m // ss.MC, n_sub=1, alpha=-2.0, kf=kf,
                   interpret=False)
 
-    def fresh():    # a new trace, so the route is decided again
-        return jax.jit(ss._strip_class_call.__wrapped__,
-                       static_argnames=tuple(static))
+    def run():      # a new trace, so the route is decided again
+        call = jax.jit(ss._strip_class_call.__wrapped__,
+                       static_argnames=tuple(static) + ("start", "count"))
+        cand = call(*args, None, start=0, count=s // 2, **static)
+        return call(*args, cand, start=s // 2, count=s // 2, **static)
 
-    sv, se = fresh()(*args, **static)
+    sv, se = run()
     monkeypatch.setattr(ss, "topk_route", lambda *a, **k: "direct")
-    dv, de = fresh()(*args, **static)
+    dv, de = run()
+    assert sv.shape == (s + 1, kf, ss.C)
     real = strip_list >= 0
-    assert np.array_equal(np.asarray(sv)[real], np.asarray(dv)[real])
-    assert np.array_equal(np.asarray(se)[real], np.asarray(de)[real])
-    assert np.isfinite(np.asarray(sv)[real]).all()
+    sv, se = np.asarray(sv)[:-1][real], np.asarray(se)[:-1][real]
+    assert np.array_equal(sv, np.asarray(dv)[:-1][real])
+    assert np.array_equal(se, np.asarray(de)[:-1][real])
+    assert np.isfinite(sv).all()
+    assert ((se >= 0) & (se < lens[strip_list[real], None, None])).all()
