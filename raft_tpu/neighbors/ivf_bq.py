@@ -54,7 +54,7 @@ from raft_tpu.obs import compile as obs_compile
 from raft_tpu.obs import roofline as obs_roofline
 from raft_tpu.cluster import kmeans_balanced
 from raft_tpu.core.bitset import Bitset
-from raft_tpu.neighbors import _filtering
+from raft_tpu.neighbors import _entry, _filtering
 from raft_tpu.core.trace import traced
 from raft_tpu.core.resources import Resources, current_resources
 from raft_tpu.core.serialize import load_arrays, save_arrays
@@ -731,16 +731,16 @@ def _bq_search_prep(queries, centers, rotation, list_bias, list_ids, filter,
 @functools.partial(
     jax.jit,
     static_argnames=("k", "n_probes", "metric", "select_algo",
-                     "compute_dtype", "classes", "class_counts", "q_tile",
+                     "compute_dtype", "classes", "class_counts",
                      "interpret", "impl", "bits", "rotation_kind"),
 )
 def _bq_fused(queries, centers, rotation, list_codes, list_scale, list_bias,
               list_ids, filter, cls_ord, k, n_probes, metric, select_algo,
-              compute_dtype, classes, class_counts, q_tile, interpret, impl,
+              compute_dtype, classes, class_counts, interpret, impl,
               bits: int = 1, rotation_kind: str = "dense"):
-    """The ENTIRE BQ search — coarse gemm, device strip planning, packed
-    scan, merge, finalize — as one jit: one runtime dispatch, zero host
-    syncs (the round-4 _ragged_fused shape). The in-kernel tournament
+    """The ENTIRE BQ search of one query tile — coarse gemm, device strip
+    planning, packed scan, merge, finalize — as one jit: one runtime
+    dispatch, zero host syncs (the round-4 _ragged_fused shape). The in-kernel tournament
     top-k is allowed (approx_ok=True): this path over-fetches and
     exact-re-ranks via neighbors/refine, which absorbs its ~1e-4/row
     bin-collision loss (the IVF-PQ precedent)."""
@@ -754,7 +754,7 @@ def _bq_fused(queries, centers, rotation, list_codes, list_scale, list_bias,
         static={"k": k, "n_probes": n_probes, "metric": metric,
                 "select_algo": select_algo, "compute_dtype": compute_dtype,
                 "classes": classes, "class_counts": class_counts,
-                "q_tile": q_tile, "interpret": interpret, "impl": impl,
+                "interpret": interpret, "impl": impl,
                 "bits": bits, "rotation_kind": rotation_kind})
     l2 = metric in ("sqeuclidean", "euclidean")
     # packed coarse select only while its perturbation bound stays tight
@@ -769,7 +769,7 @@ def _bq_fused(queries, centers, rotation, list_codes, list_scale, list_bias,
     vals, ids = bq_strip_search_traced(
         qr, probes, list_codes, list_scale, bias, list_ids, cls_ord,
         classes, class_counts, int(k), int(k), -2.0 if l2 else -1.0,
-        q_tile, interpret, pair_const=pair_const, approx_ok=True, impl=impl,
+        interpret, pair_const=pair_const, approx_ok=True, impl=impl,
     )
     from raft_tpu.neighbors.ivf_flat import _finalize_ragged
 
@@ -804,69 +804,34 @@ def search(
     counting the degradation, DEADLINE/FATAL classes propagate classified.
     """
     res = res or current_resources()
-    queries = jnp.asarray(queries).astype(jnp.float32)
-    if queries.ndim != 2 or queries.shape[1] != index.dim:
-        raise ValueError(f"queries must be (q, {index.dim}), got {queries.shape}")
-    n_probes = int(min(n_probes, index.n_lists))
-    filter_attrs = None
-    if filter is not None:
-        from raft_tpu.resilience import faultpoint
-
-        faultpoint("ivf_bq.search.filter")
-        n_probes, _, f_rate, f_widen = _filtering.widen_plan(
-            filter, n_probes, index.n_lists)
-        filter_attrs = {"filter_pass_rate": round(f_rate, 6),
-                        "filter_widen_x": round(f_widen, 4),
-                        "filter_n_probes": n_probes}
-    if not 0 < k <= min(n_probes * index.max_list_size, 512):
-        raise ValueError(
-            f"k={k} out of range (1..min(n_probes·max_list_size, 512)) for "
-            f"n_probes={n_probes} x max_list_size={index.max_list_size}")
-    if index.metric == "cosine":
-        queries = queries / jnp.maximum(
-            jnp.linalg.norm(queries, axis=1, keepdims=True), 1e-30)
-
     if backend == "auto":
         backend = "packed" if jax.default_backend() == "tpu" else "reference"
     if backend not in ("packed", "reference"):
         raise ValueError(f"unknown backend {backend!r}")
     impl = "pallas" if backend == "packed" else "jnp"
-    scan_attrs = None
+    queries, n_probes, filter, scan_attrs = _entry.prepare(
+        "ivf_bq.search", queries, k, n_probes, filter, backend,
+        dim=index.dim, n_lists=index.n_lists, metric=index.metric,
+        rows=index.max_list_size, k_cap=512,
+        k_error="k={k} out of range (1..min(n_probes·max_list_size, 512)) "
+                "for n_probes={n_probes} x max_list_size={rows}")
     if obs.enabled():
-        q_obs = int(queries.shape[0])
-        obs.add("ivf_bq.search.queries", q_obs)
-        obs.add("ivf_bq.search.probes", q_obs * n_probes)
-        obs.add(f"ivf_bq.search.backend.{backend}", 1)
-        scan_attrs = {"backend": backend, "queries": q_obs,
-                      "probes": int(n_probes), "k": int(k)}
-        if filter_attrs:
-            scan_attrs.update(filter_attrs)
         # roofline note (round 15): packed-scan FLOP/byte model + strip
-        # occupancy at the scan's real planning width (rot_dim) when the
-        # host already caches per-list lengths (no forced device sync)
-        occ = None
-        lens_cached = getattr(index, "_lens_np_cache", None)
-        if lens_cached is not None \
-                and lens_cached.shape[0] == index.n_lists:
-            from raft_tpu.ops.bq_scan import occupancy_stats
-            kf_occ = min(int(k), 512)
-            occ = obs_roofline.memo_occupancy(
-                index,
-                (id(lens_cached), q_obs, int(n_probes), kf_occ,
-                 res.workspace_bytes),
-                lambda: occupancy_stats(
-                    lens_cached, index.max_list_size, q_obs, n_probes,
-                    rot_dim=index.rot_dim,
-                    workspace_bytes=res.workspace_bytes, kf=kf_occ,
-                    bits=index.bits))
+        # occupancy at the scan's real planning width (rot_dim)
+        from raft_tpu.ops.bq_scan import occupancy_stats
+
         obs_roofline.note_dispatch(
             "ivf_bq.search",
-            {"q": q_obs, "dim": index.dim, "n_lists": index.n_lists,
+            {"q": int(queries.shape[0]), "dim": index.dim,
+             "n_lists": index.n_lists,
              "max_list_size": index.max_list_size,
              "n_probes": int(n_probes), "k": int(k),
              "rot_dim": index.rot_dim, "bits": index.bits,
              "rotation_kind": index.rotation_kind},
-            occupancy=occ)
+            occupancy=_entry.list_occupancy(
+                index, int(queries.shape[0]), n_probes, k, res,
+                functools.partial(occupancy_stats, rot_dim=index.rot_dim,
+                                  bits=index.bits)))
     from raft_tpu import resilience
     from raft_tpu.neighbors.ivf_flat import _ragged_plan_static
 
@@ -877,6 +842,8 @@ def search(
         index, n_probes, k, res, index.rot_dim * index.bits)
     q_tile = min(q_tile, queries.shape[0])
     interpret = jax.default_backend() != "tpu"
+    from raft_tpu.ops.strip_scan import per_tile
+
     while True:
         try:
             resilience.faultpoint("ivf_bq.search.scan")
@@ -884,14 +851,14 @@ def search(
             # stamped on the ledger record (steady state stamps nothing)
             with obs.record_span("ivf_bq::scan", attrs=scan_attrs), \
                     obs_compile.watch():
-                return _bq_fused(
-                    queries, index.centers, index.rotation, index.list_codes,
-                    index.list_scale, index.list_bias, index.list_ids,
-                    filter, cls_ord, int(k), n_probes, index.metric,
-                    select_algo, res.compute_dtype, classes, class_counts,
-                    q_tile, interpret, impl, index.bits,
+                return per_tile(lambda lo, hi: _bq_fused(
+                    queries[lo:hi], index.centers, index.rotation,
+                    index.list_codes, index.list_scale, index.list_bias,
+                    index.list_ids, filter, cls_ord, int(k), n_probes,
+                    index.metric, select_algo, res.compute_dtype, classes,
+                    class_counts, interpret, impl, index.bits,
                     index.rotation_kind,
-                )
+                ), queries.shape[0], q_tile)
         except Exception as e:
             kind = resilience.classify(e)
             if kind == resilience.OOM and q_tile > 64:
@@ -915,15 +882,15 @@ def search(
 @functools.partial(
     jax.jit,
     static_argnames=("k", "n_probes", "metric", "select_algo",
-                     "compute_dtype", "q_tile", "interpret", "impl",
+                     "compute_dtype", "interpret", "impl",
                      "bits", "rotation_kind"),
 )
 def _paged_fused_bq(queries, centers, rotation, codes_pool, scale_pool,
                     bias_pool, page_ids, table, chain_pages, filter,
                     k, n_probes, metric, select_algo, compute_dtype,
-                    q_tile, interpret, impl, bits: int = 1,
+                    interpret, impl, bits: int = 1,
                     rotation_kind: str = "dense"):
-    """The ENTIRE paged BQ search as one jit: coarse gemm + rotation,
+    """The ENTIRE paged BQ search of one query tile as one jit: coarse gemm + rotation,
     device strip planning, the page-table DMA ±1 kernel, merge, finalize —
     the ``_bq_fused`` shape over page chains. Capacity-shaped operands
     (zero-recompile serving contract); the exact −2⟨q, c_l⟩ term rides
@@ -937,7 +904,7 @@ def _paged_fused_bq(queries, centers, rotation, codes_pool, scale_pool,
         chain_pages=chain_pages, filter=filter,
         static={"k": k, "n_probes": n_probes, "metric": metric,
                 "select_algo": select_algo, "compute_dtype": compute_dtype,
-                "q_tile": q_tile, "interpret": interpret, "impl": impl,
+                "interpret": interpret, "impl": impl,
                 "bits": bits, "rotation_kind": rotation_kind})
     l2 = metric in ("sqeuclidean", "euclidean")
     sa = ("packed" if select_algo == "exact" and not interpret
@@ -952,7 +919,7 @@ def _paged_fused_bq(queries, centers, rotation, codes_pool, scale_pool,
     alpha = -2.0 if l2 else -1.0
     vals, ids = paged_bq_search_traced(
         qr, probes, codes_pool, scale_pool, bias, page_ids, table,
-        chain_pages, int(k), int(k), alpha, q_tile, interpret,
+        chain_pages, int(k), int(k), alpha, interpret,
         pair_const=pair_const, impl=impl)
     from raft_tpu.neighbors.ivf_flat import _finalize_ragged
 
@@ -982,21 +949,6 @@ def search_paged(
     if store.kind != "ivf_bq":
         raise ValueError(f"expected an ivf_bq store, got {store.kind!r}")
     res = res or current_resources()
-    queries = jnp.asarray(queries).astype(jnp.float32)
-    if queries.ndim != 2 or queries.shape[1] != store.dim:
-        raise ValueError(f"queries must be (q, {store.dim}), got {queries.shape}")
-    n_probes = int(min(n_probes, store.n_lists))
-    if filter is None:
-        filter = getattr(store, "filter", None)
-    filter_attrs = None
-    if filter is not None:
-        from raft_tpu.resilience import faultpoint
-        faultpoint("ivf_bq.search.filter")
-        n_probes, _, f_rate, f_widen = _filtering.widen_plan(
-            filter, n_probes, store.n_lists)
-        filter_attrs = {"filter_pass_rate": round(f_rate, 6),
-                        "filter_widen_x": round(f_widen, 4),
-                        "filter_n_probes": n_probes}
     from raft_tpu.neighbors.ivf_flat import (_paged_plan_static,
                                              paged_backend_auto)
 
@@ -1008,58 +960,41 @@ def search_paged(
     codes_pool, bias_pool, scale_pool, page_ids, table, chain_pages = \
         store.paged_scan_state()
     width = int(table.shape[1])
-    if not 0 < k <= min(n_probes * width * store.page_rows, 512):
-        raise ValueError(f"k={k} out of range")
-    if store.metric == "cosine":
-        queries = queries / jnp.maximum(
-            jnp.linalg.norm(queries, axis=1, keepdims=True), 1e-30)
+    queries, n_probes, filter, scan_attrs = _entry.prepare(
+        "ivf_bq.search_paged", queries, k, n_probes, filter, backend,
+        dim=store.dim, n_lists=store.n_lists, metric=store.metric,
+        rows=width * store.page_rows, k_cap=512,
+        standing_filter=getattr(store, "filter", None),
+        attrs={"table_width": width})
     rot_dim = int(store.rotation.shape[0])
     bits = int(getattr(store, "bq_bits", 1))
     rotation_kind = getattr(store, "rotation_kind", "dense")
-    scan_attrs = None
     if obs.enabled():
         q_obs = int(queries.shape[0])
-        obs.add("ivf_bq.search_paged.queries", q_obs)
-        obs.add("ivf_bq.search_paged.probes", q_obs * n_probes)
-        obs.add(f"ivf_bq.search_paged.backend.{backend}", 1)
-        scan_attrs = {"backend": backend, "queries": q_obs,
-                      "probes": int(n_probes), "k": int(k),
-                      "table_width": width}
-        if filter_attrs:
-            scan_attrs.update(filter_attrs)
-        from raft_tpu.ops.strip_scan import paged_occupancy_stats
-        occ = obs_roofline.memo_occupancy(
-            store,
-            (store.pages_used, store.size, store.tombstones, width,
-             q_obs, int(n_probes), int(k), res.workspace_bytes),
-            lambda: paged_occupancy_stats(
-                width, store.page_rows, store._list_pages, store.size,
-                store.tombstones, q_obs, int(n_probes), int(k),
-                int(codes_pool.shape[-1]),
-                workspace_bytes=res.workspace_bytes, dim=rot_dim * bits))
         obs_roofline.note_dispatch(
             "ivf_bq.paged_pallas",
             {"q": q_obs, "dim": store.dim, "n_lists": store.n_lists,
              "page_rows": store.page_rows, "table_width": width,
              "n_probes": int(n_probes), "k": int(k), "rot_dim": rot_dim,
              "bits": bits, "rotation_kind": rotation_kind},
-            occupancy=occ)
+            occupancy=_entry.page_occupancy(
+                store, width, q_obs, n_probes, k, res,
+                int(codes_pool.shape[-1]), rot_dim * bits))
+    from raft_tpu.ops.strip_scan import per_tile
     from raft_tpu.resilience import faultpoint
 
     interpret = jax.default_backend() != "tpu"
-    q_tile = min(_paged_plan_static(store, n_probes, k, res,
-                                    rot_dim * bits),
-                 queries.shape[0])
+    q_tile = _paged_plan_static(store, n_probes, k, res, rot_dim * bits)
     impl = "pallas" if backend == "paged_pallas" else "jnp"
     faultpoint("ivf_bq.search_paged.scan")
     with obs.record_span("ivf_bq::paged_pallas", attrs=scan_attrs):
         with obs_compile.watch():
-            return _paged_fused_bq(
-                queries, store.centers, store.rotation, codes_pool,
+            return per_tile(lambda lo, hi: _paged_fused_bq(
+                queries[lo:hi], store.centers, store.rotation, codes_pool,
                 scale_pool, bias_pool, page_ids, table, chain_pages,
                 filter, int(k), n_probes, store.metric, select_algo,
-                res.compute_dtype, int(q_tile), interpret, impl, bits,
-                rotation_kind)
+                res.compute_dtype, interpret, impl, bits, rotation_kind),
+                queries.shape[0], q_tile)
 
 
 @traced("ivf_bq::search_refined")

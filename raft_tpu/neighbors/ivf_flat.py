@@ -38,7 +38,7 @@ from raft_tpu.obs import compile as obs_compile
 from raft_tpu.obs import roofline as obs_roofline
 from raft_tpu.cluster import kmeans_balanced
 from raft_tpu.core.bitset import Bitset
-from raft_tpu.neighbors import _filtering
+from raft_tpu.neighbors import _entry, _filtering
 from raft_tpu.neighbors import _packing
 from raft_tpu.neighbors._packing import pack_lists, unpack_lists
 from raft_tpu.core.trace import traced
@@ -571,72 +571,29 @@ def search(
     oracle path and CPU default), or "auto".
     """
     res = res or current_resources()
-    queries = jnp.asarray(queries).astype(jnp.float32)
-    if queries.ndim != 2 or queries.shape[1] != index.dim:
-        raise ValueError(f"queries must be (q, {index.dim}), got {queries.shape}")
-    n_probes = int(min(n_probes, index.n_lists))
-    filter_attrs = None
-    if filter is not None:
-        from raft_tpu.resilience import faultpoint
-
-        faultpoint("ivf_flat.search.filter")
-        # selectivity-aware widening: over-probe by ~1/pass_rate (capped)
-        # so k SURVIVORS come back at selective filters — the effective
-        # n_probes flows into validation, telemetry and the roofline model
-        n_probes, _, f_rate, f_widen = _filtering.widen_plan(
-            filter, n_probes, index.n_lists)
-        filter_attrs = {"filter_pass_rate": round(f_rate, 6),
-                        "filter_widen_x": round(f_widen, 4),
-                        "filter_n_probes": n_probes}
-    if not 0 < k <= n_probes * index.max_list_size:
-        raise ValueError(
-            f"k={k} out of range for n_probes={n_probes} x max_list_size={index.max_list_size}"
-        )
-    if index.metric == "cosine":
-        queries = queries / jnp.maximum(jnp.linalg.norm(queries, axis=1, keepdims=True), 1e-30)
-
-    from raft_tpu.ops.strip_scan import strip_eligible
+    from raft_tpu.ops.strip_scan import occupancy_stats, strip_eligible
 
     aligned = strip_eligible(index.max_list_size) and k <= 512
     if backend == "auto":
         backend = "ragged" if jax.default_backend() == "tpu" and aligned else "gather"
     if backend not in ("ragged", "gather"):
         raise ValueError(f"unknown backend {backend!r}")
-    scan_attrs = None
+    queries, n_probes, filter, scan_attrs = _entry.prepare(
+        "ivf_flat.search", queries, k, n_probes, filter, backend,
+        dim=index.dim, n_lists=index.n_lists, metric=index.metric,
+        rows=index.max_list_size, count_rows=True,
+        k_error="k={k} out of range for n_probes={n_probes} x "
+                "max_list_size={rows}")
     if obs.enabled():
-        q_obs = int(queries.shape[0])
-        obs.add("ivf_flat.search.queries", q_obs)
-        obs.add("ivf_flat.search.probes", q_obs * n_probes)
-        # padded upper bound on candidate rows visited (the ragged backend's
-        # actual work is ∝ real list fills; this is telemetry, not billing)
-        obs.add("ivf_flat.search.rows_scanned",
-                q_obs * n_probes * index.max_list_size)
-        obs.add(f"ivf_flat.search.backend.{backend}", 1)
-        scan_attrs = {"backend": backend, "queries": q_obs,
-                      "probes": int(n_probes), "k": int(k)}
-        if filter_attrs:
-            scan_attrs.update(filter_attrs)
         # roofline note (round 15): static FLOP/byte model of this
-        # dispatch, plus the strip planner's occupancy stats when the
-        # host already holds the per-list lengths (the ragged path's
-        # cache — telemetry must never force a device sync to get them)
-        occ = None
-        lens_cached = getattr(index, "_lens_np_cache", None)
-        if backend == "ragged" and lens_cached is not None \
-                and lens_cached.shape[0] == index.n_lists:
-            from raft_tpu.ops.strip_scan import occupancy_stats
-            kf_occ = min(int(k), 512)
-            occ = obs_roofline.memo_occupancy(
-                index,
-                (id(lens_cached), q_obs, int(n_probes), kf_occ,
-                 res.workspace_bytes),
-                lambda: occupancy_stats(
-                    lens_cached, index.max_list_size, q_obs, n_probes,
-                    dim=index.dim, workspace_bytes=res.workspace_bytes,
-                    kf=kf_occ))
+        # dispatch, plus the strip planner's occupancy on the ragged path
+        occ = None if backend != "ragged" else _entry.list_occupancy(
+            index, int(queries.shape[0]), n_probes, k, res,
+            functools.partial(occupancy_stats, dim=index.dim))
         obs_roofline.note_dispatch(
             "ivf_flat.search",
-            {"q": q_obs, "dim": index.dim, "n_lists": index.n_lists,
+            {"q": int(queries.shape[0]), "dim": index.dim,
+             "n_lists": index.n_lists,
              "max_list_size": index.max_list_size,
              "n_probes": int(n_probes), "k": int(k),
              "dtype": str(index.list_data.dtype)},
@@ -762,15 +719,15 @@ def _paged_impl(
 @functools.partial(
     jax.jit,
     static_argnames=("k", "n_probes", "metric", "select_algo",
-                     "compute_dtype", "q_tile", "interpret", "impl"),
+                     "compute_dtype", "interpret", "impl"),
 )
 def _paged_fused(queries, centers, pages, bias_pool, page_ids, table,
                  chain_pages, filter, k, n_probes, metric, select_algo,
-                 compute_dtype, q_tile, interpret, impl):
-    """The ENTIRE paged Pallas search — coarse gemm, device strip
-    planning, page-table DMA kernel, merge, finalize — as one jit (the
-    ``_ragged_fused`` shape over page chains): mutable paged storage
-    scanned in place at strip-kernel throughput. Every operand is
+                 compute_dtype, interpret, impl):
+    """The ENTIRE paged Pallas search of one query tile — coarse gemm,
+    device strip planning, page-table DMA kernel, merge, finalize — as one
+    jit (the ``_ragged_fused`` shape over page chains): mutable paged
+    storage scanned in place at strip-kernel throughput. Every operand is
     CAPACITY-shaped, so steady-state upserts/deletes re-dispatch this
     same program (zero-recompile serving contract)."""
     from raft_tpu.ops.strip_scan import paged_strip_search_traced
@@ -783,7 +740,7 @@ def _paged_fused(queries, centers, pages, bias_pool, page_ids, table,
         chain_pages=chain_pages, filter=filter,
         static={"k": k, "n_probes": n_probes, "metric": metric,
                 "select_algo": select_algo, "compute_dtype": compute_dtype,
-                "q_tile": q_tile, "interpret": interpret, "impl": impl})
+                "interpret": interpret, "impl": impl})
     # same coarse select as the packed ragged path (parity: probe choice
     # decides the candidate set — see ivf_flat._ragged_fused's bound note)
     sa = ("packed" if select_algo == "exact" and not interpret
@@ -797,7 +754,7 @@ def _paged_fused(queries, centers, pages, bias_pool, page_ids, table,
     l2 = metric in ("sqeuclidean", "euclidean")
     vals, ids = paged_strip_search_traced(
         queries, probes, pages, bias, page_ids, table, chain_pages,
-        int(k), int(k), -2.0 if l2 else -1.0, q_tile, interpret, impl=impl)
+        int(k), int(k), -2.0 if l2 else -1.0, interpret, impl=impl)
     return _finalize_ragged(vals, ids, queries, metric)
 
 
@@ -862,24 +819,6 @@ def search_paged(
     if store.kind != "ivf_flat":
         raise ValueError(f"expected an ivf_flat store, got {store.kind!r}")
     res = res or current_resources()
-    queries = jnp.asarray(queries).astype(jnp.float32)
-    if queries.ndim != 2 or queries.shape[1] != store.dim:
-        raise ValueError(f"queries must be (q, {store.dim}), got {queries.shape}")
-    n_probes = int(min(n_probes, store.n_lists))
-    if filter is None:
-        # a standing store-level filter (PagedListStore.set_filter) applies
-        # when the caller passes none — per-call filters take precedence
-        filter = getattr(store, "filter", None)
-    filter_attrs = None
-    if filter is not None:
-        from raft_tpu.resilience import faultpoint
-
-        faultpoint("ivf_flat.search.filter")
-        n_probes, _, f_rate, f_widen = _filtering.widen_plan(
-            filter, n_probes, store.n_lists)
-        filter_attrs = {"filter_pass_rate": round(f_rate, 6),
-                        "filter_widen_x": round(f_widen, 4),
-                        "filter_n_probes": n_probes}
     if backend == "auto":
         backend = paged_backend_auto(store, k)
     if backend not in ("gather", "paged_pallas", "paged_jnp"):
@@ -892,70 +831,45 @@ def search_paged(
         pages, bias_pool, _, page_ids, table, chain_pages = \
             store.paged_scan_state()
     width = int(table.shape[1])
-    if not 0 < k <= n_probes * width * store.page_rows:
-        raise ValueError(f"k={k} out of range")
-    if store.metric == "cosine":
-        queries = queries / jnp.maximum(
-            jnp.linalg.norm(queries, axis=1, keepdims=True), 1e-30)
-    scan_attrs = None
+    queries, n_probes, filter, scan_attrs = _entry.prepare(
+        "ivf_flat.search_paged", queries, k, n_probes, filter, backend,
+        dim=store.dim, n_lists=store.n_lists, metric=store.metric,
+        rows=width * store.page_rows,
+        standing_filter=getattr(store, "filter", None),
+        attrs={"table_width": width})
     if obs.enabled():
+        # roofline note (round 15): the gather scan's per-(query, probe)
+        # capacity-padded chain cost — no cross-query sharing, which is
+        # exactly what the paged Pallas engine buys back — or the paged
+        # Pallas model with the planner's occupancy
         q_obs = int(queries.shape[0])
-        obs.add("ivf_flat.search_paged.queries", q_obs)
-        obs.add("ivf_flat.search_paged.probes", q_obs * n_probes)
-        obs.add(f"ivf_flat.search_paged.backend.{backend}", 1)
-        scan_attrs = {"backend": backend, "queries": q_obs,
-                      "probes": int(n_probes), "k": int(k),
-                      "table_width": width}
-        if filter_attrs:
-            scan_attrs.update(filter_attrs)
+        shapes = {"q": q_obs, "dim": store.dim, "n_lists": store.n_lists,
+                  "page_rows": store.page_rows, "table_width": width,
+                  "n_probes": int(n_probes), "k": int(k),
+                  "dtype": str(pages.dtype)}
         if backend == "gather":
-            # roofline note (round 15): the gather scan's per-(query,
-            # probe) capacity-padded chain cost — no cross-query sharing,
-            # which is exactly what the paged Pallas engine buys back
-            obs_roofline.note_dispatch(
-                "ivf_flat.paged_scan",
-                {"q": q_obs, "dim": store.dim, "n_lists": store.n_lists,
-                 "page_rows": store.page_rows, "table_width": width,
-                 "n_probes": int(n_probes), "k": int(k),
-                 "dtype": str(pages.dtype)})
+            obs_roofline.note_dispatch("ivf_flat.paged_scan", shapes)
         else:
-            # paged-Pallas roofline + planner occupancy (round-15 standing
-            # gate: new hot-path kernels ship with their model). The
-            # planner stats come from host state the store already holds —
-            # no device sync (memoized until the layout/fill moves).
-            from raft_tpu.ops.strip_scan import paged_occupancy_stats
-            row_bytes = int(pages.shape[-1]) * pages.dtype.itemsize
-            occ = obs_roofline.memo_occupancy(
-                store,
-                (store.pages_used, store.size, store.tombstones, width,
-                 q_obs, int(n_probes), int(k), res.workspace_bytes),
-                lambda: paged_occupancy_stats(
-                    width, store.page_rows, store._list_pages, store.size,
-                    store.tombstones, q_obs, int(n_probes), int(k),
-                    row_bytes, workspace_bytes=res.workspace_bytes,
-                    dim=store.dim))
             obs_roofline.note_dispatch(
-                "ivf_flat.paged_pallas",
-                {"q": q_obs, "dim": store.dim, "n_lists": store.n_lists,
-                 "page_rows": store.page_rows, "table_width": width,
-                 "n_probes": int(n_probes), "k": int(k),
-                 "dtype": str(pages.dtype)},
-                occupancy=occ)
+                "ivf_flat.paged_pallas", shapes,
+                occupancy=_entry.page_occupancy(
+                    store, width, q_obs, n_probes, k, res,
+                    int(pages.shape[-1]) * pages.dtype.itemsize, store.dim))
+    from raft_tpu.ops.strip_scan import per_tile
     from raft_tpu.resilience import faultpoint
 
     if backend != "gather":
         interpret = jax.default_backend() != "tpu"
-        q_tile = min(_paged_plan_static(store, n_probes, k, res, store.dim),
-                     queries.shape[0])
+        q_tile = _paged_plan_static(store, n_probes, k, res, store.dim)
         impl = "pallas" if backend == "paged_pallas" else "jnp"
         faultpoint("ivf_flat.search_paged.scan")
         with obs.record_span("ivf_flat::paged_pallas", attrs=scan_attrs):
             with obs_compile.watch():
-                return _paged_fused(
-                    queries, store.centers, pages, bias_pool, page_ids,
-                    table, chain_pages, filter, int(k), n_probes,
-                    store.metric, select_algo, res.compute_dtype,
-                    int(q_tile), interpret, impl)
+                return per_tile(lambda lo, hi: _paged_fused(
+                    queries[lo:hi], store.centers, pages, bias_pool,
+                    page_ids, table, chain_pages, filter, int(k), n_probes,
+                    store.metric, select_algo, res.compute_dtype, interpret,
+                    impl), queries.shape[0], q_tile)
     # the (qt, p, W, R, d) page gather is the big intermediate
     per_query = max(1, n_probes * width * store.page_rows * (store.dim + 2) * 4)
     q_tile = int(max(1, min(queries.shape[0],

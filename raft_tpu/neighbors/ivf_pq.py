@@ -48,7 +48,7 @@ from raft_tpu.obs import compile as obs_compile
 from raft_tpu.obs import roofline as obs_roofline
 from raft_tpu.cluster import kmeans_balanced
 from raft_tpu.core.bitset import Bitset
-from raft_tpu.neighbors import _filtering
+from raft_tpu.neighbors import _entry, _filtering
 from raft_tpu.neighbors import _packing
 from raft_tpu.core.logger import get_logger
 from raft_tpu.core.trace import traced
@@ -1417,26 +1417,7 @@ def search(
     for exact re-ranking (the reference does the same, refine-inl.cuh:70).
     """
     res = res or current_resources()
-    queries = jnp.asarray(queries).astype(jnp.float32)
-    if queries.ndim != 2 or queries.shape[1] != index.dim:
-        raise ValueError(f"queries must be (q, {index.dim}), got {queries.shape}")
-    n_probes = int(min(n_probes, index.n_lists))
-    filter_attrs = None
-    if filter is not None:
-        from raft_tpu.resilience import faultpoint
-
-        faultpoint("ivf_pq.search.filter")
-        n_probes, _, f_rate, f_widen = _filtering.widen_plan(
-            filter, n_probes, index.n_lists)
-        filter_attrs = {"filter_pass_rate": round(f_rate, 6),
-                        "filter_widen_x": round(f_widen, 4),
-                        "filter_n_probes": n_probes}
-    if not 0 < k <= n_probes * index.max_list_size:
-        raise ValueError(f"k={k} out of range")
-    if index.metric == "cosine":
-        queries = queries / jnp.maximum(jnp.linalg.norm(queries, axis=1, keepdims=True), 1e-30)
-
-    from raft_tpu.ops.strip_scan import strip_eligible
+    from raft_tpu.ops.strip_scan import occupancy_stats, strip_eligible
 
     aligned = strip_eligible(index.max_list_size) and k <= 512
     pallas_ok = index.max_list_size % 128 == 0
@@ -1467,41 +1448,21 @@ def search(
         # the LUT kernel's table is per query; PER_CLUSTER tables are per
         # list — served by the strip cache / gather paths instead
         backend = "ragged" if aligned and jax.default_backend() == "tpu" else "gather"
-    scan_attrs = None
+    queries, n_probes, filter, scan_attrs = _entry.prepare(
+        "ivf_pq.search", queries, k, n_probes, filter, backend,
+        dim=index.dim, n_lists=index.n_lists, metric=index.metric,
+        rows=index.max_list_size, count_rows=True)
     if obs.enabled():
-        q_obs = int(queries.shape[0])
-        obs.add("ivf_pq.search.queries", q_obs)
-        obs.add("ivf_pq.search.probes", q_obs * n_probes)
-        # padded upper bound on candidate rows visited (the ragged backend's
-        # actual work is ∝ real list fills; this is telemetry, not billing)
-        obs.add("ivf_pq.search.rows_scanned",
-                q_obs * n_probes * index.max_list_size)
-        obs.add(f"ivf_pq.search.backend.{backend}", 1)
-        scan_attrs = {"backend": backend, "queries": q_obs,
-                      "probes": int(n_probes), "k": int(k)}
-        if filter_attrs:
-            scan_attrs.update(filter_attrs)
-        # roofline note (round 15): static FLOP/byte model + strip
-        # occupancy when the host already caches per-list lengths (the
-        # ragged path; telemetry must never force a device sync)
+        # roofline note (round 15): static FLOP/byte model + the strip
+        # planner's occupancy on the ragged path
         rot_dim_obs = int(index.rotation.shape[0])
-        occ = None
-        lens_cached = getattr(index, "_lens_np_cache", None)
-        if backend == "ragged" and lens_cached is not None \
-                and lens_cached.shape[0] == index.n_lists:
-            from raft_tpu.ops.strip_scan import occupancy_stats
-            kf_occ = min(int(k), 512)
-            occ = obs_roofline.memo_occupancy(
-                index,
-                (id(lens_cached), q_obs, int(n_probes), kf_occ,
-                 res.workspace_bytes),
-                lambda: occupancy_stats(
-                    lens_cached, index.max_list_size, q_obs, n_probes,
-                    dim=rot_dim_obs, workspace_bytes=res.workspace_bytes,
-                    kf=kf_occ))
+        occ = None if backend != "ragged" else _entry.list_occupancy(
+            index, int(queries.shape[0]), n_probes, k, res,
+            functools.partial(occupancy_stats, dim=rot_dim_obs))
         obs_roofline.note_dispatch(
             "ivf_pq.search",
-            {"q": q_obs, "dim": index.dim, "n_lists": index.n_lists,
+            {"q": int(queries.shape[0]), "dim": index.dim,
+             "n_lists": index.n_lists,
              "max_list_size": index.max_list_size,
              "pq_dim": index.pq_dim, "pq_bits": index.pq_bits,
              "n_probes": int(n_probes), "k": int(k),
@@ -1740,13 +1701,13 @@ def _paged_impl(
 @functools.partial(
     jax.jit,
     static_argnames=("k", "n_probes", "metric", "select_algo",
-                     "compute_dtype", "q_tile", "interpret", "impl"),
+                     "compute_dtype", "interpret", "impl"),
 )
 def _paged_fused_pq(queries, centers, rotation, cache_pool, bias_pool,
                     page_ids, table, chain_pages, decoded_scale, filter,
                     k, n_probes, metric, select_algo, compute_dtype,
-                    q_tile, interpret, impl):
-    """The ENTIRE paged PQ Pallas search as one jit: coarse gemm + query
+                    interpret, impl):
+    """The ENTIRE paged PQ Pallas search of one query tile as one jit: coarse gemm + query
     rotation, device strip planning, the page-table DMA kernel over the
     int8 decoded-residual cache pool, merge, finalize — the
     ``_ragged_fused_pq`` shape over page chains. All operands are
@@ -1762,7 +1723,7 @@ def _paged_fused_pq(queries, centers, rotation, cache_pool, bias_pool,
         decoded_scale=decoded_scale, filter=filter,
         static={"k": k, "n_probes": n_probes, "metric": metric,
                 "select_algo": select_algo, "compute_dtype": compute_dtype,
-                "q_tile": q_tile, "interpret": interpret, "impl": impl})
+                "interpret": interpret, "impl": impl})
     l2 = metric in ("sqeuclidean", "euclidean")
     sa = ("packed" if select_algo == "exact" and not interpret
           and centers.shape[0] <= 4096 else select_algo)
@@ -1775,7 +1736,7 @@ def _paged_fused_pq(queries, centers, rotation, cache_pool, bias_pool,
     bias = _filtering.apply_filter_bias(bias_pool, page_ids, filter)
     vals, ids = paged_strip_search_traced(
         qr * decoded_scale, probes, cache_pool, bias, page_ids, table,
-        chain_pages, int(k), int(k), alpha, q_tile, interpret,
+        chain_pages, int(k), int(k), alpha, interpret,
         pair_const=pair_const, impl=impl)
     from raft_tpu.neighbors.ivf_flat import _finalize_ragged
 
@@ -1806,22 +1767,6 @@ def search_paged(
     if store.kind != "ivf_pq":
         raise ValueError(f"expected an ivf_pq store, got {store.kind!r}")
     res = res or current_resources()
-    queries = jnp.asarray(queries).astype(jnp.float32)
-    if queries.ndim != 2 or queries.shape[1] != store.dim:
-        raise ValueError(f"queries must be (q, {store.dim}), got {queries.shape}")
-    n_probes = int(min(n_probes, store.n_lists))
-    if filter is None:
-        filter = getattr(store, "filter", None)
-    filter_attrs = None
-    if filter is not None:
-        from raft_tpu.resilience import faultpoint
-
-        faultpoint("ivf_pq.search.filter")
-        n_probes, _, f_rate, f_widen = _filtering.widen_plan(
-            filter, n_probes, store.n_lists)
-        filter_attrs = {"filter_pass_rate": round(f_rate, 6),
-                        "filter_widen_x": round(f_widen, 4),
-                        "filter_n_probes": n_probes}
     from raft_tpu.neighbors.ivf_flat import (_paged_plan_static,
                                              paged_backend_auto)
 
@@ -1837,70 +1782,49 @@ def search_paged(
         cache_pool, bias_pool, _, page_ids, table, chain_pages = \
             store.paged_scan_state()
     width = int(table.shape[1])
-    if not 0 < k <= n_probes * width * store.page_rows:
-        raise ValueError(f"k={k} out of range")
-    if store.metric == "cosine":
-        queries = queries / jnp.maximum(
-            jnp.linalg.norm(queries, axis=1, keepdims=True), 1e-30)
-    scan_attrs = None
+    queries, n_probes, filter, scan_attrs = _entry.prepare(
+        "ivf_pq.search_paged", queries, k, n_probes, filter, backend,
+        dim=store.dim, n_lists=store.n_lists, metric=store.metric,
+        rows=width * store.page_rows,
+        standing_filter=getattr(store, "filter", None),
+        attrs={"table_width": width})
     if obs.enabled():
+        # roofline note (round 15): the LUT-scan cost over the capacity-
+        # padded page chains (no cross-query sharing on this path), or
+        # the paged Pallas model with the planner's occupancy
         q_obs = int(queries.shape[0])
-        obs.add("ivf_pq.search_paged.queries", q_obs)
-        obs.add("ivf_pq.search_paged.probes", q_obs * n_probes)
-        obs.add(f"ivf_pq.search_paged.backend.{backend}", 1)
-        scan_attrs = {"backend": backend, "queries": q_obs,
-                      "probes": int(n_probes), "k": int(k),
-                      "table_width": width}
-        if filter_attrs:
-            scan_attrs.update(filter_attrs)
+        shapes = {"q": q_obs, "dim": store.dim, "n_lists": store.n_lists,
+                  "page_rows": store.page_rows, "table_width": width,
+                  "pq_dim": store.pq_dim, "pq_bits": store.pq_bits,
+                  "n_probes": int(n_probes), "k": int(k),
+                  "rot_dim": int(store.rotation.shape[0])}
         if backend == "gather":
-            # roofline note (round 15): LUT-scan cost over the capacity-
-            # padded page chains (no cross-query sharing on this path)
-            obs_roofline.note_dispatch(
-                "ivf_pq.paged_scan",
-                {"q": q_obs, "dim": store.dim, "n_lists": store.n_lists,
-                 "page_rows": store.page_rows, "table_width": width,
-                 "pq_dim": store.pq_dim, "pq_bits": store.pq_bits,
-                 "n_probes": int(n_probes), "k": int(k),
-                 "rot_dim": int(store.rotation.shape[0])})
+            obs_roofline.note_dispatch("ivf_pq.paged_scan", shapes)
         else:
-            from raft_tpu.ops.strip_scan import paged_occupancy_stats
-            occ = obs_roofline.memo_occupancy(
-                store,
-                (store.pages_used, store.size, store.tombstones, width,
-                 q_obs, int(n_probes), int(k), res.workspace_bytes),
-                lambda: paged_occupancy_stats(
-                    width, store.page_rows, store._list_pages, store.size,
-                    store.tombstones, q_obs, int(n_probes), int(k),
-                    store._cache_dim, workspace_bytes=res.workspace_bytes,
-                    dim=store._cache_dim))
             obs_roofline.note_dispatch(
-                "ivf_pq.paged_pallas",
-                {"q": q_obs, "dim": store.dim, "n_lists": store.n_lists,
-                 "page_rows": store.page_rows, "table_width": width,
-                 "pq_dim": store.pq_dim, "pq_bits": store.pq_bits,
-                 "n_probes": int(n_probes), "k": int(k),
-                 "rot_dim": int(store.rotation.shape[0])},
-                occupancy=occ)
+                "ivf_pq.paged_pallas", shapes,
+                occupancy=_entry.page_occupancy(
+                    store, width, q_obs, n_probes, k, res,
+                    store._cache_dim, store._cache_dim))
+    from raft_tpu.ops.strip_scan import per_tile
     from raft_tpu.resilience import faultpoint
 
     if backend != "gather":
         interpret = jax.default_backend() != "tpu"
-        q_tile = min(_paged_plan_static(store, n_probes, k, res,
-                                        store._cache_dim),
-                     queries.shape[0])
+        q_tile = _paged_plan_static(store, n_probes, k, res,
+                                    store._cache_dim)
         impl = "pallas" if backend == "paged_pallas" else "jnp"
         faultpoint("ivf_pq.search_paged.scan")
         with obs.record_span("ivf_pq::paged_pallas", attrs=scan_attrs):
             with obs_compile.watch():
                 # cosine is already folded by _finalize_ragged inside the
                 # fused dispatch (the packed ragged path's convention)
-                return _paged_fused_pq(
-                    queries, store.centers, store.rotation, cache_pool,
-                    bias_pool, page_ids, table, chain_pages,
+                return per_tile(lambda lo, hi: _paged_fused_pq(
+                    queries[lo:hi], store.centers, store.rotation,
+                    cache_pool, bias_pool, page_ids, table, chain_pages,
                     store.decoded_scale, filter, int(k), n_probes,
                     store.metric, select_algo, res.compute_dtype,
-                    int(q_tile), interpret, impl)
+                    interpret, impl), queries.shape[0], q_tile)
     # the (qt, p, W, R, s) unpacked-code gather dominates the working set
     per_query = max(1, n_probes * width * store.page_rows
                     * (store.pq_dim * 5 + 8))

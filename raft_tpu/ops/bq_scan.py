@@ -338,12 +338,14 @@ def _bq_tile_body(queries_rot, qids, strip_list, pair_strip, pair_slot,
 
 def bq_strip_search_traced(queries_rot, probes, list_codes, scale, bias,
                            list_ids, cls_ord, classes, class_counts,
-                           k: int, kf: int, alpha: float, q_tile: int,
+                           k: int, kf: int, alpha: float,
                            interpret: bool, pair_const=None,
                            approx_ok: bool = False, impl: str = "pallas"):
-    """Sync-free packed strip search — fully traceable so callers fuse
-    coarse quantizer + device planning + scan + finalize into ONE dispatch
-    (the strip_scan.strip_search_traced protocol, packed-B edition).
+    """Sync-free packed strip search of ONE query tile (all of
+    ``queries_rot``) — fully traceable so callers fuse coarse quantizer +
+    device planning + scan + finalize into ONE dispatch (the
+    strip_scan.strip_search_traced protocol, packed-B edition), and split a
+    search into tiles with :func:`strip_scan.per_tile`.
 
     queries_rot: (q, rot_dim) ROTATED queries. list_codes: (n_lists, m,
     rot_dim/8) packed sign codes. scale / bias: (n_lists, m) per-entry
@@ -351,30 +353,14 @@ def bq_strip_search_traced(queries_rot, probes, list_codes, scale, bias,
     ``impl`` picks the Pallas kernel or the pure-jnp reference — identical
     results either way (tests/test_bq_scan.py asserts bit parity)."""
     q, p = probes.shape
-    n_lists = list_codes.shape[0]
-    out_v, out_i = [], []
-    for start in range(0, q, q_tile):
-        qt = min(q_tile, q - start)
-        region_starts, s_tot, layout = ss.static_layout(
-            classes, class_counts, qt, p)
-        qids, strip_list, pair_strip, pair_slot, _ = ss._plan_device(
-            lax.slice_in_dim(probes, start, start + qt, axis=0),
-            cls_ord, n_lists, region_starts, s_tot,
-        )
-        v, i = _bq_tile_body(
-            lax.slice_in_dim(queries_rot, start, start + qt, axis=0),
-            qids, strip_list, pair_strip, pair_slot, list_codes, scale,
-            bias, list_ids, layout, int(k), kf, float(alpha),
-            bool(interpret),
-            None if pair_const is None
-            else lax.slice_in_dim(pair_const, start, start + qt, axis=0),
-            approx_ok, impl,
-        )
-        out_v.append(v)
-        out_i.append(i)
-    if len(out_v) == 1:
-        return out_v[0], out_i[0]
-    return jnp.concatenate(out_v, 0), jnp.concatenate(out_i, 0)
+    region_starts, s_tot, layout = ss.static_layout(
+        classes, class_counts, q, p)
+    qids, strip_list, pair_strip, pair_slot, _ = ss._plan_device(
+        probes, cls_ord, list_codes.shape[0], region_starts, s_tot)
+    return _bq_tile_body(
+        queries_rot, qids, strip_list, pair_strip, pair_slot, list_codes,
+        scale, bias, list_ids, layout, int(k), kf, float(alpha),
+        bool(interpret), pair_const, approx_ok, impl)
 
 
 # ---------------------------------------------------------------------------
@@ -563,78 +549,32 @@ def _paged_bq_class_jnp(strip_list, table_flat, chain_pages, sub_live,
 
 def paged_bq_search_traced(queries_rot, probes, codes, scale_pool,
                            bias_pool, page_ids, table, chain_pages, k: int,
-                           kf: int, alpha: float, q_tile: int,
-                           interpret: bool, pair_const=None,
-                           approx_ok: bool = False, impl: str = "pallas"):
-    """Sync-free paged packed strip search — the
+                           kf: int, alpha: float, interpret: bool,
+                           pair_const=None, approx_ok: bool = False,
+                           impl: str = "pallas"):
+    """Sync-free paged packed strip search of ONE query tile — the
     :func:`strip_scan.paged_strip_search_traced` protocol with the packed
     B operand and the per-row RaBitQ scale pool. All operands are
     capacity-shaped (zero-recompile serving contract)."""
-    from raft_tpu.ops.strip_scan import (PagedIds, _plan_device, paged_plan,
-                                         static_layout)
-
-    q, p = probes.shape
-    n_lists, table_width = table.shape
     page_rows = codes.shape[1]
-    ppf, n_sub, w = paged_plan(table_width, page_rows,
-                               int(codes.shape[-1]), kf)
+    ppf, n_sub, w = ss.paged_plan(table.shape[1], page_rows,
+                                  int(codes.shape[-1]), kf)
     if kf > w:
         raise ValueError(
             f"paged packed scan needs kf <= fetch block ({w} rows), got "
             f"{kf}")
-    classes = ((ppf, n_sub),)
-    class_counts = (n_lists,)
-    cls_ord = jnp.zeros((n_lists,), jnp.int32)
-    table_flat = table.reshape(-1)
-    translator = PagedIds(page_ids, table, page_rows)
+    fn = (_paged_bq_class_call if impl == "pallas"
+          else _paged_bq_class_jnp)
+    kwargs = {"interpret": interpret} if impl == "pallas" else {}
 
-    # per-(list, sub-block) filter-liveness words — the
-    # strip_scan.paged_strip_search_traced convention (all-+inf-bias pages
-    # contribute nothing; dead sub-blocks skip their DMAs and compute)
-    span = n_sub * ppf
-    page_live = jnp.any(jnp.isfinite(bias_pool), axis=1)
-    slot_live = page_live[jnp.maximum(table, 0)] & (table >= 0)
-    if span > table_width:
-        slot_live = jnp.pad(slot_live, ((0, 0), (0, span - table_width)))
-    elif span < table_width:
-        slot_live = slot_live[:, :span]
-    pos = jnp.arange(span, dtype=jnp.int32)[None, :]
-    slot_live = slot_live & (pos < chain_pages[:, None])
-    sub_live = jnp.any(slot_live.reshape(n_lists, n_sub, ppf),
-                       axis=2).astype(jnp.int32).reshape(-1)
+    def scan(strip_list, table_flat, sub_live, a_grouped):
+        return fn(strip_list, table_flat, chain_pages, sub_live, a_grouped,
+                  codes, scale_pool, bias_pool, ppf, n_sub, page_rows,
+                  table.shape[1], alpha, kf, approx_ok=approx_ok, **kwargs)
 
-    out_v, out_i = [], []
-    for start in range(0, q, q_tile):
-        qt = min(q_tile, q - start)
-        region_starts, s_tot, _ = static_layout(
-            classes, class_counts, qt, p)
-        qids, strip_list, pair_strip, pair_slot, _ = _plan_device(
-            lax.slice_in_dim(probes, start, start + qt, axis=0),
-            cls_ord, n_lists, region_starts, s_tot,
-        )
-        a_grouped = jnp.where(
-            (qids >= 0)[:, :, None],
-            lax.slice_in_dim(queries_rot, start, start + qt,
-                             axis=0)[jnp.clip(qids, 0), :],
-            0,
-        ).astype(jnp.bfloat16)
-        fn = (_paged_bq_class_call if impl == "pallas"
-              else _paged_bq_class_jnp)
-        kwargs = {"interpret": interpret} if impl == "pallas" else {}
-        ov, oe = fn(strip_list, table_flat, chain_pages, sub_live,
-                    a_grouped, codes, scale_pool, bias_pool, ppf, n_sub,
-                    page_rows, table_width, alpha, kf, approx_ok=approx_ok,
-                    **kwargs)
-        v, i = ss.merge_strip_candidates(
-            ov, oe, strip_list, pair_strip, pair_slot, translator, k, kf,
-            interpret,
-            None if pair_const is None
-            else lax.slice_in_dim(pair_const, start, start + qt, axis=0))
-        out_v.append(v)
-        out_i.append(i)
-    if len(out_v) == 1:
-        return out_v[0], out_i[0]
-    return jnp.concatenate(out_v, 0), jnp.concatenate(out_i, 0)
+    return ss._paged_tile_body(queries_rot, probes, bias_pool, page_ids,
+                              table, chain_pages, ppf, n_sub, scan, k, kf,
+                              interpret, pair_const)
 
 
 def occupancy_stats(lens, m: int, q: int, p: int, rot_dim: int,

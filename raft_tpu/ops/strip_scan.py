@@ -1311,13 +1311,14 @@ class PagedIds:
 
 def paged_strip_search_traced(queries_mat, probes, pages, bias_pool,
                               page_ids, table, chain_pages, k: int, kf: int,
-                              alpha: float, q_tile: int, interpret: bool,
+                              alpha: float, interpret: bool,
                               pair_const=None, approx_ok: bool = False,
                               impl: str = "pallas"):
-    """Sync-free paged strip search — fully traceable, so family callers
-    fuse coarse quantizer + device planning + paged kernel + merge +
-    finalize into ONE dispatch (the ``strip_search_traced`` protocol over
-    page chains).
+    """Sync-free paged strip search of ONE query tile (all of
+    ``queries_mat``) — fully traceable, so family callers fuse coarse
+    quantizer + device planning + paged kernel + merge + finalize into ONE
+    dispatch (the ``strip_search_traced`` protocol over page chains), and
+    split a search into tiles with :func:`per_tile`.
 
     pages: (capacity_pages, page_rows, row_width) payload pool.
     bias_pool: (capacity_pages, page_rows) fp32 — the store-maintained
@@ -1326,11 +1327,8 @@ def paged_strip_search_traced(queries_mat, probes, pages, bias_pool,
     chain_pages: (n_lists,) int32 live pages per list. Every operand is
     CAPACITY-shaped: steady-state upserts/deletes re-dispatch this same
     compiled program (the zero-recompile serving contract)."""
-    q, p = probes.shape
-    n_lists, table_width = table.shape
-    page_rows = pages.shape[1]
     ppf, n_sub, w = paged_plan(
-        table_width, page_rows,
+        table.shape[1], pages.shape[1],
         int(pages.shape[-1]) * pages.dtype.itemsize, kf)
     if kf > w:
         # the running per-pair top-kf can never recover candidates a
@@ -1339,12 +1337,30 @@ def paged_strip_search_traced(queries_mat, probes, pages, bias_pool,
         raise ValueError(
             f"paged strip scan needs kf <= fetch block ({w} rows), got "
             f"{kf}; use the gather backend")
-    # one capacity length class: the layout depends only on capacity
-    classes = ((ppf, n_sub),)
-    class_counts = (n_lists,)
-    cls_ord = jnp.zeros((n_lists,), jnp.int32)
-    table_flat = table.reshape(-1)
-    translator = PagedIds(page_ids, table, page_rows)
+    fn = _paged_class_call if impl == "pallas" else _paged_class_jnp
+    kwargs = {"interpret": interpret} if impl == "pallas" else {}
+
+    def scan(strip_list, table_flat, sub_live, a_grouped):
+        return fn(strip_list, table_flat, chain_pages, sub_live, a_grouped,
+                  pages, bias_pool, ppf, n_sub, pages.shape[1],
+                  table.shape[1], alpha, kf, approx_ok=approx_ok, **kwargs)
+
+    return _paged_tile_body(queries_mat, probes, bias_pool, page_ids, table,
+                           chain_pages, ppf, n_sub, scan, k, kf, interpret,
+                           pair_const)
+
+
+def _paged_tile_body(queries_mat, probes, bias_pool, page_ids, table,
+                    chain_pages, ppf: int, n_sub: int, scan, k: int, kf: int,
+                    interpret: bool, pair_const=None):
+    """The engine-independent part of a paged scan of one query tile:
+    filter-liveness words, device strip planning over the one capacity
+    length class, the grouped query side, then ``scan(strip_list,
+    table_flat, sub_live, a_grouped)`` — the payload's kernel — and the
+    shared merge. Shared by the fp/int8 and the packed ±1 paged engines."""
+    q, p = probes.shape
+    n_lists, table_width = table.shape
+    page_rows = bias_pool.shape[1]
 
     # Per-(list, sub-block) filter-liveness words (round 19, predicate
     # push-down): a page whose bias rows are ALL +inf (every row filtered
@@ -1365,36 +1381,19 @@ def paged_strip_search_traced(queries_mat, probes, pages, bias_pool,
     sub_live = jnp.any(slot_live.reshape(n_lists, n_sub, ppf),
                        axis=2).astype(jnp.int32).reshape(-1)
 
-    out_v, out_i = [], []
-    for start in range(0, q, q_tile):
-        qt = min(q_tile, q - start)
-        region_starts, s_tot, _ = static_layout(
-            classes, class_counts, qt, p)
-        qids, strip_list, pair_strip, pair_slot, _ = _plan_device(
-            lax.slice_in_dim(probes, start, start + qt, axis=0),
-            cls_ord, n_lists, region_starts, s_tot,
-        )
-        a_grouped = jnp.where(
-            (qids >= 0)[:, :, None],
-            lax.slice_in_dim(queries_mat, start, start + qt,
-                             axis=0)[jnp.clip(qids, 0), :],
-            0,
-        ).astype(jnp.bfloat16)
-        fn = _paged_class_call if impl == "pallas" else _paged_class_jnp
-        kwargs = {"interpret": interpret} if impl == "pallas" else {}
-        ov, oe = fn(strip_list, table_flat, chain_pages, sub_live,
-                    a_grouped, pages, bias_pool, ppf, n_sub, page_rows,
-                    table_width, alpha, kf, approx_ok=approx_ok, **kwargs)
-        v, i = merge_strip_candidates(
-            ov, oe, strip_list, pair_strip, pair_slot, translator, k, kf,
-            interpret,
-            None if pair_const is None
-            else lax.slice_in_dim(pair_const, start, start + qt, axis=0))
-        out_v.append(v)
-        out_i.append(i)
-    if len(out_v) == 1:
-        return out_v[0], out_i[0]
-    return jnp.concatenate(out_v, 0), jnp.concatenate(out_i, 0)
+    # one capacity length class: the layout depends only on capacity
+    region_starts, s_tot, _ = static_layout(((ppf, n_sub),), (n_lists,),
+                                            q, p)
+    qids, strip_list, pair_strip, pair_slot, _ = _plan_device(
+        probes, jnp.zeros((n_lists,), jnp.int32), n_lists, region_starts,
+        s_tot)
+    a_grouped = jnp.where(
+        (qids >= 0)[:, :, None], queries_mat[jnp.clip(qids, 0), :], 0,
+    ).astype(jnp.bfloat16)
+    ov, oe = scan(strip_list, table.reshape(-1), sub_live, a_grouped)
+    return merge_strip_candidates(
+        ov, oe, strip_list, pair_strip, pair_slot,
+        PagedIds(page_ids, table, page_rows), k, kf, interpret, pair_const)
 
 
 def paged_occupancy_stats(table_width: int, page_rows: int, chain_pages,

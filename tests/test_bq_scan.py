@@ -71,8 +71,7 @@ def run_both(queries, probes, codes, scale, bias, ids, lens, k,
                 jnp.asarray(queries), jnp.asarray(probes),
                 jnp.asarray(codes), jnp.asarray(scale), jnp.asarray(bias),
                 jnp.asarray(ids), jnp.asarray(cls_ord_np), tuple(classes),
-                class_counts, int(k), int(k), float(alpha),
-                queries.shape[0], True,
+                class_counts, int(k), int(k), float(alpha), True,
                 None if pair_const is None else jnp.asarray(pair_const),
                 False, impl)
     return outs

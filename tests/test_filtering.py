@@ -40,6 +40,30 @@ def _data(rng, n=600, dim=16, q=8):
             rng.normal(size=(q, dim)).astype(np.float32))
 
 
+#: the six IVF search entries, which share one prologue
+#: (neighbors/_entry.prepare)
+ENTRIES = ["ivf_flat.search", "ivf_flat.search_paged", "ivf_pq.search",
+           "ivf_pq.search_paged", "ivf_bq.search", "ivf_bq.search_paged"]
+_FAMILY = {"ivf_flat": ivf_flat, "ivf_pq": ivf_pq, "ivf_bq": ivf_bq}
+
+
+def _entry(rng, name, metric="sqeuclidean"):
+    """``(search, target, X, Q)`` for one of :data:`ENTRIES`: ``search(
+    target, queries, k, ...)`` over a small index, or over the paged store
+    wrapping it."""
+    family, entry = name.split(".")
+    params = {"ivf_flat": ivf_flat.IvfFlatParams(n_lists=8, metric=metric),
+              "ivf_pq": ivf_pq.IvfPqParams(n_lists=8, pq_dim=8,
+                                           metric=metric),
+              "ivf_bq": ivf_bq.IvfBqParams(n_lists=8, metric=metric)}[family]
+    # the shapes of _data's other callers here, so builds share compiles
+    X, Q = _data(rng)
+    target = _FAMILY[family].build(X, params)
+    if entry == "search_paged":
+        target = serving.PagedListStore.from_index(target)
+    return getattr(_FAMILY[family], entry), target, X, Q
+
+
 # ---------------------------------------------------------------------------
 # bitset: popcount / pass_rate
 # ---------------------------------------------------------------------------
@@ -243,17 +267,17 @@ def test_widening_recovers_selective_recall(rng):
     assert mask[i[np.isfinite(np.asarray(v))]].all()
 
 
-def test_widening_stamped_on_span(rng):
+@pytest.mark.parametrize("entry", ENTRIES)
+def test_widening_stamped_on_span(rng, entry):
     from raft_tpu import obs
 
-    X, Q = _data(rng, n=400)
-    idx = ivf_flat.build(X, ivf_flat.IvfFlatParams(n_lists=16))
+    search, target, X, Q = _entry(rng, entry)
     f = Bitset.from_mask(rng.random(X.shape[0]) < 0.25)
     obs.reset()
     obs.clear_spans()
     obs.enable()
     try:
-        ivf_flat.search(idx, Q, 5, n_probes=4, filter=f)
+        search(target, Q, 5, n_probes=4, filter=f)
         spans = [s for s in obs.spans()
                  if "filter_pass_rate" in (s.get("attrs") or {})]
         assert spans, "no span carried the filter plan"
@@ -301,10 +325,9 @@ def test_search_refined_widens_k_fetch(rng):
 # store.set_filter + zero-recompile contract
 # ---------------------------------------------------------------------------
 
-def test_store_set_filter_zero_recompile(rng):
-    X, Q = _data(rng, n=900)
-    idx = ivf_flat.build(X, ivf_flat.IvfFlatParams(n_lists=8))
-    store = serving.PagedListStore.from_index(idx)
+@pytest.mark.parametrize("entry", [e for e in ENTRIES if "paged" in e])
+def test_store_set_filter_zero_recompile(rng, entry):
+    _, store, X, Q = _entry(rng, entry)
     serving.search(store, Q, 5, n_probes=8)  # warm the unfiltered program
     t0 = serving.scan_trace_count()
     store.set_filter(np.arange(X.shape[0]) % 3 == 0)
@@ -327,6 +350,33 @@ def test_store_set_filter_zero_recompile(rng):
     v, i = serving.search(store, Q, 5, n_probes=8)
     fin = np.isfinite(np.asarray(v))
     assert not (np.asarray(i)[fin] % 3 == 0).all()
+
+
+# ---------------------------------------------------------------------------
+# the shared prologue: validation and cosine normalisation, all six entries
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("entry", ENTRIES)
+def test_entry_rejects_bad_queries_and_k(rng, entry):
+    search, target, _, Q = _entry(rng, entry)
+    with pytest.raises(ValueError, match="queries must be"):
+        search(target, Q[:, :5], 5)
+    with pytest.raises(ValueError, match="queries must be"):
+        search(target, Q[0], 5)
+    for k in (0, 10 ** 6):
+        with pytest.raises(ValueError, match=f"k={k} out of range"):
+            search(target, Q, k, n_probes=4)
+
+
+@pytest.mark.parametrize("entry", ENTRIES)
+def test_entry_normalises_cosine_queries(rng, entry):
+    """A cosine index answers a query and its double alike: the prologue
+    scales every query to unit norm (by powers of two, exactly)."""
+    search, target, _, Q = _entry(rng, entry, metric="cosine")
+    v1, i1 = search(target, Q, 5, n_probes=4)
+    v2, i2 = search(target, 2.0 * Q, 5, n_probes=4)
+    np.testing.assert_array_equal(np.asarray(i1), np.asarray(i2))
+    np.testing.assert_array_equal(np.asarray(v1), np.asarray(v2))
 
 
 # ---------------------------------------------------------------------------

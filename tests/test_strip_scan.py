@@ -199,6 +199,53 @@ def test_ragged_search_tiles_one_dispatch_each(rng, family):
                                rtol=1e-6)
 
 
+@pytest.mark.parametrize("case", ["ivf_flat.search_paged",
+                                  "ivf_pq.search_paged", "ivf_bq.search",
+                                  "ivf_bq.search_paged"])
+def test_strip_scan_tiles_one_program_each(rng, monkeypatch, case):
+    """The paged and IVF-BQ scans launch through strip_scan.per_tile too:
+    at a 64-query tile, 150 queries run ceil(150/64) = 3 programs, each
+    scanning one tile, and answer as the same search in one tile does."""
+    from raft_tpu import serving
+    from raft_tpu.neighbors import ivf_bq, ivf_flat, ivf_pq
+    from raft_tpu.ops import strip_scan as ss
+
+    family, entry = case.split(".")
+    mod = {"ivf_flat": ivf_flat, "ivf_pq": ivf_pq, "ivf_bq": ivf_bq}[family]
+    # the build shapes of the test above, so the builds share its compiles
+    X = rng.normal(size=(3000, 16)).astype(np.float32)
+    Q = rng.normal(size=(150, 16)).astype(np.float32)
+    params = {"ivf_flat": ivf_flat.IvfFlatParams(n_lists=8, group_size=512),
+              "ivf_pq": ivf_pq.IvfPqParams(n_lists=8, pq_dim=8,
+                                           group_size=512),
+              "ivf_bq": ivf_bq.IvfBqParams(n_lists=8)}[family]
+    index = mod.build(X, params)
+    if entry == "search_paged":
+        target = serving.PagedListStore.from_index(index, page_rows=128)
+        backend = "paged_pallas"
+        program = {"ivf_flat": "_paged_fused", "ivf_pq": "_paged_fused_pq",
+                   "ivf_bq": "_paged_fused_bq"}[family]
+    else:
+        target, backend, program = index, "packed", "_bq_fused"
+    search = getattr(mod, entry)
+    one = search(target, Q, 10, n_probes=3, backend=backend)
+
+    calls = []
+    launch = getattr(mod, program)
+
+    def counted(queries, *args):
+        calls.append(queries.shape[0])
+        return launch(queries, *args)
+
+    monkeypatch.setattr(ss, "fit_q_tile", lambda *a, **kw: 64)
+    monkeypatch.setattr(mod, program, counted)
+    tiled = search(target, Q, 10, n_probes=3, backend=backend)
+    assert calls == [50, 50, 50]
+    np.testing.assert_array_equal(np.asarray(tiled[1]), np.asarray(one[1]))
+    np.testing.assert_allclose(np.asarray(tiled[0]), np.asarray(one[0]),
+                               rtol=1e-6)
+
+
 def _score_block(rng, c, w):
     """Scores with the cases an extraction must survive: +inf padding
     lanes, an all-+inf row, runs of equal scores, a NaN and a -inf."""
