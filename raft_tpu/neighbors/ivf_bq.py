@@ -63,6 +63,7 @@ from raft_tpu.ops import distance as dist_mod
 from raft_tpu.ops import linalg
 from raft_tpu.ops.bq_scan import (extend_query_planes, multibit_width,
                                   pack_code_planes, pack_sign_bits)
+from raft_tpu.ops.select_k import probe_algo
 
 # legacy alias (pre-round-17 this module imported ivf_pq's private helper;
 # the shared copy now lives in ops/linalg — satellite 1)
@@ -757,11 +758,7 @@ def _bq_fused(queries, centers, rotation, list_codes, list_scale, list_bias,
                 "interpret": interpret, "impl": impl,
                 "bits": bits, "rotation_kind": rotation_kind})
     l2 = metric in ("sqeuclidean", "euclidean")
-    # packed coarse select only while its perturbation bound stays tight
-    # (2^-(23-ceil(log2 n_lists)) ≤ 5e-4 at 4096 lists — see
-    # ivf_flat._ragged_fused)
-    sa = ("packed" if select_algo == "exact" and not interpret
-          and centers.shape[0] <= 4096 else select_algo)
+    sa = probe_algo(select_algo, interpret, centers.shape[0])
     probes, qr, bias, pair_const = _bq_search_prep(
         queries, centers, rotation, list_bias, list_ids, filter,
         n_probes, metric, sa, compute_dtype, l2, bits, rotation_kind,
@@ -907,8 +904,7 @@ def _paged_fused_bq(queries, centers, rotation, codes_pool, scale_pool,
                 "interpret": interpret, "impl": impl,
                 "bits": bits, "rotation_kind": rotation_kind})
     l2 = metric in ("sqeuclidean", "euclidean")
-    sa = ("packed" if select_algo == "exact" and not interpret
-          and centers.shape[0] <= 4096 else select_algo)
+    sa = probe_algo(select_algo, interpret, centers.shape[0])
     # THE packed path's prep (one copy — probes/rotation/pair_const are
     # bitwise parity by construction); the bias/ids operands are simply
     # the store's pools instead of the packed arrays

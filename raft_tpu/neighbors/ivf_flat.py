@@ -45,7 +45,7 @@ from raft_tpu.core.trace import traced
 from raft_tpu.core.resources import Resources, current_resources
 from raft_tpu.core.serialize import load_arrays, save_arrays
 from raft_tpu.ops import distance as dist_mod
-from raft_tpu.ops.select_k import select_k
+from raft_tpu.ops.select_k import probe_algo, select_k
 from raft_tpu.utils.tiling import map_row_tiles
 
 SUPPORTED_METRICS = ("sqeuclidean", "euclidean", "inner_product", "cosine")
@@ -396,14 +396,7 @@ def _ragged_fused(queries, centers, list_data, bias, list_ids, cls_ord,
                 "classes": classes, "class_counts": class_counts,
                 "interpret": interpret})
 
-    # "exact" probe selection rides the packed iter (half the VPU passes)
-    # only while n_lists keeps the index bits cheap: the perturbation is
-    # 2^-(23-ceil(log2 n_lists)) relative — ≤ 5e-4 at 4096 lists, where it
-    # only reorders boundary-tie lists (recall-neutral, measured). Larger
-    # n_lists would steal real mantissa (ADVICE r4 medium), so "exact" is
-    # honored literally there.
-    sa = ("packed" if select_algo == "exact" and not interpret
-          and centers.shape[0] <= 4096 else select_algo)
+    sa = probe_algo(select_algo, interpret, centers.shape[0])
     probes = _coarse_probes(queries, centers, n_probes, metric, sa,
                             compute_dtype)
     l2 = metric in ("sqeuclidean", "euclidean")
@@ -471,7 +464,11 @@ def _search_ragged(index, queries, k, n_probes, filter, select_algo, res):
     from raft_tpu.ops.strip_scan import note_topk_routes, per_tile
 
     if obs.enabled():
-        note_topk_routes(classes, int(k), False, queries.shape[0], q_tile)
+        interpret = jax.default_backend() != "tpu"
+        note_topk_routes(classes, int(k), False, queries.shape[0], q_tile,
+                         index.n_lists, n_probes, int(k),
+                         probe_algo(select_algo, interpret, index.n_lists),
+                         interpret)
     return per_tile(lambda lo, hi: _ragged_fused(
         queries[lo:hi], index.centers, index.list_data, bias,
         index.list_ids, cls_ord, int(k), n_probes, index.metric,
@@ -742,9 +739,8 @@ def _paged_fused(queries, centers, pages, bias_pool, page_ids, table,
                 "select_algo": select_algo, "compute_dtype": compute_dtype,
                 "interpret": interpret, "impl": impl})
     # same coarse select as the packed ragged path (parity: probe choice
-    # decides the candidate set — see ivf_flat._ragged_fused's bound note)
-    sa = ("packed" if select_algo == "exact" and not interpret
-          and centers.shape[0] <= 4096 else select_algo)
+    # decides the candidate set)
+    sa = probe_algo(select_algo, interpret, centers.shape[0])
     probes = _coarse_probes(queries, centers, n_probes, metric, sa,
                             compute_dtype)
     # the store's bias pool is already +inf at dead slots; the filter

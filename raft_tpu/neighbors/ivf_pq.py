@@ -57,7 +57,7 @@ from raft_tpu.core.serialize import load_arrays, save_arrays
 from raft_tpu.ops import distance as dist_mod
 from raft_tpu.ops import linalg
 from raft_tpu.ops.pq_scan import group_probed_pairs, pq_scan
-from raft_tpu.ops.select_k import select_k
+from raft_tpu.ops.select_k import probe_algo, select_k
 from raft_tpu.utils.tiling import map_row_tiles
 
 _log = get_logger()
@@ -1076,11 +1076,7 @@ def _ragged_fused_pq(queries, centers, rotation, b_sum, list_ids, decoded,
                 "l2": l2, "classes": classes, "class_counts": class_counts,
                 "interpret": interpret})
 
-    # packed coarse select only while its perturbation bound stays tight
-    # (2^-(23-ceil(log2 n_lists)) ≤ 5e-4 at 4096 lists; ADVICE r4 medium —
-    # see ivf_flat._ragged_fused)
-    sa = ("packed" if select_algo == "exact" and not interpret
-          and centers.shape[0] <= 4096 else select_algo)
+    sa = probe_algo(select_algo, interpret, centers.shape[0])
     probes, qr_scaled, bias, pair_const = _pq_search_prep(
         queries, centers, rotation, b_sum, list_ids, decoded_scale,
         filter, n_probes, metric, sa, compute_dtype, l2,
@@ -1126,7 +1122,11 @@ def _search_ragged_pq(index, queries, k, n_probes, filter, select_algo, res):
     from raft_tpu.ops.strip_scan import note_topk_routes, per_tile
 
     if obs.enabled():
-        note_topk_routes(classes, int(k), True, queries.shape[0], q_tile)
+        interpret = jax.default_backend() != "tpu"
+        note_topk_routes(classes, int(k), True, queries.shape[0], q_tile,
+                         index.n_lists, n_probes, int(k),
+                         probe_algo(select_algo, interpret, index.n_lists),
+                         interpret)
     return per_tile(lambda lo, hi: _ragged_fused_pq(
         queries[lo:hi], index.centers, index.rotation, index.b_sum,
         index.list_ids, index.decoded, index.decoded_scale, filter, cls_ord,
@@ -1725,8 +1725,7 @@ def _paged_fused_pq(queries, centers, rotation, cache_pool, bias_pool,
                 "select_algo": select_algo, "compute_dtype": compute_dtype,
                 "interpret": interpret, "impl": impl})
     l2 = metric in ("sqeuclidean", "euclidean")
-    sa = ("packed" if select_algo == "exact" and not interpret
-          and centers.shape[0] <= 4096 else select_algo)
+    sa = probe_algo(select_algo, interpret, centers.shape[0])
     # the packed path's shared probe prep (bitwise parity by
     # construction); the bias comes from the store-maintained pool —
     # already rc2 + b_sum per row — instead of _ragged_bias_pq

@@ -28,6 +28,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
 
 
 def iter_topk_min(values, k: int):
@@ -134,6 +135,91 @@ def iter_topk_min_packed(values, k: int):
     return out_v, jnp.stack(idxs, -1).astype(jnp.int32)
 
 
+_RESIDENT_BLOCK_BYTES = 1 << 20  # one f32 row block; the pipeline holds two
+
+
+def resident_ok(n: int, k: int) -> bool:
+    """Whether a packed top-k of ``k`` over ``n`` columns fits
+    :func:`resident_topk_min_packed`: k ≤ 64 and the packed route's n ≤
+    8192 (its callers add: on TPU only)."""
+    return 0 < k <= min(n, 64) and n <= (1 << 13)
+
+
+def probe_algo(select_algo: str, interpret: bool, n_lists: int) -> str:
+    """The coarse probe's select in every IVF search: "exact" rides the
+    packed passes on the chip (half the VPU passes, and on TPU the resident
+    kernel) only while n_lists keeps the index bits cheap: the perturbation
+    is 2^-(23-ceil(log2 n_lists)) relative — ≤ 5e-4 at 4096 lists, where
+    it only reorders boundary-tie lists (recall-neutral, measured). Larger
+    n_lists would steal real mantissa (ADVICE r4 medium), so "exact" is
+    honored literally there."""
+    if select_algo == "exact" and not interpret and n_lists <= 4096:
+        return "packed"
+    return select_algo
+
+
+def _resident_rows(rows: int, w: int) -> int:
+    """Rows of one block: the most, up to 512, whose (rows, w) f32 block
+    stays within _RESIDENT_BLOCK_BYTES (256 at w = 640 or 1024, 32 at
+    8192), or all the rows where fewer."""
+    r = 512
+    while r > 8 and r * w * 4 > _RESIDENT_BLOCK_BYTES:
+        r //= 2
+    return rows if rows <= r else r
+
+
+def _resident_kernel(x_ref, v_ref, i_ref, *, n: int, k: int, w: int,
+                     bits: int):
+    """One row block: pack, then all k passes in VMEM on the extraction
+    route the strip kernel takes at (k, w) (ops/strip_scan._topk_block).
+    Lanes at or past ``n`` (the block's padding to whole 128-lane slabs,
+    read past the array's end) are set to +inf: packed, they sort after
+    every real column, +inf ones included."""
+    from raft_tpu.ops import strip_scan as ss
+
+    x = x_ref[...]
+    if w > n:
+        cols = lax.broadcasted_iota(jnp.int32, x.shape, 1)
+        x = jnp.where(cols < n, x, jnp.inf)
+    vals, idx = ss._topk_block(x, k, w, False, bits)
+    # the -inf restore of iter_topk_min_packed (the +inf one is the
+    # extraction's own)
+    v_ref[...] = jnp.where(vals <= -pack_clamp_for(bits), -jnp.inf, vals)
+    i_ref[...] = idx
+
+
+@functools.partial(jax.jit, static_argnames=("k", "interpret"))
+def resident_topk_min_packed(values, k: int, interpret: bool = False):
+    """:func:`iter_topk_min_packed` of a (rows, n) array as one Pallas
+    kernel: each row block is read from HBM once and packed with the same
+    ``pack_values(v, _pack_bits_for(n))``, and all k passes run in VMEM,
+    where XLA makes each pass a reduce fusion that reads the whole array
+    again. Values and indices equal iter_topk_min_packed's bit for bit:
+    the same packed values leave in the same order, on the direct passes
+    or, where :func:`strip_scan.topk_route` picks it for (k, n rounded up
+    to 128 lanes), the sorted lane bins. Needs :func:`resident_ok`."""
+    from raft_tpu.ops import strip_scan as ss
+
+    v = values.astype(jnp.float32)
+    rows, n = v.shape
+    assert resident_ok(n, k), (n, k)
+    w = -(-n // 128) * 128
+    if ss.topk_route(k, w) != "sorted":
+        w = n   # the direct passes take the row as it is
+    r = _resident_rows(rows, w)
+    out = jax.ShapeDtypeStruct((rows, k), jnp.float32)
+    return pl.pallas_call(
+        functools.partial(_resident_kernel, n=n, k=k, w=w,
+                          bits=_pack_bits_for(n)),
+        grid=(pl.cdiv(rows, r),),
+        in_specs=[pl.BlockSpec((r, w), lambda i: (i, 0))],
+        out_specs=[pl.BlockSpec((r, k), lambda i: (i, 0))] * 2,
+        out_shape=(out, out.update(dtype=jnp.int32)),
+        interpret=interpret,
+        name="select_k_resident",
+    )(v)
+
+
 @functools.partial(jax.jit, static_argnames=("k", "select_min", "algo", "recall_target"))
 def _select_k_impl(values, k, select_min, algo, recall_target):
     if algo == "approx":
@@ -141,8 +227,10 @@ def _select_k_impl(values, k, select_min, algo, recall_target):
             vals, idx = lax.approx_min_k(values, k, recall_target=recall_target)
         else:
             vals, idx = lax.approx_max_k(values, k, recall_target=recall_target)
-    elif algo == "packed":
-        vals, idx = iter_topk_min_packed(values if select_min else -values, k)
+    elif algo in ("packed", "resident"):
+        topk = (resident_topk_min_packed if algo == "resident"
+                else iter_topk_min_packed)
+        vals, idx = topk(values if select_min else -values, k)
         if not select_min:
             vals = -vals
     elif algo == "iter":
@@ -176,7 +264,8 @@ def select_k(
     ``algo``: "exact" (lax.top_k) | "iter" (k masked-min passes; exact,
     the fast TPU route for small k) | "packed" (mantissa-packed iter —
     half the passes' cost, values perturbed ≤ 2^-(23-ceil(log2 n))
-    relative — ~1e-4 at n=1024, ~1e-3 at the n=8192 fallback bound) |
+    relative — ~1e-4 at n=1024, ~1e-3 at the n=8192 fallback bound; on
+    TPU at k ≤ 64 one VMEM-resident kernel, resident_topk_min_packed) |
     "approx"
     (TPU partial-reduce; ``recall_target`` trades recall for speed).
     "exact" auto-routes to "iter" for k <= 64 on TPU — same results,
@@ -207,6 +296,9 @@ def select_k(
         # worst case — inconsistent with the documented contract, ADVICE
         # r4), so they fall back to the exact iter select
         algo = "iter"
+    if (algo == "packed" and jax.default_backend() == "tpu"
+            and resident_ok(values.shape[-1], k)):
+        algo = "resident"   # the same passes, in one VMEM-resident kernel
     vals, idx = _select_k_impl(values, int(k), bool(select_min), algo, float(recall_target))
     if indices is not None:
         indices = jnp.asarray(indices)

@@ -29,9 +29,10 @@ shrinks to one gather + one small select over (q, n_probes·kf).
     A (query, probe) pair maps to exactly one strip slot, so these ARE the
     per-pair candidates — no cross-chunk reduction exists anymore.
   * The merge is one XLA gather of (q, p, kf) candidate rows followed by an
-    iterative top-k over p·kf candidates (ops/select_k.iter_topk_min; TPU
-    top_k's sort measured ~10× slower at these widths) and one final
-    (q, k) id-translate gather.
+    iterative top-k over p·kf candidates (on the chip one VMEM-resident
+    kernel, ops/select_k.resident_topk_min_packed; TPU top_k's sort
+    measured ~10× slower at these widths) and one final (q, k)
+    id-translate gather.
 
 Work remains ∝ Σ_pairs len(list): no per-list query cap, zero candidate
 drops by construction (pairs beyond one strip's query slots get their
@@ -226,14 +227,13 @@ def plan_strips(probes: np.ndarray, lens: np.ndarray, n_lists: int) -> StripPlan
 _PACK_BITS = 12          # low-mantissa bits carrying the column index
                          # (covers w = MAX_CLASS·MC = 4096; ≤ 2⁻¹¹ relative
                          # value perturbation — inside the bf16 contract)
-_PACK_MASK = (1 << _PACK_BITS) - 1
 _NB = 128                # lane bins of the sorted route and the tournament
                          # (strided: bin j = cols ≡ j mod _NB — a full VPU
                          # lane row, so the per-bin operations stay wide)
 
 
-def _pack_scores(s, w: int):
-    """Pack column ids into the low mantissa bits of fp32 scores
+def _pack_scores(s, w: int, bits: int = _PACK_BITS):
+    """Pack column ids into the low ``bits`` mantissa bits of fp32 scores
     (ops/select_k.pack_values — shared so the clamp/NaN/±inf invariants
     live in one place).
 
@@ -242,21 +242,24 @@ def _pack_scores(s, w: int):
     (compare-to-min + one-hot sum) that dominated the round-3 kernel cost
     drops out entirely. The ≤ 2⁻¹¹ relative perturbation (12 index bits)
     sits inside this path's documented bf16 (~3 significant digits)
-    ranking contract.
+    ranking contract. The resident top-k (ops/select_k) packs with the
+    bits ``iter_topk_min_packed`` takes for its row width instead.
     """
-    assert w <= (1 << _PACK_BITS), w
+    assert w <= (1 << bits), w
     from raft_tpu.ops.select_k import pack_values
 
-    return pack_values(s, _PACK_BITS)
+    return pack_values(s, bits)
 
 
-def _extract_passes(state, head, drop, c: int, kf: int):
+def _extract_passes(state, head, drop, c: int, kf: int,
+                    bits: int = _PACK_BITS):
     """kf extraction passes → ((C, kf) values, (C, kf) columns).
-    ``head(state)`` is a packed block whose row minimum is the row's next
-    winner; ``drop(state, hit)`` removes it (``hit`` marks it in
-    ``head(state)``). Values at the packing clamp are restored to +inf: a
-    clamped +inf sentinel (filtered/padding entry) must come back as inf,
-    not as a finite ~3.4e38 hit (code-review r4)."""
+    ``head(state)`` is a block packed with ``bits`` index bits whose row
+    minimum is the row's next winner; ``drop(state, hit)`` removes it
+    (``hit`` marks it in ``head(state)``). Values at the packing clamp are
+    restored to +inf: a clamped +inf sentinel (filtered/padding entry)
+    must come back as inf, not as a finite ~3.4e38 hit (code-review r4)."""
+    mask = (1 << bits) - 1
     kcols = lax.broadcasted_iota(jnp.int32, (c, kf), 1)
 
     def body(i, carry):
@@ -264,8 +267,8 @@ def _extract_passes(state, head, drop, c: int, kf: int):
         h = head(state)
         mn = jnp.min(h, axis=1)                       # packed winner
         mb = lax.bitcast_convert_type(mn, jnp.int32)
-        e = mb & jnp.int32(_PACK_MASK)
-        v = lax.bitcast_convert_type(mb & jnp.int32(~_PACK_MASK), jnp.float32)
+        e = mb & jnp.int32(mask)
+        v = lax.bitcast_convert_type(mb & jnp.int32(~mask), jnp.float32)
         sel = kcols == i
         vals = jnp.where(sel, v[:, None], vals)
         es = jnp.where(sel, e[:, None], es)
@@ -281,17 +284,17 @@ def _extract_passes(state, head, drop, c: int, kf: int):
     # pack_clamp_for's value already has zero low mantissa bits, so the
     # unpacked winner of a clamped entry equals it exactly (static python
     # float: Mosaic rejects scalar bitcast ops in-kernel)
-    return jnp.where(vals >= pack_clamp_for(_PACK_BITS), jnp.inf, vals), es
+    return jnp.where(vals >= pack_clamp_for(bits), jnp.inf, vals), es
 
 
-def _extract_topk_packed(pv, kf: int):
+def _extract_topk_packed(pv, kf: int, bits: int = _PACK_BITS):
     """kf min passes over packed scores (C, n) → ((C, kf) values, (C, kf)
     columns). Two full-width VPU ops per pass (min + mask) vs the generic
     _extract_topk's five — the packed trick halves-to-thirds the kernel's
     dominant cost."""
     return _extract_passes(
         pv, lambda pv: pv, lambda pv, hit: jnp.where(hit, jnp.inf, pv),
-        pv.shape[0], kf)
+        pv.shape[0], kf, bits)
 
 
 @functools.lru_cache(maxsize=None)
@@ -316,7 +319,7 @@ def _sort_pairs(n: int) -> Tuple[Tuple[int, int], ...]:
     return tuple(pairs)
 
 
-def _extract_topk_sorted(pv, kf: int):
+def _extract_topk_sorted(pv, kf: int, bits: int = _PACK_BITS):
     """Exact top-kf of packed scores (C, w), w = bs·_NB, from sorted lane
     bins: the same (values, columns) as :func:`_extract_topk_packed`.
 
@@ -347,7 +350,7 @@ def _extract_topk_sorted(pv, kf: int):
         return jnp.where(hit, nxt, head), depth
 
     return _extract_passes((lv[0], jnp.zeros(lv[0].shape, jnp.int32)),
-                           lambda state: state[0], drop, c, kf)
+                           lambda state: state[0], drop, c, kf, bits)
 
 
 def _extract_topk(v, offs, kf: int):
@@ -411,12 +414,13 @@ def topk_route(kf: int, w: int, approx_ok: bool = False) -> str:
     return "sorted" if sort_ops < 3 * kf * bs else "direct"
 
 
-def _topk_block(s, kf: int, w: int, approx_ok: bool):
+def _topk_block(s, kf: int, w: int, approx_ok: bool,
+                bits: int = _PACK_BITS):
     """Top-kf of a (C, w) score block, on the route :func:`topk_route`
     picks from the static (kf, w).
 
-    Every route first packs each column id into its score's low mantissa
-    bits (_pack_scores), so a row minimum names its winner.
+    Every route first packs each column id into its score's low ``bits``
+    mantissa bits (_pack_scores), so a row minimum names its winner.
 
     * ``direct`` (:func:`_extract_topk_packed`): kf min passes over the
       whole block, each a min, a compare and a select over all w lanes.
@@ -440,11 +444,11 @@ def _topk_block(s, kf: int, w: int, approx_ok: bool):
     c = s.shape[0]
     bs = w // _NB
     route = topk_route(kf, w, approx_ok)
-    pv = _pack_scores(s, w)
+    pv = _pack_scores(s, w, bits)
     if route == "sorted":
-        return _extract_topk_sorted(pv, kf)
+        return _extract_topk_sorted(pv, kf, bits)
     if route == "direct":
-        return _extract_topk_packed(pv, kf)
+        return _extract_topk_packed(pv, kf, bits)
     # tournament on packed values: the bin survivors carry their own column
     # ids in the mantissa, so the pool extraction needs no offset tables
     sv = pv.reshape(c, bs, _NB)
@@ -453,7 +457,7 @@ def _topk_block(s, kf: int, w: int, approx_ok: bool):
         mn = jnp.min(sv, axis=1)                       # (C, _NB) packed
         pool.append(mn)
         sv = jnp.where(sv == mn[:, None, :], jnp.inf, sv)
-    return _extract_topk_packed(jnp.concatenate(pool, axis=1), kf)
+    return _extract_topk_packed(jnp.concatenate(pool, axis=1), kf, bits)
 
 
 def _store_candidates(outv_ref, oute_ref, nv, ne, j, n_sub: int, kf: int):
@@ -703,6 +707,13 @@ def _strip_tile_body(queries_mat, qids, strip_list, pair_strip, pair_slot,
                                   list_ids, k, kf, interpret, pair_const)
 
 
+def merge_on_resident(p: int, k: int, kf: int, interpret: bool) -> bool:
+    """Whether :func:`merge_strip_candidates` selects its top-k of the
+    (q, p·kf) candidates with ops/select_k.resident_topk_min_packed: on
+    the chip, k ≤ 64 and p·kf ≤ 2048 (≤ 11 index bits)."""
+    return not interpret and min(k, p * kf) <= 64 and p * kf <= 2048
+
+
 def merge_strip_candidates(cand_v, cand_e, strip_list, pair_strip, pair_slot,
                            list_ids, k: int, kf: int, interpret: bool,
                            pair_const=None):
@@ -723,16 +734,18 @@ def merge_strip_candidates(cand_v, cand_e, strip_list, pair_strip, pair_slot,
             cand = cand + pair_const[:, :, None]
         cand_v = cand.reshape(q, p * kf)
         cand_e = cand_e[pair_strip, :, pair_slot].reshape(q, p * kf)
-        from raft_tpu.ops.select_k import iter_topk_min, iter_topk_min_packed
+        from raft_tpu.ops.select_k import (iter_topk_min,
+                                           resident_topk_min_packed)
 
         kk = min(k, p * kf)
-        if kk <= 64 and not interpret and p * kf <= 2048:
-            # packed passes: half the VPU cost of iter_topk_min; ≤ 11 index
-            # bits keeps the perturbation ≤ 2^-12 ≈ 2.4e-4 — inside this
-            # path's bf16 score contract. Wider merges (big n_probes · kf)
-            # would dilute the value mantissa (code-review r4), so they take
-            # the exact iter passes instead.
-            vals, sel = iter_topk_min_packed(cand_v, kk)
+        if merge_on_resident(p, k, kf, interpret):
+            # packed passes (iter_topk_min_packed's, in one VMEM-resident
+            # kernel that reads the candidates once): half the VPU cost of
+            # iter_topk_min; ≤ 11 index bits keeps the perturbation ≤
+            # 2^-12 ≈ 2.4e-4 — inside this path's bf16 score contract.
+            # Wider merges (big n_probes · kf) would dilute the value
+            # mantissa (code-review r4), so they take the exact iter passes.
+            vals, sel = resident_topk_min_packed(cand_v, kk)
         elif kk <= 64 and not interpret:
             vals, sel = iter_topk_min(cand_v, kk)
         else:
@@ -953,17 +966,28 @@ def per_tile(search, q: int, q_tile: int, span: str = "",
 
 
 def note_topk_routes(classes, kf: int, approx_ok: bool, q: int,
-                     q_tile: int) -> None:
+                     q_tile: int, n_lists: int, p: int, k: int,
+                     probe_algo: str, interpret: bool) -> None:
     """Count the length-class kernel calls of the programs
     :func:`per_tile` launches for ``q`` queries (``strip_scan.topk.calls``)
     and those of them whose in-kernel top-kf takes the sorted route
-    (``strip_scan.topk.sorted``), from the static (kf, w) alone: no device
-    fetch. Callers gate it on ``obs.enabled()``."""
+    (``strip_scan.topk.sorted``), from the static (kf, w) alone; and their
+    top-k calls on ops/select_k.resident_topk_min_packed
+    (``select_k.resident.calls``): the coarse probe's select of
+    ``n_lists`` (``probe_algo``, select_k's guards) and the merge
+    (:func:`merge_on_resident`). No device fetch. Callers gate it on
+    ``obs.enabled()``."""
+    from raft_tpu.ops.select_k import resident_ok
+
     launches = _ceil_div(q, q_tile)
     routes = [topk_route(kf, w_blocks * MC, approx_ok)
               for w_blocks, _ in classes]
     obs.add("strip_scan.topk.calls", launches * len(routes))
     obs.add("strip_scan.topk.sorted", launches * routes.count("sorted"))
+    resident = (probe_algo == "packed" and not interpret
+                and resident_ok(n_lists, p))
+    resident += merge_on_resident(p, k, kf, interpret)
+    obs.add("select_k.resident.calls", launches * int(resident))
 
 
 def occupancy_stats(lens, m: int, q: int, p: int, dim: int = 0,

@@ -20,7 +20,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from raft_tpu.ops import bq_scan, cagra_hop, pq_scan
+from raft_tpu.ops import bq_scan, cagra_hop, pq_scan, select_k
 from raft_tpu.ops import strip_scan as ss
 
 
@@ -136,6 +136,18 @@ def test_paged_bq_kernel(one_chip):
              ((cap_pages, page_rows, nb), jnp.uint8),
              ((cap_pages, page_rows), jnp.float32),
              ((cap_pages, page_rows), jnp.float32))
+
+
+@pytest.mark.parametrize("rows,n,k", [
+    (10_000, 160, 10),     # IVF-Flat's merge: 16 probes × kf 10
+    (10_000, 640, 40),     # IVF-PQ's merge: 16 probes × kf 40
+    (10_000, 1024, 16),    # both cells' coarse top-16 of 1,024 lists
+    (1000, 8192, 64),      # the packed route's widest row, largest k
+])
+def test_resident_topk_kernel(one_chip, rows, n, k):
+    fn = functools.partial(select_k.resident_topk_min_packed, k=k,
+                           interpret=False)
+    _compile(fn, one_chip, ((rows, n), jnp.float32))
 
 
 def test_lut_kernel(one_chip):

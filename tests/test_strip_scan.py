@@ -514,6 +514,47 @@ def test_tile_body_hands_candidates_over_in_place(rng):
         assert bool(e.params["input_output_aliases"]) == (n > 0)
 
 
+@pytest.mark.parametrize("kf,with_const", [(10, False), (40, True)])
+def test_merge_resident_route_matches_jnp_route(rng, monkeypatch, kf,
+                                                with_const):
+    """merge_strip_candidates on its chip route (``interpret=False``):
+    the top-k of the (q, p·kf) candidates through the resident kernel
+    (run here in interpret mode) gives the same values and ids, bit for
+    bit, as through iter_topk_min_packed, the jnp route it replaces."""
+    import raft_tpu.ops.select_k as sk
+    import raft_tpu.ops.strip_scan as ss
+
+    q, p, s_tot, n_lists, m = 45, 4, 7, 3, 256
+    k = kf
+    assert ss.merge_on_resident(p, k, kf, interpret=False)
+    cand_v = rng.standard_normal((s_tot + 1, kf, C)).astype(np.float32)
+    cand_v[1, kf // 2:] = np.inf               # a list shorter than kf
+    cand_v[2, :, :20] = 0.5                    # ties across pairs
+    cand_e = rng.integers(0, m, (s_tot + 1, kf, C)).astype(np.int32)
+    strip_list = np.array([0, 1, 2, 0, 1, 2, -1], np.int32)
+    pair_strip = rng.integers(0, s_tot - 1, (q, p)).astype(np.int32)
+    pair_slot = rng.integers(0, C, (q, p)).astype(np.int32)
+    list_ids = np.arange(n_lists * m, dtype=np.int32).reshape(n_lists, m)
+    const = (jnp.asarray(rng.standard_normal((q, p)), jnp.float32)
+             if with_const else None)
+    args = [jnp.asarray(a) for a in (cand_v, cand_e, strip_list,
+                                     pair_strip, pair_slot, list_ids)]
+
+    def merge(topk):
+        monkeypatch.setattr(sk, "resident_topk_min_packed", topk)
+        return ss.merge_strip_candidates(*args, k, kf, False, const)
+
+    resident, shapes = sk.resident_topk_min_packed, []
+    got = merge(lambda v, kk: shapes.append(v.shape)
+                or resident(v, kk, interpret=True))
+    want = merge(sk.iter_topk_min_packed)
+    assert shapes == [(q, p * kf)]       # one lane-dense candidate array
+    assert np.array_equal(np.asarray(got[0]).view(np.int32),
+                          np.asarray(want[0]).view(np.int32))
+    assert np.array_equal(np.asarray(got[1]), np.asarray(want[1]))
+    assert (np.asarray(got[1]) >= 0).any()
+
+
 @pytest.mark.parametrize("kind,kf,n_sub", [
     (kind, kf, n_sub) for kind in ("fp", "bq") for kf in (10, 40)
     for n_sub in (1, 2)])

@@ -156,3 +156,34 @@ def test_strip_kernel_topk_routes_bit_identical(monkeypatch, dtype, kf):
     assert np.array_equal(se, np.asarray(de)[:-1][real])
     assert np.isfinite(sv).all()
     assert ((se >= 0) & (se < lens[strip_list[real], None, None])).all()
+
+
+@pytest.mark.parametrize("rows,n,k", [
+    (10_000, 640, 40),     # IVF-PQ's merge
+    (10_000, 160, 10),     # IVF-Flat's merge
+    (10_000, 1024, 16),    # the cells' coarse top-16
+])
+def test_resident_topk_bit_identical_on_chip(rows, n, k):
+    """The compiled resident top-k kernel at the SIFT-1M cells' shapes
+    returns exactly iter_topk_min_packed's XLA passes' values and indices,
+    bit for bit, with rows of +inf, -inf, NaN and exact ties."""
+    import jax.numpy as jnp
+
+    from raft_tpu.ops.select_k import (iter_topk_min_packed,
+                                       resident_topk_min_packed)
+
+    x = np.random.default_rng(n + k).normal(scale=100.0, size=(rows, n))
+    x = x.astype(np.float32)
+    x[0] = np.inf
+    x[1, ::2] = -np.inf
+    x[2, 3:40:3] = np.nan
+    x[3, : n // 2] = 1.5
+    x[4, ::3] = -2.25
+    x[5, -k:] = np.inf
+    x[-1, :7] = -3.5                     # ties in the last, partial block
+    x = jnp.asarray(x)
+    want = jax.jit(iter_topk_min_packed, static_argnums=1)(x, k)
+    got = resident_topk_min_packed(x, k)
+    assert np.array_equal(np.asarray(got[0]).view(np.int32),
+                          np.asarray(want[0]).view(np.int32))
+    assert np.array_equal(np.asarray(got[1]), np.asarray(want[1]))

@@ -305,6 +305,32 @@ def test_ragged_search_counts_topk_routes(telemetry, rng, family, k,
     assert counters["strip_scan.topk.calls"] == 2 * len(classes) > 0
     assert counters.get("strip_scan.topk.sorted", 0) == (
         counters["strip_scan.topk.calls"] if sorted_route else 0)
+    # the CPU takes the jnp top-k routes, not the resident kernel
+    assert counters["select_k.resident.calls"] == 0
+
+
+@pytest.mark.parametrize("n_lists,p,k,algo,per_launch", [
+    (1024, 16, 10, "exact", 2),     # IVF-Flat cell: coarse top-16, merge
+    (1024, 16, 40, "exact", 2),     # IVF-PQ cell
+    (1024, 16, 65, "exact", 1),     # k past the kernel: the probe only
+    (1024, 64, 40, "exact", 1),     # p·kf = 2560 past the packed merge
+    (1024, 16, 10, "approx", 1),    # an approximate probe: the merge only
+    (8192, 16, 10, "exact", 1),     # too many lists for the packed probe
+    (1024, 16, 200, "approx", 0),
+])
+def test_resident_counter_per_launch(telemetry, n_lists, p, k, algo,
+                                     per_launch):
+    """``select_k.resident.calls`` counts, per launch and from the static
+    shapes alone, the top-k calls that run the resident kernel on the
+    chip: the coarse probe's select and the merge."""
+    from raft_tpu.ops.select_k import probe_algo
+    from raft_tpu.ops.strip_scan import note_topk_routes
+
+    obs.reset()
+    note_topk_routes(((1, 1),), k, False, 3000, 1000, n_lists, p, k,
+                     probe_algo(algo, False, n_lists), False)
+    assert obs.snapshot()["counters"]["select_k.resident.calls"] == \
+        3 * per_launch
 
 
 def test_refine_records_entry_and_launch_spans(telemetry, rng):
